@@ -270,7 +270,7 @@ int main() {
     CHECK(h.exec.launched.size() == 2);
 
     // Worker death past the (zero) backoff budget: the job must NOT
-    // fail — it downsizes to 1 and resumes (VERDICT r3 item 7 e2e shape).
+    // fail — it downsizes to 1 and resumes (the elastic e2e's shape).
     h.exec.Finish("je/1", 137);
     h.Settle();
     CHECK(Phase(h.store, "je") == "Running");

@@ -28,11 +28,16 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU lowering)
+from jax.sharding import PartitionSpec as P
+
+from kubeflow_tpu.utils.devices import on_tpu
 
 NEG_INF = -1e30
 
@@ -398,9 +403,44 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
     return out
 
 
+def flash_attention_on_mesh(q, k, v, mesh, *, block_q: int = 512,
+                            block_kv: int = 512,
+                            segment_ids: jax.Array | None = None,
+                            mask: MaskSpec | str | None = None):
+    """Causal `flash_attention` for a caller traced under `mesh` (None or a
+    one-device mesh: the plain call). Mosaic kernels cannot be partitioned
+    by GSPMD — under jit with sharded operands the compiled kernel refuses
+    to lower ("wrap the call in a shard_map") — so on a multi-device mesh
+    the call runs in a shard_map: batch over the dp-like axes, heads over
+    `tensor`, each where the sizes divide, everything else gathered.
+    Attention is independent across batch rows and (kv-)head groups, so
+    the body needs no collective. Interpret mode takes the same route so
+    the CPU-mesh tests run the path the chip runs."""
+    def attend(q, k, v, seg=None):
+        return flash_attention(q, k, v, causal=True, block_q=block_q,
+                               block_kv=block_kv, segment_ids=seg, mask=mask)
+
+    if mesh is None or mesh.size == 1:
+        return attend(q, k, v, segment_ids)
+    batch = tuple(a for a in ("data", "fsdp")
+                  if a in mesh.axis_names and mesh.shape[a] > 1)
+    if q.shape[0] % math.prod(mesh.shape[a] for a in batch):
+        batch = ()
+    tp = mesh.shape["tensor"] if "tensor" in mesh.axis_names else 1
+    heads = ("tensor" if tp > 1 and q.shape[2] % tp == 0
+             and k.shape[2] % tp == 0 else None)
+    spec = P(batch or None, None, heads, None)
+    args, in_specs = (q, k, v), (spec, spec, spec)
+    if segment_ids is not None:
+        args += (segment_ids,)
+        in_specs += (P(batch or None, None),)
+    return shard_map(attend, mesh=mesh, in_specs=in_specs, out_specs=spec,
+                     check_vma=False)(*args)
+
+
 def _resolve(q, k, block_q, block_kv, interpret):
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
+        interpret = not on_tpu()
     s, t = q.shape[1], k.shape[1]
     block_q = min(block_q, max(s, 1))
     block_kv = min(block_kv, max(t, 1))

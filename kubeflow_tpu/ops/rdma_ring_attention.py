@@ -43,6 +43,7 @@ from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
 from kubeflow_tpu.parallel.mesh import current_mesh
+from kubeflow_tpu.utils.devices import on_tpu
 
 NEG_INF = -1e30
 
@@ -438,7 +439,7 @@ def _resolve_ring(axis_name, mesh, interpret):
     if mesh is None:
         raise ValueError("rdma_ring_attention needs a mesh")
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
+        interpret = not on_tpu()
     return mesh, mesh.shape[axis_name], interpret
 
 

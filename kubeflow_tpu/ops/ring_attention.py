@@ -248,8 +248,9 @@ def _ring_attention_flash(q, k, v, axis_name, mesh, n, block_q, block_kv):
     resident KV originates from shard (me - i) mod n, so the whole step is
     before/at/after the Q shard — see _flash_case_block."""
     if n == 1:
-        from kubeflow_tpu.ops.flash_attention import flash_attention
-        return flash_attention(q, k, v, True, block_q, block_kv)
+        from kubeflow_tpu.ops.flash_attention import flash_attention_on_mesh
+        return flash_attention_on_mesh(q, k, v, mesh, block_q=block_q,
+                                       block_kv=block_kv)
 
     spec = P(_batch_spec(mesh, axis_name), axis_name, None, None)
 

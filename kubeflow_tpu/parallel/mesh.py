@@ -168,7 +168,9 @@ def dp_like_axes(mesh: Mesh) -> tuple[str, ...]:
 def current_mesh() -> Mesh | None:
     """The mesh installed by `with mesh:` (thread-local). Lets ops like
     ring_attention find the mesh from inside a model without plumbing."""
-    from jax._src import mesh as mesh_lib  # stable across jax 0.4–0.9
+    # jax 0.9.0 has no public accessor for the `with mesh:` context (the
+    # jax.interpreters.pxla alias is deprecated), so this reads the source.
+    from jax._src import mesh as mesh_lib
 
     phys = mesh_lib.thread_resources.env.physical_mesh
     return None if phys.empty else phys

@@ -153,9 +153,6 @@ def main(argv=None) -> int:
     # env form injected at exec instead (no jax import paid here).
     cpu = os.environ.get("TPK_CPU_DEVICES")
     if cpu and spec.get("component", {}).get("kind", "python") == "python":
-        # Shared helper: covers jax >= 0.5 (jax_num_cpu_devices) AND
-        # older jax (XLA_FLAGS) — a raw config update crashes the
-        # component body on old-jax environments.
         from kubeflow_tpu.utils.devices import force_cpu_device_count
 
         force_cpu_device_count(int(cpu))

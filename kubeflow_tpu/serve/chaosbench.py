@@ -91,7 +91,9 @@ def _worker_main(args) -> int:
 
         from kubeflow_tpu.models.llama import Llama, llama_tiny
         from kubeflow_tpu.serve.generation import GenerativeJAXModel
+        from kubeflow_tpu.utils.devices import enable_compile_cache
 
+        enable_compile_cache()  # every worker compiles the same engine
         cfg = dataclasses.replace(llama_tiny(), dtype=jnp.float32,
                                   num_layers=2)
         net = Llama(cfg)
@@ -122,10 +124,6 @@ class ReplicaProc:
         if fake:
             cmd.append("--fake")
         env = dict(os.environ, JAX_PLATFORMS="cpu")
-        # Best-effort shared compile cache across worker subprocesses
-        # (ignored by jax versions/backends that don't support it).
-        env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                       "/tmp/tpk-chaos-jax-cache")
         self.proc = subprocess.Popen(
             cmd, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
             env=env, text=True)

@@ -56,9 +56,14 @@ class Model:
     def __call__(self, payload: Any) -> Any:
         return self.postprocess(self.predict(self.preprocess(payload)))
 
-    # Metadata for the v2 protocol's GET /v2/models/{name}.
+    # Metadata for the v2 protocol's GET /v2/models/{name}. `platform` is
+    # the protocol's framework string; `device` is what this replica is
+    # actually running on, as JAX reports it.
     def metadata(self) -> dict:
-        return {"name": self.name, "platform": "jax-tpu",
+        from kubeflow_tpu.utils.devices import device_summary
+
+        return {"name": self.name, "platform": "jax",
+                "device": device_summary(),
                 "inputs": [], "outputs": []}
 
 
@@ -179,7 +184,7 @@ class JAXModel(Model):
 
     def metadata(self) -> dict:
         return {
-            "name": self.name, "platform": "jax-tpu",
+            **super().metadata(),
             "inputs": [{"name": f"input_{i}", "shape": [-1, *shape],
                         "datatype": _v2_dtype(dtype)}
                        for i, (shape, dtype) in enumerate(self.input_spec)],

@@ -26,6 +26,7 @@ import functools
 import json
 import math
 import os
+import signal
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -1400,13 +1401,25 @@ def main(argv: list[str] | None = None) -> int:
             except ValueError:
                 p.error(f"--mesh parts must be axis=N, got {part!r}")
 
-    if args.cpu_devices:
-        # Shared helper: covers jax >= 0.5 (jax_num_cpu_devices) AND
-        # older jax (XLA_FLAGS) — a raw config update crash-loops every
-        # controller-launched replica on old-jax environments.
-        from kubeflow_tpu.utils.devices import force_cpu_device_count
+    from kubeflow_tpu.utils import devices
 
-        force_cpu_device_count(args.cpu_devices)
+    if args.cpu_devices:
+        devices.force_cpu_device_count(args.cpu_devices)
+    devices.enable_compile_cache()
+    clock = devices.CompileClock()
+    print(json.dumps({
+        "event": "device",
+        **devices.require_tpu_or_requested_cpu("tpk-model-server")}),
+        flush=True)
+
+    # SIGTERM unwinds main() like ^C does (default disposition would drop
+    # the process mid-dispatch): the finally below stops the decode
+    # threads, then the interpreter tears the PJRT client down in order,
+    # which is what hands the chip to the next process.
+    def _on_sigterm(signum, frame):
+        raise SystemExit(0)
+
+    signal.signal(signal.SIGTERM, _on_sigterm)
 
     from kubeflow_tpu.serve import runtimes, storage
 
@@ -1418,20 +1431,30 @@ def main(argv: list[str] | None = None) -> int:
               if args.request_log else None)
     server = ModelServer(request_logger=logger,
                          max_inflight=args.max_inflight)
-    for i, d in enumerate(dirs):
-        name = args.name[i] if i < len(args.name) else None
-        model = runtimes.load_model(d, name=name, mesh=mesh_spec)
-        server.repo.register(model, model_dir=d, mesh=mesh_spec,
-                             max_batch_size=args.max_batch_size,
-                             max_latency_ms=args.max_latency_ms)
-        print(json.dumps({"event": "model_loaded", "name": model.name,
-                          "load_time_s": model.load_time_s}), flush=True)
-    if args.grpc_port is not None:
-        bound = server.start_grpc(args.grpc_port)
-        print(json.dumps({"event": "grpc_serving", "port": bound}),
+    try:
+        for i, d in enumerate(dirs):
+            name = args.name[i] if i < len(args.name) else None
+            model = runtimes.load_model(d, name=name, mesh=mesh_spec)
+            server.repo.register(model, model_dir=d, mesh=mesh_spec,
+                                 max_batch_size=args.max_batch_size,
+                                 max_latency_ms=args.max_latency_ms)
+            print(json.dumps({"event": "model_loaded", "name": model.name,
+                              "load_time_s": model.load_time_s}),
+                  flush=True)
+        if args.grpc_port is not None:
+            bound = server.start_grpc(args.grpc_port)
+            print(json.dumps({"event": "grpc_serving", "port": bound}),
+                  flush=True)
+        print(json.dumps({"event": "serving", "port": args.port}),
               flush=True)
-    print(json.dumps({"event": "serving", "port": args.port}), flush=True)
-    server.run(args.port)
+        server.run(args.port)
+    finally:
+        for name in server.repo.names():
+            server.repo.get(name).unload()
+        server.stop()
+        print(json.dumps({"event": "device_end", **clock.snapshot(),
+                          "peak_bytes_in_use":
+                              devices.peak_bytes_in_use()}), flush=True)
     return 0
 
 

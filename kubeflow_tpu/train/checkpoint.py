@@ -155,19 +155,7 @@ class CheckpointManager:
         os.rename(src, dst)
         resilience.metrics.inc("tpk_checkpoint_quarantined_total",
                                component="train")
-        # Refresh the manager's cached step list; older orbax without
-        # reload() gets a rebuilt manager (same options).
-        try:
-            self._mgr.reload()
-        except AttributeError:
-            self._mgr.close()
-            self._mgr = ocp.CheckpointManager(
-                self.directory,
-                options=ocp.CheckpointManagerOptions(
-                    save_interval_steps=self.interval,
-                    max_to_keep=self._keep,
-                    enable_async_checkpointing=self._async_save,
-                ))
+        self._mgr.reload()  # refresh the manager's cached step list
         return dst
 
     def restore_latest_good(self, state_template: Any

@@ -15,24 +15,39 @@ import time
 
 import jax
 
-# Peak dense BF16 FLOP/s per chip (public spec sheets).
-PEAK_FLOPS = {
-    "v4": 275e12,
-    "v5e": 197e12,
-    "v5 lite": 197e12,
-    "v5p": 459e12,
-    "v6e": 918e12,
-    "cpu": 1e12,  # nominal, so MFU math stays finite in CPU tests
+#: Peak dense bf16 FLOP/s per chip, keyed by `device_kind` exactly as
+#: JAX spells it. Figures: Google Cloud TPU documentation, the "System
+#: architecture" page of each version (v4 275, v5e 197, v5p 459, v6e 918
+#: TFLOP/s); kind spellings: jaxlib 0.9.0's own table,
+#: jax/_src/pallas/mosaic/tpu_info.py. A kind that is not here is an
+#: error — an assumed peak turns every MFU after it into fiction.
+PEAK_BF16_FLOPS = {
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,
+    "TPU v5e": 197e12,
+    "TPU v5": 459e12,
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,
+    "TPU v6e": 918e12,
 }
 
 
-def peak_flops_per_chip() -> float:
-    dev = jax.devices()[0]
-    kind = getattr(dev, "device_kind", "cpu").lower()
-    for key, val in PEAK_FLOPS.items():
-        if key in kind:
-            return val
-    return PEAK_FLOPS["cpu"]
+def peak_flops_per_chip(device=None) -> float | None:
+    """Peak bf16 FLOP/s of `device` (default: the first device). None on
+    the CPU platform — there is no accelerator peak to hold a CPU run
+    against, so its MFU is "not measured", never a number. Raises on an
+    accelerator whose kind is not in the table."""
+    dev = device if device is not None else jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    try:
+        return PEAK_BF16_FLOPS[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s recorded for device_kind "
+            f"{dev.device_kind!r} (platform {dev.platform!r}); add it to "
+            f"PEAK_BF16_FLOPS with its source — have "
+            f"{sorted(PEAK_BF16_FLOPS)}") from None
 
 
 @dataclasses.dataclass
@@ -72,7 +87,9 @@ class StepTimer:
         avg = self._total_time / counted if counted else (step_time or 0.0)
         tps = self.tokens_per_step / avg if avg else 0.0
         model_flops = 6.0 * self.num_params * tps  # fwd+bwd matmul FLOPs
-        mfu = model_flops / (self.num_chips * self.peak) if avg else 0.0
+        mfu = None
+        if self.peak is not None:
+            mfu = model_flops / (self.num_chips * self.peak) if avg else 0.0
         return {
             "step_time_s": step_time if step_time is not None else avg,
             "avg_step_time_s": avg,

@@ -35,11 +35,11 @@ the claims are arm DELTAS plus mechanism facts, never absolute speed.
 
 Harness discipline (PROFILE §11/§15): the fault schedule is seeded and
 recorded; kills are condition-triggered at step thresholds read from the
-worker's own metrics stream; the persistent XLA compile cache is
-disabled (a post-resize attempt loading a cache entry written at the
-other topology segfaults this jaxlib's cache deserialization) — compile
-cost stays symmetric instead: every arm compiles 4-way at launch, and
-the two compared arms each pay exactly one 2-way recompile after the
+worker's own metrics stream. Workers run with the trainer's persistent
+compile cache like any other: under jaxlib 0.9.0 a post-resize attempt
+loads entries written at the other topology cleanly (3 of 3 runs with
+every program cached, ISSUE 21). Compile cost is symmetric either way: every arm compiles 4-way at launch, and the two
+compared arms each pay one 2-way compile or cache load after the
 identical downsize.
 """
 
@@ -357,16 +357,6 @@ def run_trainchaos(quick: bool = False, seed: int = 0,
     base = workdir or tempfile.mkdtemp(prefix="tpk-trainchaos-")
     own_dir = workdir is None
     os.makedirs(base, exist_ok=True)
-    # NO persistent XLA compile cache: on this jaxlib, a post-resize
-    # attempt that loads a cache entry written at the other topology
-    # segfaults natively in cache deserialization (reproduced 3/3 with
-    # the cache, 0/3 without) — the controller then reads the SIGSEGV
-    # as one more worker death and downsizes AGAIN. Workers inherit env
-    # through the controller, so scrub it here. Compile cost stays fair
-    # without warm caches: every arm compiles 4-way at launch, and
-    # elastic and restart-from-scratch each pay exactly one 2-way
-    # recompile after the (identical) downsize.
-    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
     corpus = os.path.join(base, "corpus.npy")
     np.save(corpus, np.random.default_rng(seed + 11).integers(
         0, 64, 200000, dtype=np.int32))

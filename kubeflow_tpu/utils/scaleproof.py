@@ -70,10 +70,7 @@ def _mem_report(compiled, *, hbm_bytes: int = V5P_HBM_BYTES,
     temp = int(ma.temp_size_in_bytes)
     out = int(ma.output_size_in_bytes)
     alias = int(ma.alias_size_in_bytes)
-    # Newer jaxlibs dropped peak_memory_in_bytes from CompiledMemoryStats;
-    # args+temp is the same conservative stand-in the total already uses
-    # (peak <= live arguments + live temps at the worst program point).
-    peak = int(getattr(ma, "peak_memory_in_bytes", 0)) or (args + temp)
+    peak = int(ma.peak_memory_in_bytes)
     # Conservative per-device live set: arguments + temps + outputs with no
     # donation credit (alias_size already subtracts what XLA aliased; the
     # CPU backend typically reports 0, so this double-counts donated state

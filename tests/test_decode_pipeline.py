@@ -45,7 +45,7 @@ def test_dispatch_count_guard_pipelined_vs_sync(tiny):
     sync engine blocks the host on every one of its M fetches; the
     pipelined engine must overlap all but the pipe-drain tail. A
     regression that quietly re-serializes the loop flips these counters
-    long before anyone can measure tunnel latency on a chip."""
+    long before anyone can measure it on a chip."""
     model, params = tiny
     prompt = [5, 9, 2]
     chunks = 6

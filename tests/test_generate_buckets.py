@@ -1,4 +1,4 @@
-"""Length-aware decode buckets + prefix caching (VERDICT r2 item 4):
+"""Length-aware decode buckets + prefix caching:
 decode cost tracks the longest active sequence, shared prompt prefixes
 skip recompute, and greedy outputs are bit-identical either way."""
 

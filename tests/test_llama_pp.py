@@ -124,7 +124,7 @@ def _packed_batch(cfg, batch=8, seq=16, seed=3):
     (2, dict(pipe=2), 16),  # circular schedule with packed metadata
 ])
 def test_pipeline_packed_matches_scanned(devices8, chunks, mesh_kw, batch):
-    """VERDICT r3 item 5: packed-batch PP logits must match the no-PP
+    """Packed-batch PP logits must match the no-PP
     packed model — segment_ids/positions ride the ring with activations."""
     cfg = _cfg()
     model, params, _ = _params_and_tokens(cfg)
@@ -210,7 +210,7 @@ def test_trainer_packed_pipeline_end_to_end(tmp_path, devices8):
     ("naive", 2),   # circular schedule x CP
 ])
 def test_pipeline_cp_forward_matches_scanned(devices8, attn, chunks):
-    """CP-inside-PP (VERDICT r3 weak #5): seq_axis shards the traveling
+    """CP-inside-PP: seq_axis shards the traveling
     activations' sequence dim over `seq` and stage attention runs the ring
     schedule — logits must match the scanned no-PP model exactly."""
     cfg = dataclasses.replace(_cfg(), attention_impl=attn)
@@ -229,7 +229,7 @@ def test_pipeline_cp_forward_matches_scanned(devices8, attn, chunks):
 
 @pytest.mark.parametrize("chunks", [1, 2])
 def test_pipeline_cp_packed_matches_scanned(devices8, chunks):
-    """VERDICT r4 item 8: packed segments x CP-inside-PP — segment ids
+    """Packed segments x CP-inside-PP — segment ids
     shard with the sequence, travel the pipeline, and rotate the stage
     ring with K/V; logits must match the scanned packed model. Also
     checks the auto-downgrade from 'flash' (the fused ring has no

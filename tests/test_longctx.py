@@ -1,7 +1,7 @@
 """Pins the long-context harness (kubeflow_tpu/utils/longctx.py): the
 tiny-model shape must produce a complete fit report off-chip, so
-`bench.py --longctx` can't rot between live-chip windows (the BENCH_r03
-failure mode: a harness that only ever runs when the chip is up)."""
+`bench.py --longctx` can't rot between chip runs (a harness that only ever
+runs when the chip is up breaks unnoticed)."""
 
 import jax
 import pytest
@@ -24,10 +24,11 @@ def test_analyze_fit_tiny_shape():
 
 def test_measure_tiny_shape():
     """The measured path (what the chip run executes) works off-chip too:
-    real steps on the CPU backend, sane tok/s + MFU fields."""
+    real steps on the CPU backend, a sane tok/s — and no MFU, because
+    the CPU has no accelerator peak to hold it against."""
     r = longctx.measure(2, 64, timed_steps=2, size="tiny")
     assert r["tok_s"] > 0
-    assert 0 <= r["mfu"] < 10  # CPU nominal peak makes this loose
+    assert r["mfu"] is None
     assert r["avg_step_time_s"] > 0
     assert r["device_kind"] == jax.devices()[0].device_kind
 
@@ -36,14 +37,14 @@ def test_measure_tiny_shape():
 def test_tune_point_tiny_shape():
     """The knob sweep (bench.py --longctx-tune) runs off-chip on the
     tiny shape: every variant measured or its failure recorded inline,
-    best-MFU-first ordering, knob fields present."""
+    fastest-first ordering, knob fields present."""
     variants = ({}, {"remat_policy": "save_attn"}, {"loss_chunk": 32},
                 {"flash_block": (64, 32)})
     rows = longctx.tune_point(2, 64, timed_steps=1, variants=variants,
                               size="tiny")
     assert len(rows) == len(variants)
-    ok = [r for r in rows if "mfu" in r]
+    ok = [r for r in rows if "tok_s" in r]
     assert ok, rows  # at least the default variant must measure
-    assert ok == sorted(ok, key=lambda r: -r["mfu"])
+    assert ok == sorted(ok, key=lambda r: -r["tok_s"])
     for r in ok:
         assert {"remat_policy", "loss_chunk", "flash_block"} <= set(r)

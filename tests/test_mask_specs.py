@@ -1,4 +1,4 @@
-"""Block-sparse mask specs (ops/ROADMAP.md item 2, VERDICT r2 item 7):
+"""Block-sparse mask specs (ops/ROADMAP.md item 2):
 prefix-LM, sliding-window, and full masks through all three fused flash
 kernels (fwd, bwd-dq, bwd-dkv), composed with segments, and through Llama.
 """

@@ -126,7 +126,7 @@ def test_hybrid_mesh_collectives_run(devices8):
 
 def test_mesh_factors_all_world_sizes():
     """The driver's mesh-factor split must cover every world size, not
-    just the n=8 the dryrun exercises (VERDICT r2 weak #4): products
+    just the n=8 the dryrun exercises: products
     always match and odd remainders land on fsdp."""
     import importlib
 

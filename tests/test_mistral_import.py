@@ -120,7 +120,7 @@ def test_windowed_serving_rolls_past_window(hf_mistral_dir):
     """max_len > window switches to the ROLLING cache (window rows,
     modular writes) and greedy decode stays token-identical to torch even
     when prompt + generation outgrow the window — the vLLM capability the
-    engine used to refuse (VERDICT r4 item 2)."""
+    engine used to refuse."""
     path, tmodel = hf_mistral_dir
     from kubeflow_tpu.models.hf_import import import_llama
     from kubeflow_tpu.models.llama import Llama

@@ -1,6 +1,6 @@
 """Rolling sliding-window KV cache: serving Mistral-class checkpoints
 PAST the window (the vLLM/huggingfaceserver capability; SURVEY.md §2.2
-runtimes row, VERDICT r4 item 2).
+runtimes row).
 
 Oracle: step-by-step FULL-FORWARD greedy decode under the sliding-window
 MaskSpec — no cache at all, so any rolling-cache bookkeeping bug (modular

@@ -1,6 +1,6 @@
 """Serving feature composition: speculative decoding x TP, multi-LoRA x
 TP, spec-decode x multi-LoRA — the pairs vLLM composes and the engine
-used to refuse (VERDICT r4 item 3; ops/ROADMAP.md composition ledger).
+used to refuse (ops/ROADMAP.md composition ledger).
 
 Contract: every composition is TOKEN-IDENTICAL to the same request on
 the single-device / single-feature engine — composition must never

@@ -11,7 +11,14 @@ pytestmark = pytest.mark.slow  # multi-process/e2e/AOT tier
 
 
 def test_servebench_quick_shape():
-    r = run_servebench(size="tiny", quick=True)
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.models.llama import llama_tiny
+
+    cfg = dataclasses.replace(llama_tiny(), dtype=jnp.float32, num_layers=2)
+    r = run_servebench(cfg=cfg, quick=True)
     # Pipelined-vs-sync A/B (ISSUE 3 tentpole): both engines measured,
     # and the overlap mechanism visibly engaged — the sync engine blocks
     # on every fetch, the pipelined one overlaps its steady state.

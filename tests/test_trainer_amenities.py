@@ -1,6 +1,6 @@
 """Fine-tune trainer amenities: grad clipping, LR schedules, gradient
 accumulation, and the in-run eval stream (reference SDK `train()` semantics,
-SURVEY.md §2.1 — VERDICT r2 item 6)."""
+SURVEY.md §2.1)."""
 
 import json
 

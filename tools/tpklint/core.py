@@ -30,9 +30,12 @@ import re
 import tokenize
 from typing import Callable
 
-#: Directories never scanned (build trees, VCS, caches).
+#: Directories never scanned (build trees, VCS, caches, and the git-ignored
+#: run-time output directories — a builder unpacks a second copy of the
+#: tree under one of them to prove chip_smoke.py from committed files).
 SKIP_DIRS = {".git", "__pycache__", "build", "build-asan", "build-tsan",
-             ".claude", "node_modules", ".pytest_cache"}
+             ".claude", "node_modules", ".pytest_cache",
+             ".jax_compile_cache", "chip_smoke_out", "chiprun_out"}
 
 
 @dataclasses.dataclass(frozen=True)
