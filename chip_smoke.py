@@ -28,12 +28,16 @@ server's SIGTERM shutdown released the chip).
                                       test suite's guard against rot; it
                                       refuses to run on a TPU
 
-Every passed phase prints one JSON line (platform, device_kind,
-device_count, wall_s, compile_s, ...). Only if all passed, the last stdout
-line is `{"ok": true, "device": {...}}` and the exit code is 0; failures go
-to stderr and leave no verdict on stdout. Everything written lands in
-`chip_smoke_out/` (git-ignored); compiled programs go where
-kubeflow_tpu.utils.devices.enable_compile_cache() puts them.
+Every phase prints one JSON line (ok, platform, device_kind, device_count,
+wall_s and, when it passed, compile_s, ...); a failed phase's reason and
+the tail of its log go to stderr. The last stdout line is the verdict:
+`{"ok": true, "device": {...}}` with exit code 0 only if all phases passed,
+else `{"ok": false, "failed": [...], "device": {...}}` and exit code 1.
+Where no child comes up on the platform the run is for (no accelerator),
+nothing at all is printed to stdout and the exit code is non-zero.
+Everything written lands in `chip_smoke_out/` (git-ignored); compiled
+programs go where kubeflow_tpu.utils.devices.enable_compile_cache() puts
+them.
 """
 
 from __future__ import annotations
@@ -41,12 +45,14 @@ from __future__ import annotations
 import json
 import math
 import os
+import shutil
 import signal
 import socket
 import subprocess
 import sys
 import threading
 import time
+import traceback
 import urllib.error
 import urllib.request
 
@@ -104,11 +110,6 @@ class PhaseFailed(Exception):
     pass
 
 
-class NoAccelerator(PhaseFailed):
-    """A child came up on another platform than the run requires — no
-    later phase can pass, so the run stops here."""
-
-
 # -- the kernels child (the only code in this file that imports JAX) ---------
 
 
@@ -119,10 +120,10 @@ def kernels_child(cfg: dict) -> int:
     devices.enable_compile_cache()
     clock = devices.CompileClock()
     dev = devices.device_summary()
-    report = {"platform": dev["platform"], "device_kind": dev["kind"],
-              "device_count": dev["count"]}
+    # Like both mains: the device first, before anything compiles, so a
+    # kernel Mosaic refuses still leaves the parent knowing where it ran.
+    print(json.dumps({"event": "device", **dev}), flush=True)
     if dev["platform"] != cfg["platform"]:
-        print(json.dumps(report), flush=True)
         return 3
 
     import jax
@@ -191,9 +192,9 @@ def kernels_child(cfg: dict) -> int:
         if not all(math.isfinite(e) and e <= KERNEL_TOL
                    for e in errs.values()):
             ok = False
-    report.update({"interpret": k["interpret"], "tolerance": KERNEL_TOL,
-                   "max_rel_err": worst, **clock.snapshot()})
-    print(json.dumps(report), flush=True)
+    print(json.dumps({"event": "kernels", "interpret": k["interpret"],
+                      "tolerance": KERNEL_TOL, "max_rel_err": worst,
+                      **clock.snapshot()}), flush=True)
     return 0 if ok else 1
 
 
@@ -280,25 +281,25 @@ def _tail(path: str, n: int = 4000) -> str:
         return f"<{e}>"
 
 
-def _check_device(run: Run, row: dict | None, who: str) -> dict:
-    """The child's own device line must name the platform this run is
-    for. Records it on the run; returns the phase line's device fields."""
+def _event(rows: list[dict], name: str) -> dict | None:
+    return next((r for r in rows if r.get("event") == name), None)
+
+
+def _check_device(run: Run, log: str, who: str) -> dict:
+    """The child's own `device` line (its first, printed before anything
+    compiles) must name the platform this run is for. Records it on the
+    run; returns the phase line's device fields."""
+    row = _event(_json_lines(log), "device")
     if row is None:
-        raise PhaseFailed(f"{who}: never reported its device")
-    dev = {"platform": row.get("platform"),
-           "kind": row.get("kind", row.get("device_kind")),
-           "count": row.get("count", row.get("device_count"))}
+        raise PhaseFailed(f"{who}: never reported its device\n"
+                          + _tail(log))
+    dev = {key: row.get(key) for key in ("platform", "kind", "count")}
     if dev["platform"] != run.cfg["platform"]:
-        raise NoAccelerator(
-            f"{who}: came up on {dev}, this run needs platform "
-            f"{run.cfg['platform']!r}")
+        raise PhaseFailed(f"{who}: came up on {dev}, this run needs "
+                          f"platform {run.cfg['platform']!r}")
     run.device = dev
     return {"platform": dev["platform"], "device_kind": dev["kind"],
             "device_count": dev["count"]}
-
-
-def _event(rows: list[dict], name: str) -> dict | None:
-    return next((r for r in rows if r.get("event") == name), None)
 
 
 # -- phases ------------------------------------------------------------------
@@ -310,13 +311,14 @@ def phase_kernels(run: Run) -> dict:
     if run.cfg is CPU_TINY:
         argv.append("--cpu-tiny")
     rc = run.wait(run.spawn(argv, log), "kernels")
-    rows = _json_lines(log)
-    row = rows[-1] if rows else None
-    dev = _check_device(run, row, "kernels")
-    if rc != 0:
+    dev = _check_device(run, log, "kernels")
+    row = _event(_json_lines(log), "kernels")
+    if rc != 0 or row is None:
+        # No result row: a kernel did not lower or run (the compiler's
+        # message is in the log's tail). A row: it ran and disagreed.
         raise PhaseFailed(
             f"kernels: exit code {rc}; max_rel_err="
-            f"{row.get('max_rel_err')} (tolerance {KERNEL_TOL})\n"
+            f"{row and row['max_rel_err']} (tolerance {KERNEL_TOL})\n"
             + _tail(log))
     return {**dev, "compile_s": row["compile_s"],
             "compile_cache_hits": row["compile_cache_hits"],
@@ -327,10 +329,11 @@ def phase_kernels(run: Run) -> dict:
 def phase_train(run: Run) -> dict:
     t = run.cfg["train"]
     work = os.path.join(OUT, "train")
-    os.makedirs(work, exist_ok=True)
+    # Nothing an earlier run left may pass this one's checks
+    # (MetricsLogger appends; the profiler adds a directory per run).
+    shutil.rmtree(work, ignore_errors=True)
+    os.makedirs(work)
     metrics = os.path.join(work, "metrics.jsonl")
-    if os.path.exists(metrics):
-        os.remove(metrics)  # MetricsLogger appends
     spec = {
         "model": run.cfg["model"],
         "dataset": "synthetic_lm",
@@ -352,8 +355,8 @@ def phase_train(run: Run) -> dict:
     rc = run.wait(run.spawn(
         ["-m", "kubeflow_tpu.train.trainer", "--spec", spec_path], log),
         "train")
+    dev = _check_device(run, log, "train")
     rows = _json_lines(log)
-    dev = _check_device(run, _event(rows, "device"), "train")
     if rc != 0:
         raise PhaseFailed(f"train: exit code {rc}\n" + _tail(log))
     losses = [r["loss"] for r in rows if "loss" in r and "event" not in r]
@@ -501,8 +504,7 @@ def phase_serve(run: Run) -> dict:
         # The port opens only after load (weights + every AOT compile).
         while True:
             if proc.poll() is not None:
-                _check_device(run, _event(_json_lines(log), "device"),
-                              "serve")
+                _check_device(run, log, "serve")
                 raise PhaseFailed(f"serve: server exited with code "
                                   f"{proc.returncode} before it was ready\n"
                                   + _tail(log))
@@ -513,8 +515,7 @@ def phase_serve(run: Run) -> dict:
                     break
             except (OSError, ValueError):
                 time.sleep(1.0)
-        dev = _check_device(run, _event(_json_lines(log), "device"),
-                            "serve")
+        dev = _check_device(run, log, "serve")
 
         # Deterministic prompts; distinct first tokens so no prompt is a
         # prefix of another by accident.
@@ -610,26 +611,35 @@ def main(argv: list[str]) -> int:
         for name, phase in phases.items():
             t0 = time.monotonic()
             try:
-                row = phase(run)
-            except PhaseFailed as e:
+                row = {"ok": True, **phase(run)}
+            except Exception as e:
+                # PhaseFailed, or a check tripping over a reply it did not
+                # expect: either way the phase failed and the run goes on.
                 failed.append(name)
-                print(f"chip_smoke: FAILED {e}", file=sys.stderr)
+                print("chip_smoke: FAILED " + (
+                    str(e) if isinstance(e, PhaseFailed)
+                    else f"{name}: {traceback.format_exc()}"),
+                    file=sys.stderr)
                 if run.device is None:
                     # Wrong platform, or not even the kernels child could
-                    # say where it ran: no later phase can pass.
+                    # say where it ran: no later phase can pass, and there
+                    # is no result to print.
                     break
-                continue
+                row = {"ok": False, "platform": run.device["platform"],
+                       "device_kind": run.device["kind"],
+                       "device_count": run.device["count"]}
             print(json.dumps({
-                "phase": name, "ok": True,
-                "wall_s": round(time.monotonic() - t0, 1), **row}),
-                flush=True)
+                "phase": name, **row,
+                "wall_s": round(time.monotonic() - t0, 1)}), flush=True)
     finally:
         run.stop_all()
-    if failed:
-        print(f"chip_smoke: FAILED phases: {failed}", file=sys.stderr)
+    if run.device is None:
         return 1
-    print(json.dumps({"ok": True, "device": run.device}), flush=True)
-    return 0
+    verdict = {"ok": not failed, "device": run.device}
+    if failed:
+        verdict["failed"] = failed
+    print(json.dumps(verdict), flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":

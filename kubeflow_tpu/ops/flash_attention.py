@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import math
 
 import jax
@@ -37,7 +38,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU lowering)
 from jax.sharding import PartitionSpec as P
 
+from kubeflow_tpu.parallel.mesh import dp_like_axes
 from kubeflow_tpu.utils.devices import on_tpu
+
+_LOG = logging.getLogger(__name__)
 
 NEG_INF = -1e30
 
@@ -412,7 +416,8 @@ def flash_attention_on_mesh(q, k, v, mesh, *, block_q: int = 512,
     by GSPMD — under jit with sharded operands the compiled kernel refuses
     to lower ("wrap the call in a shard_map") — so on a multi-device mesh
     the call runs in a shard_map: batch over the dp-like axes, heads over
-    `tensor`, each where the sizes divide, everything else gathered.
+    `tensor`. A dimension whose size does not divide is gathered instead
+    (every chip then computes all of it), with a warning at trace time.
     Attention is independent across batch rows and (kv-)head groups, so
     the body needs no collective. Interpret mode takes the same route so
     the CPU-mesh tests run the path the chip runs."""
@@ -422,13 +427,20 @@ def flash_attention_on_mesh(q, k, v, mesh, *, block_q: int = 512,
 
     if mesh is None or mesh.size == 1:
         return attend(q, k, v, segment_ids)
-    batch = tuple(a for a in ("data", "fsdp")
-                  if a in mesh.axis_names and mesh.shape[a] > 1)
-    if q.shape[0] % math.prod(mesh.shape[a] for a in batch):
-        batch = ()
+    batch = dp_like_axes(mesh)
+    dp = math.prod(mesh.shape[a] for a in batch)
     tp = mesh.shape["tensor"] if "tensor" in mesh.axis_names else 1
-    heads = ("tensor" if tp > 1 and q.shape[2] % tp == 0
-             and k.shape[2] % tp == 0 else None)
+    heads = "tensor" if tp > 1 else None
+    if q.shape[0] % dp:
+        _LOG.warning("flash_attention_on_mesh: batch %d does not divide "
+                     "%s=%d; gathered, every chip computes all rows",
+                     q.shape[0], "x".join(batch), dp)
+        batch = ()
+    if q.shape[2] % tp or k.shape[2] % tp:
+        _LOG.warning("flash_attention_on_mesh: heads %d/%d do not divide "
+                     "tensor=%d; gathered, every chip computes all heads",
+                     q.shape[2], k.shape[2], tp)
+        heads = None
     spec = P(batch or None, None, heads, None)
     args, in_specs = (q, k, v), (spec, spec, spec)
     if segment_ids is not None:

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
-from kubeflow_tpu.parallel.mesh import current_mesh
+from kubeflow_tpu.parallel.mesh import current_mesh, dp_like_axes
 
 NEG_INF = -1e30
 
@@ -61,9 +61,7 @@ def _batch_spec(mesh, axis_name):
     """Shard batch over whichever dp-like axes the mesh actually has
     (never the ring axis itself) — a dedicated single-axis ring mesh
     (kernel tests, standalone CP) leaves batch replicated."""
-    axes = tuple(a for a in ("data", "fsdp")
-                 if a in mesh.axis_names and a != axis_name)
-    return axes or None
+    return dp_like_axes(mesh, exclude=axis_name) or None
 
 
 def _merge(carry, update):

@@ -160,9 +160,12 @@ def mesh_shape(mesh: Mesh) -> dict[str, int]:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 
 
-def dp_like_axes(mesh: Mesh) -> tuple[str, ...]:
-    """Axes over which the batch is sharded (data + fsdp)."""
-    return tuple(a for a in ("data", "fsdp") if mesh.shape[a] > 1) or ("data",)
+def dp_like_axes(mesh: Mesh, exclude: str | None = None) -> tuple[str, ...]:
+    """The axes of `mesh` over which a batch is sharded (data, fsdp), minus
+    `exclude` — the one rule behind every shard_map'd op's batch spec. A
+    mesh with neither (a dedicated single-axis ring mesh) gives ()."""
+    return tuple(a for a in ("data", "fsdp")
+                 if a in mesh.axis_names and a != exclude)
 
 
 def current_mesh() -> Mesh | None:

@@ -11,8 +11,12 @@ from __future__ import annotations
 
 import os
 
-#: Git-ignored; lives in the checkout so a sealed machine that copies the
-#: tree (and nothing under /tmp) can still be handed a warm cache.
+#: Git-ignored, at one fixed place in the checkout: every process of a
+#: checkout (both mains, bench.py, the smoke's children, the next run)
+#: finds the same entries, with no /tmp, pid or clock in the path. A
+#: machine that should start warm is handed its cache from outside, through
+#: JAX_COMPILATION_CACHE_DIR. This sandbox's directory holds CPU programs,
+#: so .chiprunignore keeps it out of the chip tool's copy.
 _CACHE_DIRNAME = ".jax_compile_cache"
 
 

@@ -520,10 +520,6 @@ def test_rdma_ring_matches_naive(devices8, nseq):
                                rtol=2e-3, atol=2e-3)
 
 
-# Slow tier (ISSUE 21): interpret-mode only — jax 0.9.0 refuses this kernel's
-# CompilerParams before Mosaic sees it (ROADMAP D3), and no model path
-# calls it; the forward pins stay in tier-1 until D3 wires or deletes it.
-@pytest.mark.slow
 @pytest.mark.parametrize("nseq", [4, 8])
 def test_rdma_ring_fused_backward_matches_naive(devices8, nseq):
     """The fused two-pass backward (K/V rotate for dq; q/dout/lse/delta
@@ -547,10 +543,6 @@ def test_rdma_ring_fused_backward_matches_naive(devices8, nseq):
                                    rtol=5e-3, atol=5e-3)
 
 
-# Slow tier (ISSUE 21): interpret-mode only — jax 0.9.0 refuses this kernel's
-# CompilerParams before Mosaic sees it (ROADMAP D3), and no model path
-# calls it; the forward pins stay in tier-1 until D3 wires or deletes it.
-@pytest.mark.slow
 def test_rdma_ring_fused_backward_gqa_batched(devices8):
     """GQA (group > 1) + batch > 1 through the fused backward: the
     [bkh, group*s, d] head-block layout must round-trip gradients."""

@@ -180,6 +180,59 @@ def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
     assert not (tmp_path / "chip_smoke_out").exists()
 
 
+@pytest.fixture
+def smoke(tmp_path, monkeypatch):
+    """chip_smoke.py as a module (stdlib only), writing under tmp_path."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location("chip_smoke", SMOKE)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    monkeypatch.setattr(mod, "OUT", str(tmp_path))
+    return mod
+
+
+def test_chip_smoke_refused_kernel_shows_the_compiler_and_run_goes_on(
+        smoke, monkeypatch, capsys):
+    """A kernels child that says where it runs and then dies compiling:
+    the parent keeps the device, puts the log's tail on stderr, still runs
+    the other phases, and ends stdout with an ok:false verdict."""
+    child = ("import json; print(json.dumps({'event': 'device', 'platform':"
+             " 'tpu', 'kind': 'TPU v5 lite', 'count': 1}), flush=True);"
+             " raise NotImplementedError('Mosaic says no')")
+    real_spawn = smoke.Run.spawn
+    monkeypatch.setattr(smoke.Run, "spawn", lambda self, argv, log:
+                        real_spawn(self, ["-c", child], log))
+    ran = []
+    for name in ("serve", "train"):
+        monkeypatch.setattr(
+            smoke, f"phase_{name}",
+            lambda run, name=name: ran.append(name) or {"compile_s": 0.0})
+    assert smoke.main([]) == 1
+    out, err = capsys.readouterr()
+    lines = [json.loads(ln) for ln in out.splitlines()]
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    assert ran == ["serve", "train"]
+    assert [(ln["phase"], ln["ok"]) for ln in lines[:-1]] == [
+        ("kernels", False), ("serve", True), ("train", True)]
+    assert lines[-1] == {"ok": False, "device": device,
+                         "failed": ["kernels"]}
+    assert "Mosaic says no" in err
+
+
+def test_chip_smoke_child_that_never_names_its_device_ends_the_run(
+        smoke, monkeypatch, capsys):
+    real_spawn = smoke.Run.spawn
+    monkeypatch.setattr(smoke.Run, "spawn", lambda self, argv, log:
+                        real_spawn(self, ["-c", "raise SystemExit('boom')"],
+                                   log))
+    monkeypatch.setattr(smoke, "phase_serve", lambda run: 1 / 0)
+    assert smoke.main([]) == 1
+    out, err = capsys.readouterr()
+    assert out == ""  # no device, no result
+    assert "never reported its device" in err and "boom" in err
+
+
 @pytest.mark.slow  # spawns both mains; ~1 min
 def test_chip_smoke_phase_code_runs_at_tiny_size_on_the_cpu():
     """The script's own phase code — same children, same checks — at
