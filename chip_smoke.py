@@ -118,7 +118,7 @@ def kernels_child(cfg: dict) -> int:
     from kubeflow_tpu.utils import devices
 
     devices.enable_compile_cache()
-    clock = devices.CompileClock()
+    clock = devices.compile_clock()
     dev = devices.device_summary()
     # Like both mains: the device first, before anything compiles, so a
     # kernel Mosaic refuses still leaves the parent knowing where it ran.

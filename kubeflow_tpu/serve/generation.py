@@ -53,7 +53,7 @@ from kubeflow_tpu.serve.model import Model
 from kubeflow_tpu.serve.paging import BlockAllocator, blocks_for
 from kubeflow_tpu.serve.quant import (KV_QUANT_MODES, kv_dequantize_rows,
                                       kv_qdtype, kv_quantize_rows)
-from kubeflow_tpu.utils import obs
+from kubeflow_tpu.utils import devices, obs
 from kubeflow_tpu.utils.resilience import (Deadline, DeadlineExceeded,
                                            metrics as res_metrics)
 
@@ -1161,7 +1161,21 @@ class GenerationEngine:
                       # full-width dequant events (prefix-hit fragment
                       # reconstruction / fmt-1 import) and shipped wire
                       # bytes (fmt-3 pays about half fmt-1's).
-                      "kv_dequant_fallbacks": 0, "kv_shipment_bytes": 0}
+                      "kv_dequant_fallbacks": 0, "kv_shipment_bytes": 0,
+                      # Where a request's first token went (ISSUE 26),
+                      # each summed where the event happens:
+                      # queue_wait_seconds over `admitted` is submit →
+                      # slot (the serve.batch_gather interval),
+                      # ttft_seconds over `first_tokens` submit → first
+                      # token on the stream; decode_context_tokens sums
+                      # each dispatch's rows' context lengths (the K/V a
+                      # decode step has to read).
+                      "queue_wait_seconds": 0.0, "admitted": 0,
+                      "ttft_seconds": 0.0, "first_tokens": 0,
+                      "decode_context_tokens": 0}
+        # The pass number of the engine loop, on its `engine.*` spans.
+        self._round = 0
+        devices.compile_clock()  # counting before this engine's compiles
         self._compile()
         from kubeflow_tpu.models.llama import init_cache
         with self._scope():
@@ -2754,18 +2768,23 @@ class GenerationEngine:
 
     def _admit(self, slot: int, req: dict) -> None:
         tracer = obs.get_tracer()
+        now = time.perf_counter()
+        t_enq = req.get("t_enq") or now
         if tracer.enabled:
             # Queue wait (submit enqueue → slot admission): the engine's
             # continuous batcher is this request's "batch gather".
-            tracer.record("serve.batch_gather",
-                          req.get("t_enq") or time.perf_counter(),
-                          time.perf_counter(), req.get("trace", ""),
-                          slot=slot)
+            tracer.record("serve.batch_gather", t_enq, now,
+                          req.get("trace", ""), slot=slot)
         with self._scope():
             with obs.span("serve.prefill", trace_id=req.get("trace", ""),
                           slot=slot,
                           prompt_tokens=len(req["input_ids"])):
                 self._admit_inner(slot, req)
+        # Counted once the admission held (a request stashed for want of
+        # KV blocks comes through here again).
+        with self._stats_lock:
+            self.stats["queue_wait_seconds"] += now - t_enq
+            self.stats["admitted"] += 1
 
     def _admit_inner(self, slot: int, req: dict) -> None:
         if req.get("mode") == "remote":
@@ -2956,6 +2975,7 @@ class GenerationEngine:
         req = st["req"]
         new: list[int] = []
         finished = req["done"].is_set()
+        first = not req["out"]
         for j, t in enumerate(tokens):
             if finished:
                 break
@@ -2976,6 +2996,13 @@ class GenerationEngine:
                 req["cb"](new, finished)
             except Exception:
                 pass
+        if first and new:
+            # The request's first token is on its stream (or in `out`,
+            # for a caller that waits for the whole reply).
+            now = time.perf_counter()
+            with self._stats_lock:
+                self.stats["ttft_seconds"] += now - (req.get("t_enq") or now)
+                self.stats["first_tokens"] += 1
         if finished:
             req["done"].set()
             if self._slots[slot] is st:
@@ -3302,6 +3329,7 @@ class GenerationEngine:
         with self._stats_lock:
             self.stats["decode_dispatches"] += 1
             self.stats["spec_dispatches"] += 1
+            self.stats["decode_context_tokens"] += sum(assumed.values())
         rec_parts: dict[int, dict] = {}
         for i in parts:
             st = self._slots[i]
@@ -3329,85 +3357,93 @@ class GenerationEngine:
         The doomed protocol is whole-record: bounded waste
         (pipeline_depth-1 records per rejection event), zero carry
         splicing."""
-        t0 = time.monotonic()
-        pf0 = time.perf_counter()
-        # tpk-lint: allow(host-sync) reason=the designed per-spec-chunk fetch boundary; D2H was prestaged by copy_to_host_async at dispatch
-        toks = np.asarray(rec["toks"])  # [B, n_spec, gamma+1]
-        # tpk-lint: allow(host-sync) reason=second half of the designed spec fetch boundary (logprobs ride the same prestaged copy)
-        lps = np.asarray(rec["lps"])
-        # tpk-lint: allow(host-sync) reason=accepted counts ARE the reconcile input — each row's next index is decided by them, on host, once per record
-        acc = np.asarray(rec["acc"])    # [B, n_spec] accepted counts
-        now = time.monotonic()
-        pf1 = time.perf_counter()
-        tracer = obs.get_tracer()
-        if tracer.enabled:
-            for i, st in rec["parts"].items():
-                trace = st["req"].get("trace", "")
-                tracer.record("serve.decode_chunk", rec["p0"], pf0,
-                              trace, slot=i, spec=True,
-                              overlapped=overlapped)
-                tracer.record("serve.fetch", pf0, pf1, trace, slot=i)
-        start = (rec["t0"] if self._busy_mark is None
-                 else max(self._busy_mark, rec["t0"]))
+        # `stalled_s`: as in _fetch_chunk.
         with self._stats_lock:
-            self.stats["host_stall_seconds"] += now - t0
-            self.stats["decode_fetch_overlapped" if overlapped
-                        else "decode_fetch_blocking"] += 1
-            self.stats["decode_seconds"] += now - start
-        self._busy_mark = now
-        worst = rec["worst"]
-        spec = self._spec
+            stalled = self.stats["host_stall_seconds"]
+        with obs.span("engine.fetch", round=self._round, stalled_s=stalled):
+            t0 = time.monotonic()
+            pf0 = time.perf_counter()
+            # tpk-lint: allow(host-sync) reason=the designed per-spec-chunk fetch boundary; D2H was prestaged by copy_to_host_async at dispatch
+            toks = np.asarray(rec["toks"])  # [B, n_spec, gamma+1]
+            # tpk-lint: allow(host-sync) reason=second half of the designed spec fetch boundary (logprobs ride the same prestaged copy)
+            lps = np.asarray(rec["lps"])
+            # tpk-lint: allow(host-sync) reason=accepted counts ARE the reconcile input — each row's next index is decided by them, on host, once per record
+            acc = np.asarray(rec["acc"])    # [B, n_spec] accepted counts
+            now = time.monotonic()
+            pf1 = time.perf_counter()
+        # engine.fetch is the host sync and nothing else (the interval
+        # host_stall_seconds sums); engine.emit is the rest of the pass:
+        # reconcile, first tokens, the callers' streams.
+        with obs.span("engine.emit", round=self._round):
+            tracer = obs.get_tracer()
+            if tracer.enabled:
+                for i, st in rec["parts"].items():
+                    trace = st["req"].get("trace", "")
+                    tracer.record("serve.decode_chunk", rec["p0"], pf0,
+                                  trace, slot=i, spec=True,
+                                  overlapped=overlapped)
+                    tracer.record("serve.fetch", pf0, pf1, trace, slot=i)
+            start = (rec["t0"] if self._busy_mark is None
+                     else max(self._busy_mark, rec["t0"]))
+            with self._stats_lock:
+                self.stats["host_stall_seconds"] += now - t0
+                self.stats["decode_fetch_overlapped" if overlapped
+                            else "decode_fetch_blocking"] += 1
+                self.stats["decode_seconds"] += now - start
+            self._busy_mark = now
+            worst = rec["worst"]
+            spec = self._spec
 
-        def doom_later() -> None:
-            for r in inflight:
-                if r.get("kind") == "spec":
-                    r["doomed"] = True
+            def doom_later() -> None:
+                for r in inflight:
+                    if r.get("kind") == "spec":
+                        r["doomed"] = True
 
-        for i, st in rec["parts"].items():
-            if self._slots[i] is not st:
-                with self._stats_lock:
-                    self.stats["decode_dead_slot_chunks"] += 1
-                    self.stats["decode_wasted_tokens"] += worst
-                continue
-            if st["pending"] is not None:
-                # First token of a mid-pipe admission: emit it before
-                # the spec tokens (the record decoded FROM it).
-                self._emit_pending(i, st)
-                if self._slots[i] is not st:  # EOS/budget at token 1
+            for i, st in rec["parts"].items():
+                if self._slots[i] is not st:
                     with self._stats_lock:
                         self.stats["decode_dead_slot_chunks"] += 1
                         self.stats["decode_wasted_tokens"] += worst
                     continue
-            if rec["doomed"] or st["idx"] != rec["assumed"][i]:
-                # Over-advanced: decoded from a start index that partial
-                # acceptance upstream made fictional. Settle this
-                # record's disp contribution and drop the rows.
-                st["disp"] -= worst
+                if st["pending"] is not None:
+                    # First token of a mid-pipe admission: emit it before
+                    # the spec tokens (the record decoded FROM it).
+                    self._emit_pending(i, st)
+                    if self._slots[i] is not st:  # EOS/budget at token 1
+                        with self._stats_lock:
+                            self.stats["decode_dead_slot_chunks"] += 1
+                            self.stats["decode_wasted_tokens"] += worst
+                        continue
+                if rec["doomed"] or st["idx"] != rec["assumed"][i]:
+                    # Over-advanced: decoded from a start index that partial
+                    # acceptance upstream made fictional. Settle this
+                    # record's disp contribution and drop the rows.
+                    st["disp"] -= worst
+                    with self._stats_lock:
+                        self.stats["decode_wasted_tokens"] += worst
+                    continue
+                emit_t: list[int] = []
+                emit_l: list[float] = []
+                accepted = 0
+                for s in range(spec["n_spec"]):
+                    kk = int(acc[i, s])
+                    emit_t += [int(t) for t in toks[i, s, :kk + 1]]
+                    emit_l += [float(v) for v in lps[i, s, :kk + 1]]
+                    accepted += kk
+                st["idx"] += len(emit_t)
+                st["disp"] -= worst - len(emit_t)
+                st["last"] = emit_t[-1]
+                if len(emit_t) < worst:
+                    # Partial acceptance: every later in-flight spec record
+                    # chained on the all-accepted assumption — doom them
+                    # wholesale (they reconcile as drops above).
+                    doom_later()
                 with self._stats_lock:
-                    self.stats["decode_wasted_tokens"] += worst
-                continue
-            emit_t: list[int] = []
-            emit_l: list[float] = []
-            accepted = 0
-            for s in range(spec["n_spec"]):
-                kk = int(acc[i, s])
-                emit_t += [int(t) for t in toks[i, s, :kk + 1]]
-                emit_l += [float(v) for v in lps[i, s, :kk + 1]]
-                accepted += kk
-            st["idx"] += len(emit_t)
-            st["disp"] -= worst - len(emit_t)
-            st["last"] = emit_t[-1]
-            if len(emit_t) < worst:
-                # Partial acceptance: every later in-flight spec record
-                # chained on the all-accepted assumption — doom them
-                # wholesale (they reconcile as drops above).
-                doom_later()
-            with self._stats_lock:
-                self.stats["spec_proposed"] += (spec["gamma"]
-                                                * spec["n_spec"])
-                self.stats["spec_accepted"] += accepted
-                self.stats["decode_tokens"] += len(emit_t)
-            self._emit(i, st, emit_t, emit_l)
+                    self.stats["spec_proposed"] += (spec["gamma"]
+                                                    * spec["n_spec"])
+                    self.stats["spec_accepted"] += accepted
+                    self.stats["decode_tokens"] += len(emit_t)
+                self._emit(i, st, emit_t, emit_l)
 
     # tpk-hot: engine-dispatch
     def _dispatch_chunk(self, active: list[int],
@@ -3499,6 +3535,8 @@ class GenerationEngine:
             getattr(arr, "copy_to_host_async", lambda: None)()
         with self._stats_lock:
             self.stats["decode_dispatches"] += 1
+            self.stats["decode_context_tokens"] += sum(
+                self._slots[i]["disp"] for i in active)
         parts: dict[int, dict] = {}
         for i in active:
             st = self._slots[i]
@@ -3516,69 +3554,77 @@ class GenerationEngine:
         records whether another chunk was still in flight during this
         fetch (the steady-state pipelining invariant the CPU dispatch-
         count guard test pins)."""
-        t0 = time.monotonic()
-        pf0 = time.perf_counter()
-        # THE one designed host sync of the decode pipeline: everything
-        # below is host numpy. (The runtime fetch-count guard test pins
-        # exactly one fetch pair per chunk.)
-        # tpk-lint: allow(host-sync) reason=the designed per-chunk fetch boundary; D2H was prestaged by copy_to_host_async at dispatch
-        toks = np.asarray(rec["toks"])  # host sync point: [B, chunk]
-        # tpk-lint: allow(host-sync) reason=second half of the designed per-chunk fetch boundary (logprobs ride the same prestaged copy)
-        lps = np.asarray(rec["lps"])
-        now = time.monotonic()
-        pf1 = time.perf_counter()
-        tracer = obs.get_tracer()
-        if tracer.enabled:
-            # Chunk-granular spans (never per-token — the hot loop adds
-            # no syncs, and the ring stays bounded): one decode-chunk
-            # span per rider covering dispatch→fetch-start, one fetch
-            # span per rider covering the host sync itself.
-            for i, st in rec["parts"].items():
-                trace = st["req"].get("trace", "")
-                tracer.record("serve.decode_chunk", rec["p0"], pf0, trace,
-                              slot=i, chunk=rec["chunk"],
-                              overlapped=overlapped)
-                tracer.record("serve.fetch", pf0, pf1, trace, slot=i)
-        # decode_seconds sums ENGINE-BUSY wall time (non-overlapping
-        # intervals), so throughput() stays honest when chunks overlap.
-        start = (rec["t0"] if self._busy_mark is None
-                 else max(self._busy_mark, rec["t0"]))
+        # `stalled_s` is host_stall_seconds as it stands before this fetch:
+        # between two engine.fetch spans of one trace the counter's change
+        # and the spans' seconds cover the same passes
+        # (benchmarks/xplane_host.py, `fetch_check`).
         with self._stats_lock:
-            self.stats["host_stall_seconds"] += now - t0
-            self.stats["decode_fetch_overlapped" if overlapped
-                        else "decode_fetch_blocking"] += 1
-            self.stats["decode_seconds"] += now - start
-        self._busy_mark = now
-        for i, st in rec["parts"].items():
-            if self._slots[i] is not st:
-                with self._stats_lock:
-                    self.stats["decode_dead_slot_chunks"] += 1
-                    self.stats["decode_wasted_tokens"] += rec["chunk"]
-                continue
-            if st["pending"] is not None:
-                # First token of a mid-pipe admission: emit it before
-                # the chunk tokens (the chunk was decoded FROM it).
-                self._emit_pending(i, st)
-                if self._slots[i] is not st:  # EOS/budget at token 1
+            stalled = self.stats["host_stall_seconds"]
+        with obs.span("engine.fetch", round=self._round, stalled_s=stalled):
+            t0 = time.monotonic()
+            pf0 = time.perf_counter()
+            # THE one designed host sync of the decode pipeline: everything
+            # below is host numpy. (The runtime fetch-count guard test pins
+            # exactly one fetch pair per chunk.)
+            # tpk-lint: allow(host-sync) reason=the designed per-chunk fetch boundary; D2H was prestaged by copy_to_host_async at dispatch
+            toks = np.asarray(rec["toks"])  # host sync point: [B, chunk]
+            # tpk-lint: allow(host-sync) reason=second half of the designed per-chunk fetch boundary (logprobs ride the same prestaged copy)
+            lps = np.asarray(rec["lps"])
+            now = time.monotonic()
+            pf1 = time.perf_counter()
+        with obs.span("engine.emit", round=self._round):
+            tracer = obs.get_tracer()
+            if tracer.enabled:
+                # Chunk-granular spans (never per-token — the hot loop adds
+                # no syncs, and the ring stays bounded): one decode-chunk
+                # span per rider covering dispatch→fetch-start, one fetch
+                # span per rider covering the host sync itself.
+                for i, st in rec["parts"].items():
+                    trace = st["req"].get("trace", "")
+                    tracer.record("serve.decode_chunk", rec["p0"], pf0, trace,
+                                  slot=i, chunk=rec["chunk"],
+                                  overlapped=overlapped)
+                    tracer.record("serve.fetch", pf0, pf1, trace, slot=i)
+            # decode_seconds sums ENGINE-BUSY wall time (non-overlapping
+            # intervals), so throughput() stays honest when chunks overlap.
+            start = (rec["t0"] if self._busy_mark is None
+                     else max(self._busy_mark, rec["t0"]))
+            with self._stats_lock:
+                self.stats["host_stall_seconds"] += now - t0
+                self.stats["decode_fetch_overlapped" if overlapped
+                            else "decode_fetch_blocking"] += 1
+                self.stats["decode_seconds"] += now - start
+            self._busy_mark = now
+            for i, st in rec["parts"].items():
+                if self._slots[i] is not st:
                     with self._stats_lock:
                         self.stats["decode_dead_slot_chunks"] += 1
                         self.stats["decode_wasted_tokens"] += rec["chunk"]
                     continue
-            st["idx"] += rec["chunk"]
-            st["last"] = int(toks[i, -1])
-            # This vanilla chunk left the slot's DRAFT cache rows
-            # unwritten — spec decoding must not trust them until
-            # re-admission replays the slot's history
-            # (_readmit_draft, once the batch is all-spec-able
-            # again). spec_demotions / spec_readmissions count both
-            # sides (perf effects, never correctness).
-            with self._stats_lock:
-                if st.get("draft_ok"):
-                    self.stats["spec_demotions"] += 1
-                self.stats["decode_tokens"] += rec["chunk"]
-            st["draft_ok"] = False
-            self._emit(i, st, [int(t) for t in toks[i]],
-                       [float(v) for v in lps[i]])
+                if st["pending"] is not None:
+                    # First token of a mid-pipe admission: emit it before
+                    # the chunk tokens (the chunk was decoded FROM it).
+                    self._emit_pending(i, st)
+                    if self._slots[i] is not st:  # EOS/budget at token 1
+                        with self._stats_lock:
+                            self.stats["decode_dead_slot_chunks"] += 1
+                            self.stats["decode_wasted_tokens"] += rec["chunk"]
+                        continue
+                st["idx"] += rec["chunk"]
+                st["last"] = int(toks[i, -1])
+                # This vanilla chunk left the slot's DRAFT cache rows
+                # unwritten — spec decoding must not trust them until
+                # re-admission replays the slot's history
+                # (_readmit_draft, once the batch is all-spec-able
+                # again). spec_demotions / spec_readmissions count both
+                # sides (perf effects, never correctness).
+                with self._stats_lock:
+                    if st.get("draft_ok"):
+                        self.stats["spec_demotions"] += 1
+                    self.stats["decode_tokens"] += rec["chunk"]
+                st["draft_ok"] = False
+                self._emit(i, st, [int(t) for t in toks[i]],
+                           [float(v) for v in lps[i]])
 
     # tpk-hot: engine-loop
     def _loop(self) -> None:
@@ -3603,22 +3649,39 @@ class GenerationEngine:
         engine."""
         inflight: deque = deque()
         while not self._stop:
-            self._admit_waiting(overlap=bool(inflight))
-            # Chunk-boundary deadline sweep: an expired request frees its
-            # slot NOW instead of decoding tokens its caller (already
-            # 504'd) will never read — expiry costs the batch at most
-            # pipeline_depth chunks of waste.
-            for i, st in enumerate(self._slots):
-                if st is not None and self._expire(st["req"]):
-                    self._slots[i] = None
-                    self._free_slot_blocks(st)
-            self._poll_pending_first()
+            # Each pass's phases are `engine.*` spans on this thread, one
+            # per phase per pass (`round` = the pass), so that a profiler
+            # trace shows which phase the host was in while the device
+            # ran or idled. Spans are host clock reads: none touches a
+            # device value.
+            self._round = rnd = self._round + 1
+            with obs.span("engine.admit", round=rnd):
+                self._admit_waiting(overlap=bool(inflight))
+            with obs.span("engine.sweep", round=rnd):
+                # Chunk-boundary deadline sweep: an expired request frees
+                # its slot NOW instead of decoding tokens its caller
+                # (already 504'd) will never read — expiry costs the
+                # batch at most pipeline_depth chunks of waste.
+                for i, st in enumerate(self._slots):
+                    if st is not None and self._expire(st["req"]):
+                        self._slots[i] = None
+                        self._free_slot_blocks(st)
+                self._poll_pending_first()
             active = [i for i, s in enumerate(self._slots)
                       if s is not None]
             if not active and not inflight:
                 self._busy_mark = None
-                self._wake.wait(0.05)
-                self._wake.clear()
+                with obs.span("engine.wait", round=rnd):
+                    # One span (and one pass) per idle period, not one
+                    # per 50 ms poll: an idle server must not turn the
+                    # span ring over. Every submit sets `_wake`; the
+                    # poll only bounds a missed wake-up, and a stashed
+                    # request's deadline is checked by the next pass.
+                    while not (self._wake.wait(0.05) or self._stop
+                               or self._kv_stash
+                               or not self._queue.empty()):
+                        pass
+                    self._wake.clear()
                 continue
             while active:
                 dispatched = False
@@ -3630,9 +3693,10 @@ class GenerationEngine:
                 parts, fallback = self._spec_batch(active, van_covered,
                                                    spec_chain)
                 if parts and len(spec_chain) < self.pipeline_depth:
-                    inflight.append(self._dispatch_spec_chunk(
-                        parts,
-                        carry=spec_chain[-1] if spec_chain else None))
+                    with obs.span("engine.dispatch", round=rnd, spec=True):
+                        inflight.append(self._dispatch_spec_chunk(
+                            parts,
+                            carry=spec_chain[-1] if spec_chain else None))
                     self.inflight_depth = len(inflight)
                     dispatched = True
                 spec_rows = {i for i in active
@@ -3645,9 +3709,10 @@ class GenerationEngine:
                         and (not van_chain
                              or self._worth_speculating(van_batch))
                         and self._van_riders_fit(van_batch)):
-                    inflight.append(self._dispatch_chunk(
-                        van_batch,
-                        carry=van_chain[-1] if van_chain else None))
+                    with obs.span("engine.dispatch", round=rnd):
+                        inflight.append(self._dispatch_chunk(
+                            van_batch,
+                            carry=van_chain[-1] if van_chain else None))
                     self.inflight_depth = len(inflight)
                     dispatched = True
                 if not dispatched:
@@ -3666,7 +3731,13 @@ class GenerationEngine:
         readers on other threads. Shallow by design: inner values are
         swapped whole (copy-on-write), never mutated in place."""
         with self._stats_lock:
-            return dict(self.stats)
+            out = dict(self.stats)
+        # The process's backend compiles, live: one inside a measured
+        # window would otherwise pass for a slow step or a TTFT outlier.
+        clock = devices.compile_clock()
+        out["compiles"] = clock.compiles
+        out["compile_seconds"] = clock.seconds
+        return out
 
     def throughput(self) -> float:
         s = self.stats_snapshot()

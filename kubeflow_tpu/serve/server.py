@@ -1406,7 +1406,7 @@ def main(argv: list[str] | None = None) -> int:
     if args.cpu_devices:
         devices.force_cpu_device_count(args.cpu_devices)
     devices.enable_compile_cache()
-    clock = devices.CompileClock()
+    clock = devices.compile_clock()
     print(json.dumps({
         "event": "device",
         **devices.require_tpu_or_requested_cpu("tpk-model-server")}),

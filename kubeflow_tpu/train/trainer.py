@@ -27,7 +27,7 @@ from kubeflow_tpu.parallel.sharding import rules_for
 from kubeflow_tpu.train.checkpoint import CheckpointManager
 from kubeflow_tpu.train.metrics import MetricsLogger, StepTimer
 from kubeflow_tpu.train.step import init_train_state, make_train_step
-from kubeflow_tpu.utils import faults, obs, resilience
+from kubeflow_tpu.utils import devices, faults, obs, resilience
 
 #: Fires at the top of every training step (ctx: step) — arming FailN
 #: with match={"step": K} is the in-process analog of the controller's
@@ -878,7 +878,8 @@ class Trainer:
         # wall the training thread spent waiting on input (data_wait_frac
         # ≈ 0 when the prefetcher keeps up; → 1 when the pipeline is the
         # bottleneck and depth/host work needs attention).
-        win = {"t0": 0.0, "wait": 0.0, "h2d": 0.0}
+        win = {"t0": 0.0, "wait": 0.0, "h2d": 0.0, "compiles": 0}
+        clock = devices.compile_clock()
         # Per-window span rollup (tentpole: "span summaries in the JSONL
         # stream"): host-side wall spent in step dispatch / boundary
         # fetches / checkpoint saves / eval, summed between log
@@ -900,6 +901,7 @@ class Trainer:
             win["t0"] = time.perf_counter()
             win["wait"] = prefetch.data_wait_s
             win["h2d"] = prefetch.h2d_s
+            win["compiles"] = clock.compiles
             span_win.clear()
 
         def win_metrics() -> dict:
@@ -909,6 +911,9 @@ class Trainer:
                 "data_wait_s": round(dw, 6),
                 "data_wait_frac": round(dw / wall, 4) if wall > 0 else 0.0,
                 "data_h2d_s": round(prefetch.h2d_s - win["h2d"], 6),
+                # Backend compiles in this window: one after the first
+                # row is a step that was not the step it looks like.
+                "compiles": clock.compiles - win["compiles"],
                 "tpk_data_wait_seconds_total": round(
                     resilience.metrics.get("tpk_data_wait_seconds_total",
                                            component="train"), 6),
@@ -1077,12 +1082,10 @@ def main(argv: list[str] | None = None) -> int:
                         help="force N virtual CPU devices (test mode)")
     args = parser.parse_args(argv)
 
-    from kubeflow_tpu.utils import devices
-
     if args.cpu_devices:
         devices.force_cpu_device_count(args.cpu_devices)
     devices.enable_compile_cache()
-    clock = devices.CompileClock()
+    clock = devices.compile_clock()
     with open(args.spec) as fh:
         spec = TrainJobSpec.from_json(fh.read())
     # Distributed init must precede the first backend use; Trainer's own

@@ -125,7 +125,7 @@ class CompileClock:
     retrieval included, so a warm start shows as a small number, not as
     zero compiles) and how many of them the persistent cache answered.
     Fed by jax.monitoring; listeners are process-global and cannot be
-    removed one by one, so make one per process, at start-up."""
+    removed one by one, so there is one per process: `compile_clock()`."""
 
     def __init__(self):
         import jax.monitoring
@@ -149,3 +149,16 @@ class CompileClock:
         return {"compile_s": round(self.seconds, 3),
                 "compiles": self.compiles,
                 "compile_cache_hits": self.cache_hits}
+
+
+_CLOCK: CompileClock | None = None
+
+
+def compile_clock() -> CompileClock:
+    """The process's one CompileClock, made on first use: the worker
+    mains take it at start-up (for `device_end`), the engine's `stats`
+    and the trainer's log rows read the same one live."""
+    global _CLOCK
+    if _CLOCK is None:
+        _CLOCK = CompileClock()
+    return _CLOCK

@@ -25,6 +25,19 @@ surface every layer shares:
     tracing at default settings (the span-overhead guard test pins
     this). `TPK_TRACE=0` (or `tracer.enabled = False`) turns recording
     into a shared no-op object — nothing is allocated at all.
+  * **Second sink: the profiler's clock.** A `span(...)` block is also a
+    `jax.profiler.TraceAnnotation` of the same name, carrying the trace
+    id and the attrs the span was opened with. While a profiler session
+    is open in the process the span therefore lands in the host plane
+    of the same `*.xplane.pb` as the device's operations, on their
+    timeline, where `benchmarks/xplane_host.py` reads it (which span
+    covered an idle gap of the device; the engine loop's phases). With
+    no session open the annotation costs well under a microsecond. This
+    module never imports JAX (the control-plane client imports it): it
+    looks the annotation class up in `sys.modules`, and a process that
+    has not imported JAX cannot have a profiler session. `record()`
+    intervals are measured after the fact and stay ring-only: an
+    annotation cannot be back-dated.
   * **Chrome trace export.** `chrome_trace()` renders the ring as
     Chrome trace-event JSON (`ph: "X"` complete events), loadable in
     chrome://tracing / Perfetto: `GET /debug/trace` on the model
@@ -36,6 +49,7 @@ from __future__ import annotations
 
 import os
 import re
+import sys
 import threading
 import time
 import uuid
@@ -47,6 +61,23 @@ _EPOCH = time.perf_counter()
 
 _TRACE_ID_RE = re.compile(r"[^A-Za-z0-9._:-]")
 _MAX_TRACE_ID = 128
+
+
+#: jax.profiler.TraceAnnotation, once JAX is in the process (see
+#: `_profiler_annotation`).
+_annotation = None
+
+
+def _profiler_annotation():
+    """`jax.profiler.TraceAnnotation` if this process has imported JAX,
+    else None — looked up, never imported, so that this module stays
+    importable without JAX. A half-finished `import jax` on another
+    thread reads as None and is asked again by the next span."""
+    global _annotation
+    if _annotation is None:
+        _annotation = getattr(sys.modules.get("jax.profiler"),
+                              "TraceAnnotation", None)
+    return _annotation
 
 
 def new_trace_id() -> str:
@@ -72,7 +103,7 @@ class Span:
     """A finished (or in-flight, inside `with`) host-side interval."""
 
     __slots__ = ("name", "trace_id", "attrs", "ts_us", "dur_us", "tid",
-                 "_tracer", "_t0")
+                 "_tracer", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  attrs: dict | None):
@@ -84,6 +115,7 @@ class Span:
         self.dur_us = 0.0
         self.tid = ""
         self._t0 = 0.0
+        self._ann = None  # the open profiler annotation, inside `with`
 
     @property
     def dur_s(self) -> float:
@@ -95,14 +127,26 @@ class Span:
             self.attrs = attrs
         else:
             self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def __enter__(self) -> "Span":
+        ann = _profiler_annotation()
+        if ann is not None:
+            attrs = self.attrs or {}
+            if self.trace_id:
+                attrs = {"trace_id": self.trace_id, **attrs}
+            self._ann = ann(self.name, **attrs)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         self.ts_us = (self._t0 - _EPOCH) * 1e6
         self.dur_us = (t1 - self._t0) * 1e6
         self.tid = threading.current_thread().name

@@ -870,11 +870,11 @@ def test_host_fetch_in_spec_reconcile_turns_red(tmp_path):
     whole pipelined loop, exactly what the hot-path guard exists to
     catch."""
     dst = _copy_engine_tree(tmp_path)
-    marker = "        def doom_later() -> None:"
+    marker = "            def doom_later() -> None:"  # in `engine.emit`
     src = dst.read_text()
     assert src.count(marker) == 1
     dst.write_text(src.replace(
-        marker, "        _ = self._cache.item()\n" + marker))
+        marker, "            _ = self._cache.item()\n" + marker))
     fs = lint(tmp_path, rules=["host-sync"])
     assert len(fs) == 1 and "spec-reconcile" in fs[0].message
 

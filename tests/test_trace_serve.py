@@ -11,6 +11,7 @@ growth on the train and decode hot loops.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import threading
@@ -218,12 +219,33 @@ def test_profile_window_knobs_from_spec(monkeypatch, tmp_path, devices8):
 # -- span-overhead guards (acceptance) ---------------------------------------
 
 
-def test_train_span_overhead_guard(monkeypatch, devices8):
+@contextlib.contextmanager
+def _profiler_session(tmp_path, on: bool):
+    """With `on`, a jax.profiler session around the block: the spans'
+    second sink (TraceAnnotation, utils/obs.py) then records, and the
+    guards below must hold all the same."""
+    if on:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path / "profile"),
+                                 profiler_options=options)
+    try:
+        yield
+    finally:
+        if on:
+            jax.profiler.stop_trace()
+
+
+@pytest.mark.parametrize("profiling", [False, True],
+                         ids=["ring", "ring+profiler"])
+def test_train_span_overhead_guard(monkeypatch, devices8, tmp_path,
+                                   profiling):
     """Tracing at DEFAULT settings must be free on the train hot loop:
     the host-sync budget is bit-identical to the pre-tracing guard
     (tests/test_prefetch.py) — zero extra float()s or block_until_ready
     — and span storage is a bounded ring, so per-step allocations can't
-    accumulate (no growth after capacity is reached)."""
+    accumulate (no growth after capacity is reached). The same with a
+    profiler session open, when every span is also an annotation."""
     from jax._src.array import ArrayImpl
 
     from kubeflow_tpu.train.trainer import TrainJobSpec, Trainer
@@ -244,7 +266,8 @@ def test_train_span_overhead_guard(monkeypatch, devices8):
                             strategy="dp", mesh={"data": 8}, steps=6,
                             batch_size=16, learning_rate=1e-2,
                             log_every=3, prefetch=2)
-        result = Trainer(spec).run()
+        with _profiler_session(tmp_path, profiling):
+            result = Trainer(spec).run()
         tracer = obs.get_tracer()
         assert result["final_step"] == 6
         # Identical budget to the pre-tracing hot-loop guard: 2 logging
@@ -262,12 +285,15 @@ def test_train_span_overhead_guard(monkeypatch, devices8):
         obs.set_tracer(prev)
 
 
-def test_decode_span_overhead_guard(devices8):
+@pytest.mark.parametrize("profiling", [False, True],
+                         ids=["ring", "ring+profiler"])
+def test_decode_span_overhead_guard(devices8, tmp_path, profiling):
     """Tracing at DEFAULT settings must be free on the decode hot loop:
     the same greedy request decoded with tracing enabled vs disabled
     performs an IDENTICAL number of device→host fetches (and identical
     tokens), spans are chunk-granular (never per token), and the ring
-    stays bounded."""
+    stays bounded. The same with a profiler session open, when the
+    engine loop's phase spans are also annotations."""
     from jax._src.array import ArrayImpl
 
     from kubeflow_tpu.models.llama import Llama, llama_tiny
@@ -305,8 +331,9 @@ def test_decode_span_overhead_guard(devices8):
 
     try:
         run_once(True)  # warm the scheduler state
-        toks_on, fetches_on, spans_on = run_once(True)
-        toks_off, fetches_off, spans_off = run_once(False)
+        with _profiler_session(tmp_path, profiling):
+            toks_on, fetches_on, spans_on = run_once(True)
+            toks_off, fetches_off, spans_off = run_once(False)
     finally:
         engine.close()
     assert toks_on == toks_off
