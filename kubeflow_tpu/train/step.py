@@ -57,9 +57,18 @@ def chunked_cross_entropy(hidden: jax.Array, head: jax.Array,
     """Fused blockwise cross entropy (ops/ROADMAP.md item 1): logits are
     computed per token-chunk against the unembedding and never
     materialized as the [B·S, V] fp32 buffer that dominates peak memory at
-    the bench point (PROFILE.md §3). `jax.checkpoint` on the chunk body
-    makes the backward recompute each chunk's logits — FLOPs traded for
-    the logits buffer, the same deal as flash attention.
+    the bench point (PROFILE.md §3).
+
+    The gradient is taken in the forward pass (a `jax.custom_vjp`): under
+    differentiation each chunk's logits are computed once and give the
+    chunk's loss, its d(hidden) and its share of d(head), so the head is
+    three matmul passes a step (logits, d(hidden), d(head)) and the
+    backward only scales the two stored gradients by the incoming
+    cotangent. Operands are in the activation dtype, the softmax in fp32,
+    and d(head) accumulates in fp32 over the chunks, in chunk order. A
+    gradient nobody asks for is not computed: with a frozen head (LoRA)
+    there is no d(head) matmul and no [V,D] carry, with no differentiation
+    at all only the loss. Targets and mask get no cotangent.
 
     hidden [B,S,D]; head [D,V] (lm_head kernel) or [V,D] with
     `head_is_vocab_major` (tied embedding); targets [B,S].
@@ -84,23 +93,65 @@ def chunked_cross_entropy(hidden: jax.Array, head: jax.Array,
     tb = t.reshape(nblk, chunk)
     mb = m.reshape(nblk, chunk)
 
-    spec = "cd,vd->cv" if head_is_vocab_major else "cd,dv->cv"
+    logits_spec, dx_spec, dw_spec = (
+        ("cd,vd->cv", "cv,vd->cd", "cv,cd->vd") if head_is_vocab_major
+        else ("cd,dv->cv", "cv,dv->cd", "cv,cd->dv"))
+    head_dtype = head.dtype
 
-    def block(carry, xs):
-        hx, tx, mx = xs
-        logits = jnp.einsum(spec, hx, head.astype(hx.dtype)).astype(
-            jnp.float32)
-        if final_softcap:
-            logits = jnp.tanh(logits / final_softcap) * final_softcap
-        logz = jax.scipy.special.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, tx[:, None], axis=-1)[:, 0]
-        tot, cnt = carry
-        return (tot + jnp.sum((logz - gold) * mx), cnt + jnp.sum(mx)), None
+    def scan_chunks(hb, head, tb, mb, want_dx, want_dw):
+        """(loss, d(hb) or None, fp32 d(head) or None) at cotangent 1."""
+        w = head.astype(hb.dtype)
+        cnt = jnp.maximum(jnp.sum(mb), 1.0)
 
-    (tot, cnt), _ = jax.lax.scan(
-        jax.checkpoint(block), (jnp.zeros((), jnp.float32),
-                                jnp.zeros((), jnp.float32)), (hb, tb, mb))
-    return tot / jnp.maximum(cnt, 1.0)
+        def block(carry, xs):
+            hx, tx, mx = xs
+            logits = jnp.einsum(logits_spec, hx, w).astype(jnp.float32)
+            if final_softcap:
+                logits = jnp.tanh(logits / final_softcap) * final_softcap
+            logz = jax.scipy.special.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, tx[:, None], axis=-1)[:, 0]
+            tot, dw = carry
+            tot = tot + jnp.sum((logz - gold) * mx)
+            if not (want_dx or want_dw):
+                return (tot, dw), None
+            onehot = tx[:, None] == jnp.arange(logits.shape[-1])[None, :]
+            g = jnp.exp(logits - logz[:, None]) - onehot
+            g = g * (mx / cnt)[:, None]
+            if final_softcap:
+                g = g * (1.0 - jnp.square(logits / final_softcap))
+            g = g.astype(hx.dtype)
+            dx = jnp.einsum(dx_spec, g, w) if want_dx else None
+            if want_dw:
+                dw = dw + jnp.einsum(dw_spec, g, hx,
+                                     preferred_element_type=jnp.float32)
+            return (tot, dw), dx
+
+        dw0 = jnp.zeros(head.shape, jnp.float32) if want_dw else None
+        (tot, dw), dx = jax.lax.scan(
+            block, (jnp.zeros((), jnp.float32), dw0), (hb, tb, mb))
+        return tot / cnt, dx, dw
+
+    @jax.custom_vjp
+    def loss(hb, head, tb, mb):
+        return scan_chunks(hb, head, tb, mb, False, False)[0]
+
+    def loss_fwd(hb, head, tb, mb):
+        # symbolic_zeros: each argument arrives with `.perturbed`, whether
+        # the caller differentiates with respect to it.
+        out, dx, dw = scan_chunks(hb.value, head.value, tb.value, mb.value,
+                                  hb.perturbed, head.perturbed)
+        return out, (dx, dw)
+
+    def loss_bwd(res, ct):
+        dx, dw = res
+        if dx is not None:
+            dx = (dx.astype(jnp.float32) * ct).astype(dx.dtype)
+        if dw is not None:
+            dw = (dw * ct).astype(head_dtype)
+        return dx, dw, None, None
+
+    loss.defvjp(loss_fwd, loss_bwd, symbolic_zeros=True)
+    return loss(hb, head, tb, mb)
 
 
 def _unembed_head(params: Any) -> tuple[jax.Array, bool]:
@@ -230,7 +281,9 @@ def make_train_step(
 
     loss_impl="chunked" computes cross entropy blockwise against the
     unembedding (model must support return_hidden) — the [B·S, V] fp32
-    logits buffer never materializes; backward recomputes per chunk.
+    logits buffer never materializes; each chunk's gradient is taken with
+    its logits in the forward pass, none is recomputed
+    (chunked_cross_entropy).
 
     pipeline={"microbatches": M, "chunks": C}: run the trunk through the
     compiled pipeline schedule over the `pipe` mesh axis

@@ -87,7 +87,9 @@ class TrainJobSpec:
     # trainer permutes batches + positions to match; _flash = fused inner).
     ring_attention: bool | str = False
     # "full" materializes [B,S,V] logits; "chunked" is the fused blockwise
-    # CE (no logits buffer — the long-context/large-vocab memory saver).
+    # CE (no logits buffer — the long-context/large-vocab memory saver):
+    # per loss_chunk tokens the logits once, and from them the chunk's
+    # loss and its gradients, so the head is three matmul passes a step.
     loss_impl: str = "full"
     loss_chunk: int = 1024
     # Pipeline parallelism: set mesh.pipe >= 2 and optionally
