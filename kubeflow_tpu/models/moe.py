@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from kubeflow_tpu.models.llama import Llama, LlamaConfig
+from kubeflow_tpu.utils.devices import on_tpu
 
 
 @dataclasses.dataclass(frozen=True)
@@ -237,6 +238,185 @@ class MoEBlock(nn.Module):
             y = y + shared_expert_ffn(x, ws_gate, ws_up, ws_down, w_sgate,
                                       cfg.dtype)
         return y.astype(cfg.dtype)
+
+
+@jax.custom_vjp
+def permute_rows(x: jax.Array, perm: jax.Array, inv: jax.Array) -> jax.Array:
+    """x[perm] for a permutation `perm` of the rows with inverse `inv`. Its
+    transpose is the gather g[inv], stated here because autodiff would
+    write it as a scatter-add, which a TPU serialises."""
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inv):
+    return x[perm], (perm, inv)
+
+
+def _permute_bwd(res, g):
+    perm, inv = res
+    return g[inv], None, None
+
+
+permute_rows.defvjp(_permute_fwd, _permute_bwd)
+
+
+def sigmoid_topk_route(x: jax.Array, w_router: jax.Array, bias: jax.Array,
+                       k: int, scale: float):
+    """DeepSeek-V3-style routing over all experts, fp32 at full precision
+    (a top-k over 256 near-equal scores flips on a bf16 rounding): scores
+    s = sigmoid(x W_r); the k experts with the largest s + bias (the
+    score-correction bias steers the choice only and takes no gradient);
+    weights scale * s_i / sum of the chosen s. x [N, H]. Returns
+    (expert ids [N, k] int32, weights [N, k] fp32)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx, scale * gates / jnp.sum(gates, axis=-1, keepdims=True)
+
+
+def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, *, start: int,
+                     num_experts: int, dtype, interpret: bool | None = None):
+    """The part of a routed-expert layer that the experts held here give:
+    sum over the chosen experts e in [start, start + held) of weight_e *
+    SwiGLU_e(x). Dropless and without capacity: the token-expert pairs are
+    sorted by expert and the three products run as grouped matmuls over the
+    held slice only (`megablox.gmm` with `group_offset`: tiles of absent
+    experts are neither read nor computed, their rows come back zero). On
+    an `expert` mesh axis this is one shard's work, its result summed over
+    the axis by the caller; on one chip it is the whole layer's routed part
+    as far as this chip can know it.
+
+    x [N, H]; idx, weights [N, K] from the router over all `num_experts`;
+    w_gate, w_up [held, H, M], w_down [held, M, H]. Returns (y [N, H],
+    pairs routed here [scalar], tokens of each held expert [held])."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    if interpret is None:
+        interpret = not on_tpu()
+    n, hidden = x.shape
+    k = idx.shape[1]
+    held = w_gate.shape[0]
+    pairs = n * k
+    flat = idx.reshape(pairs)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(pairs, dtype=jnp.int32))
+    sizes = jnp.zeros((num_experts,), jnp.int32).at[flat].add(1)
+    tm = min(128, pairs)
+    pad = -pairs % tm
+    xs = permute_rows(jnp.repeat(x.astype(dtype), k, axis=0), order, inv)
+    if pad:  # rows past every group: never read, returned as zeros
+        xs = jnp.pad(xs, ((0, pad), (0, 0)))
+    offset = jnp.asarray(start, jnp.int32)
+
+    def grouped(lhs, rhs):
+        tiling = (tm, min(1024, lhs.shape[1]), min(1024, rhs.shape[2]))
+        return megablox.gmm(lhs, rhs.astype(dtype), sizes, dtype, tiling,
+                            offset, None, False, interpret)
+
+    h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+    out = grouped(h, w_down)[:pairs]
+    out = permute_rows(out, inv, order).reshape(n, k, hidden)
+    local = jnp.logical_and(idx >= start, idx < start + held)
+    w_local = jnp.where(local, weights, 0.0).astype(dtype)
+    y = jnp.einsum("nk,nkh->nh", w_local, out)
+    return y, jnp.sum(local), jax.lax.dynamic_slice(sizes, (start,), (held,))
+
+
+#: Tokens the routed part of `HeldExpertsBlock` handles at a time.
+_ROUTED_BLOCK_TOKENS = 4096
+
+
+class HeldExpertsBlock(nn.Module):
+    """A routed-expert FFN that is told which experts it holds: the router
+    keeps its published width (`num_experts`) and top-k, the parameters are
+    those of experts [start, start + held) only, and the result is the
+    chosen *held* experts' part plus the shared expert. What the absent
+    experts would add is left out (models/kimi_linear.py says why).
+
+    Sows nothing; returns (y, pairs routed here / all pairs, busiest held
+    expert / mean held expert) for the trunk to report."""
+
+    hidden_size: int
+    expert_width: int
+    num_experts: int
+    experts_per_token: int
+    experts_held: tuple  # (start, count)
+    routed_scale: float
+    shared_width: int = 0  # the always-on dense SwiGLU beside them; 0: none
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        b, s, hidden = x.shape
+        start, held = self.experts_held
+        if not 0 <= start <= start + held <= self.num_experts:
+            raise ValueError(f"experts_held {self.experts_held} outside "
+                             f"0..{self.num_experts}")
+        init = nn.initializers.lecun_normal()
+        w_router = self.param(
+            "router", nn.with_logical_partitioning(init, ("embed", None)),
+            (hidden, self.num_experts), jnp.float32)
+        bias = self.param(
+            "e_score_correction_bias", nn.with_logical_partitioning(
+                nn.initializers.zeros_init(), (None,)),
+            (self.num_experts,), jnp.float32)
+
+        def expert_w(name, shape, axes):
+            # lecun_normal over [in, out]; the leading dim is a batch of
+            # independent experts.
+            return self.param(
+                name, nn.with_logical_partitioning(
+                    nn.initializers.variance_scaling(
+                        1.0, "fan_in", "truncated_normal", in_axis=-2,
+                        out_axis=-1, batch_axis=(0,)), axes),
+                shape, self.param_dtype)
+
+        m = self.expert_width
+        w_gate = expert_w("w_gate", (held, hidden, m),
+                          ("expert", "embed", "expert_mlp"))
+        w_up = expert_w("w_up", (held, hidden, m),
+                        ("expert", "embed", "expert_mlp"))
+        w_down = expert_w("w_down", (held, m, hidden),
+                          ("expert", "expert_mlp", "embed"))
+        # The sorted token-expert pairs are K rows a token, nearly all of
+        # them other chips' and empty here, so the routed part walks the
+        # tokens in blocks (recomputed in the backward) and its buffers
+        # stay a block's size. Routing is per token: blocks change nothing.
+        n = b * s
+        blocks = max(1, n // _ROUTED_BLOCK_TOKENS)
+        while n % blocks:
+            blocks -= 1
+
+        def routed(xb):
+            with jax.named_scope("moe_route"):
+                idx, weights = sigmoid_topk_route(
+                    xb, w_router, bias, self.experts_per_token,
+                    self.routed_scale)
+            with jax.named_scope("moe_experts"):
+                return held_experts_ffn(
+                    xb, idx, weights, w_gate, w_up, w_down, start=start,
+                    num_experts=self.num_experts, dtype=self.dtype)
+
+        y, here, load = jax.lax.map(
+            jax.checkpoint(routed), x.reshape(blocks, n // blocks, hidden))
+        here, load = jnp.sum(here), jnp.sum(load, axis=0)
+        y = y.reshape(b, s, hidden)
+        if self.shared_width:
+            from kubeflow_tpu.models.llama import MLPBlock
+
+            with jax.named_scope("moe_shared"):
+                y = y + MLPBlock(LlamaConfig(
+                    hidden_size=hidden, intermediate_size=self.shared_width,
+                    dtype=self.dtype, param_dtype=self.param_dtype),
+                    name="shared_expert")(x)
+        load = load.astype(jnp.float32)
+        return (y.astype(self.dtype),
+                here.astype(jnp.float32) / (b * s * self.experts_per_token),
+                jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9))
 
 
 def MoELlama(cfg: MoEConfig, **kwargs: Any) -> Llama:

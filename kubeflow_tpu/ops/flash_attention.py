@@ -12,16 +12,23 @@ Backward is the standard two-pass flash recipe with saved row stats:
 the forward additionally writes LSE (logsumexp per q row); the backward
 precomputes delta = rowsum(dO·O), then
   * a dq kernel over (batch·head, q blocks) streaming visible kv blocks,
-  * a dk/dv kernel over (batch·kv-head, kv blocks) streaming the visible q
-    blocks of every q head in the GQA group (zero-copy: the grouped q/dO
-    views are reshapes, never materialized per-head copies).
+  * a dk/dv kernel over (batch·kv-head, kv blocks, group · q blocks): the
+    q, dO, LSE and delta blocks of every q head in the GQA group stream
+    through the innermost grid axis (zero-copy: the grouped views are
+    reshapes, never materialized per-head copies) into fp32 dk/dv
+    accumulators in VMEM, so the kernel's footprint does not grow with
+    the sequence; blocks a kv block cannot see are neither fetched nor
+    computed.
 Neither pass materializes an O(S·T) score matrix in HBM.
 
-Layout: q [B, S, H, D], k/v [B, T, KH, D] with GQA (H % KH == 0). The grid
-is (B*H, Q_blocks); each program owns one q block and loops over its visible
+Layout: q [B, S, H, D], k [B, T, KH, D], v [B, T, KH, Dv] with GQA
+(H % KH == 0); Dv may differ from D (latent attention: keys of 192, values
+of 128) and the output is [B, S, H, Dv]. The forward and dq grids are
+(B*H, Q_blocks); each program owns one q block and loops over its visible
 kv blocks. K/V stay sequence-complete in VMEM per (batch, head) program —
-fine through ~8k tokens at D=128 in bf16; ring attention (ring_attention.py)
-is the path past that.
+fine through ~8k tokens in bf16 (the scoped-VMEM limit is raised to what
+the whole rows need when that passes the default); ring attention
+(ring_attention.py) is the path past that.
 """
 
 from __future__ import annotations
@@ -35,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import shard_map
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU lowering)
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from kubeflow_tpu.parallel.mesh import dp_like_axes
@@ -141,6 +148,29 @@ def _kv_visible(j, block_q, block_kv, seq_q_pad, mask: MaskSpec):
     return causal_first, bound
 
 
+#: Mosaic's default scoped-VMEM limit on the chips this runs on (16 MiB), and
+#: how much of it the whole-row K/V buffers may take before the forward and
+#: dq calls ask for more.
+_VMEM_DEFAULT = 16 * 2 ** 20
+_VMEM_ROWS_SHARE = 0.6
+
+
+def _whole_rows_vmem(t: int, d: int, dv: int, dtype, interpret: bool) -> dict:
+    """`pallas_call` keywords for a kernel that keeps K [t, d] and V [t, dv]
+    whole in VMEM: nothing while their double-buffered, lane-padded copies
+    leave the default limit room for the rest (every shape the kernels ran
+    at before latent attention, so those calls are unchanged), else a
+    scoped-VMEM limit of what the rows take plus the default."""
+    if interpret:
+        return {}
+    lanes = lambda n: -(-n // 128) * 128
+    rows = 2 * t * (lanes(d) + lanes(dv)) * jnp.dtype(dtype).itemsize
+    if rows <= _VMEM_ROWS_SHARE * _VMEM_DEFAULT:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=rows + _VMEM_DEFAULT)}
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_q: int,
                       block_kv: int, seq_kv: int, mask: MaskSpec,
                       sm_scale: float, segments: bool = False):
@@ -185,8 +215,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_q: int,
             preferred_element_type=jnp.float32)
         return acc_new, m_new, l_new
 
-    d = q_ref.shape[-1]
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
+    acc0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc, m, l = jax.lax.fori_loop(first_visible, num_visible, body,
@@ -206,7 +235,7 @@ def _flash_fwd(q3, k3, v3, seg_q3, seg_kv3, *, group: int, heads: int,
     None) carry packed-sequence segment ids, read zero-copy per batch row
     via b // heads index_maps. Returns (o3, lse [B*H, S])."""
     bh, s, d = q3.shape
-    t = k3.shape[1]
+    t, dv = k3.shape[1], v3.shape[2]
     grid = (bh, pl.cdiv(s, block_q))
     segments = seg_q3 is not None
     kernel = functools.partial(
@@ -215,7 +244,7 @@ def _flash_fwd(q3, k3, v3, seg_q3, seg_kv3, *, group: int, heads: int,
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         pl.BlockSpec((1, t, d), lambda b, i: (b // group, 0, 0)),
-        pl.BlockSpec((1, t, d), lambda b, i: (b // group, 0, 0)),
+        pl.BlockSpec((1, t, dv), lambda b, i: (b // group, 0, 0)),
     ]
     args = [q3, k3, v3]
     if segments:
@@ -229,14 +258,15 @@ def _flash_fwd(q3, k3, v3, seg_q3, seg_kv3, *, group: int, heads: int,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
+            jax.ShapeDtypeStruct((bh, s, dv), q3.dtype),
             jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
         ],
         interpret=interpret,
+        **_whole_rows_vmem(t, d, dv, k3.dtype, interpret),
     )(*args)
 
 
@@ -296,79 +326,73 @@ def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                           seq_q: int, seq_kv: int, seq_q_pad: int, group: int,
                           mask: MaskSpec, sm_scale: float,
                           segments: bool = False):
+    """One (kv block, q block of one head of the group) step: the innermost
+    grid axis walks the group's heads and, within a head, the q blocks in
+    order; dk and dv accumulate in fp32 scratch from its first step to its
+    last, where they are written out."""
     if segments:
-        qs_ref, ks_ref, dk_ref, dv_ref = rest
+        qs_ref, ks_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
     else:
-        dk_ref, dv_ref = rest
+        dk_ref, dv_ref, dk_acc, dv_acc = rest
     j = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)                 # [bkv, D]
-    v = v_ref[0].astype(jnp.float32)
-    cols = jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_kv), 1) + j * block_kv
-    kv_valid = cols < seq_kv
+    step = pl.program_id(2)
+    num_q = seq_q_pad // block_q
+    qi = step % num_q
 
-    first, num_q_blocks = _kv_visible(j, block_q, block_kv, seq_q_pad, mask)
+    @pl.when(step == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    d = q_ref.shape[-1]
-    dk0 = jnp.zeros((block_kv, d), jnp.float32)
-    dv0 = jnp.zeros((block_kv, d), jnp.float32)
+    first, bound = _kv_visible(j, block_q, block_kv, seq_q_pad, mask)
 
-    def make_body(g):
-        base = g * seq_q_pad
+    @pl.when(jnp.logical_and(qi >= first, qi < bound))
+    def _():
+        k = k_ref[0].astype(jnp.float32)             # [bkv, D]
+        v = v_ref[0].astype(jnp.float32)             # [bkv, Dv]
+        cols = jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_kv), 1) + j * block_kv
+        q = q_ref[0].astype(jnp.float32) * sm_scale
+        do = do_ref[0].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        rows = jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_kv), 0) + qi * block_q
+        valid = jnp.logical_and(cols < seq_kv, rows < seq_q)
+        valid = _apply_mask(valid, rows, cols, mask)
+        if segments:
+            valid = jnp.logical_and(
+                valid, qs_ref[0, :, 0][:, None] == ks_ref[0, :, 0][None, :])
+        p = jnp.where(valid, jnp.exp(s - lse_ref[0]), 0.0)
+        dv_acc[...] += jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0])
+        # q in the score matmul carried sm_scale, so ds . q is d/dk of
+        # (q·k·scale) already.
+        dk_acc[...] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-        def body(qi, carry):
-            dk, dv = carry
-            off = base + qi * block_q
-            q = q_ref[0, pl.ds(off, block_q), :].astype(
-                jnp.float32) * sm_scale
-            do = do_ref[0, pl.ds(off, block_q), :].astype(jnp.float32)
-            lse = lse_ref[0, pl.ds(off, block_q), :]
-            delta = delta_ref[0, pl.ds(off, block_q), :]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            rows = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 0) + qi * block_q
-            valid = jnp.logical_and(kv_valid, rows < seq_q)
-            valid = _apply_mask(valid, rows, cols, mask)
-            if segments:
-                valid = jnp.logical_and(
-                    valid,
-                    qs_ref[0, pl.ds(qi * block_q, block_q), 0][:, None]
-                    == ks_ref[0, :, 0][None, :])
-            p = jnp.where(valid, jnp.exp(s - lse), 0.0)
-            dv_new = dv + jax.lax.dot_general(
-                p, do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = p * (dp - delta)
-            dk_new = dk + jax.lax.dot_general(
-                ds, q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return dk_new, dv_new
-
-        return body
-
-    dk, dv = dk0, dv0
-    for g in range(group):  # static, small (GQA group)
-        dk, dv = jax.lax.fori_loop(first, num_q_blocks, make_body(g),
-                                   (dk, dv))
-    # q in the score matmul carried sm_scale; dk restores the q-side factor
-    # so dk is d/dk of (q·k·scale): ds already includes the scale via q.
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    @pl.when(step == group * num_q - 1)
+    def _():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _flatten_heads(q, k, v):
-    """[B,S,H,D] → q3 [B*H, S, D], k3/v3 [B*KH, T, D] — no GQA repetition;
-    the kernel's index_map maps q heads onto shared kv heads."""
+    """[B,S,H,D] → q3 [B*H, S, D], k3 [B*KH, T, D], v3 [B*KH, T, Dv] — no
+    GQA repetition; the kernel's index_map maps q heads onto shared kv
+    heads."""
     b, s, h, d = q.shape
     t, kh = k.shape[1], k.shape[2]
     q3 = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     k3 = k.transpose(0, 2, 1, 3).reshape(b * kh, t, d)
-    v3 = v.transpose(0, 2, 1, 3).reshape(b * kh, t, d)
+    v3 = v.transpose(0, 2, 1, 3).reshape(b * kh, t, v.shape[3])
     return q3, k3, v3
 
 
@@ -384,7 +408,8 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
                     block_kv: int = 512, interpret: bool | None = None,
                     segment_ids: jax.Array | None = None,
                     mask: MaskSpec | str | None = None):
-    """Flash attention. q [B,S,H,D]; k,v [B,T,KH,D]; returns [B,S,H,D].
+    """Flash attention. q [B,S,H,D]; k [B,T,KH,D]; v [B,T,KH,Dv]; returns
+    [B,S,H,Dv]. Scores scale by D^-½.
 
     Forward and backward both run fused Pallas kernels (O(S) memory); the
     backward uses the saved LSE row stats (two-pass dq then dk/dv).
@@ -505,7 +530,7 @@ def _attn_impl(q, k, v, causal, block_q, block_kv, interpret,
     o3, lse = _flash_fwd(q3, k3, v3, sq3, skv3, group=h // kh, heads=h,
                          mask=spec, block_q=block_q, block_kv=block_kv,
                          seq_kv=t, sm_scale=sm_scale, interpret=interpret)
-    out = o3[:, :s].reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    out = o3[:, :s].reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)
     return out, (o3, lse)
 
 
@@ -544,11 +569,12 @@ def _flash_bwd_impl(q, k, v, o3, lse, g, g_lse, causal, block_q, block_kv,
     spec = _norm_mask(causal, mask)
     sm_scale = 1.0 / (d ** 0.5)
 
+    d_v = v.shape[3]
     q3, k3, v3 = _flatten_heads(q, k, v)
     q3 = _pad_seq(q3, block_q)
     k3 = _pad_seq(k3, block_kv)
     v3 = _pad_seq(v3, block_kv)
-    do3 = _pad_seq(g.transpose(0, 2, 1, 3).reshape(b * h, s, d), block_q)
+    do3 = _pad_seq(g.transpose(0, 2, 1, 3).reshape(b * h, s, d_v), block_q)
     s_pad, t_pad = q3.shape[1], k3.shape[1]
     bh, bkh = b * h, b * kh
 
@@ -571,8 +597,8 @@ def _flash_bwd_impl(q, k, v, o3, lse, g, g_lse, causal, block_q, block_kv,
     dq_specs = [
         pl.BlockSpec((1, block_q, d), lambda bi, i: (bi, i, 0)),
         pl.BlockSpec((1, t_pad, d), lambda bi, i: (bi // group, 0, 0)),
-        pl.BlockSpec((1, t_pad, d), lambda bi, i: (bi // group, 0, 0)),
-        pl.BlockSpec((1, block_q, d), lambda bi, i: (bi, i, 0)),
+        pl.BlockSpec((1, t_pad, d_v), lambda bi, i: (bi // group, 0, 0)),
+        pl.BlockSpec((1, block_q, d_v), lambda bi, i: (bi, i, 0)),
         pl.BlockSpec((1, block_q, 1), lambda bi, i: (bi, i, 0)),
         pl.BlockSpec((1, block_q, 1), lambda bi, i: (bi, i, 0)),
     ]
@@ -590,52 +616,71 @@ def _flash_bwd_impl(q, k, v, o3, lse, g, g_lse, causal, block_q, block_kv,
         out_specs=pl.BlockSpec((1, block_q, d), lambda bi, i: (bi, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
         interpret=interpret,
+        **_whole_rows_vmem(t_pad, d, d_v, k3.dtype, interpret),
     )(*dq_args)
 
     # Grouped (per kv head) views of the q-side tensors: pure reshapes of the
-    # [B*H, ...] layout since q head h serves kv head h // group.
+    # [B*H, ...] layout since q head h serves kv head h // group. Row block
+    # g * num_q + qi of a view is q block qi of the group's head g.
     qg = q3.reshape(bkh, group * s_pad, d)
-    dog = do3.reshape(bkh, group * s_pad, d)
+    dog = do3.reshape(bkh, group * s_pad, d_v)
     lseg = lse.reshape(bkh, group * s_pad, 1)
     deltag = delta.reshape(bkh, group * s_pad, 1)
+    num_q = s_pad // block_q
+
+    def q_block(j, step):
+        """The q block a grid step reads. A step whose block this kv block
+        cannot see is skipped by the kernel; pointing it at a visible block
+        keeps the pipeline from fetching rows nobody reads."""
+        first, bound = _kv_visible(j, block_q, block_kv, s_pad, spec)
+        return jnp.clip(step % num_q, first, jnp.maximum(bound - 1, first))
+
+    def q_side(bi, j, step):
+        return (bi, (step // num_q) * num_q + q_block(j, step), 0)
 
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, block_q=block_q, block_kv=block_kv, seq_q=s,
         seq_kv=t, seq_q_pad=s_pad, group=group, mask=spec,
         sm_scale=sm_scale, segments=segments)
     dkv_specs = [
-        pl.BlockSpec((1, group * s_pad, d), lambda bi, j: (bi, 0, 0)),
-        pl.BlockSpec((1, group * s_pad, d), lambda bi, j: (bi, 0, 0)),
-        pl.BlockSpec((1, group * s_pad, 1), lambda bi, j: (bi, 0, 0)),
-        pl.BlockSpec((1, group * s_pad, 1), lambda bi, j: (bi, 0, 0)),
-        pl.BlockSpec((1, block_kv, d), lambda bi, j: (bi, j, 0)),
-        pl.BlockSpec((1, block_kv, d), lambda bi, j: (bi, j, 0)),
+        pl.BlockSpec((1, block_q, d), q_side),
+        pl.BlockSpec((1, block_q, d_v), q_side),
+        pl.BlockSpec((1, block_q, 1), q_side),
+        pl.BlockSpec((1, block_q, 1), q_side),
+        pl.BlockSpec((1, block_kv, d), lambda bi, j, step: (bi, j, 0)),
+        pl.BlockSpec((1, block_kv, d_v), lambda bi, j, step: (bi, j, 0)),
     ]
     dkv_args = [qg, dog, lseg, deltag, k3, v3]
     if segments:
         dkv_specs += [
-            pl.BlockSpec((1, sq3.shape[1], 1), lambda bi, j: (bi // kh, 0, 0)),
-            pl.BlockSpec((1, block_kv, 1), lambda bi, j: (bi // kh, j, 0)),
+            pl.BlockSpec((1, block_q, 1),
+                         lambda bi, j, step: (bi // kh, q_block(j, step), 0)),
+            pl.BlockSpec((1, block_kv, 1),
+                         lambda bi, j, step: (bi // kh, j, 0)),
         ]
         dkv_args += [sq3, skv3]
     dk3, dv3 = pl.pallas_call(
         dkv_kernel,
-        grid=(bkh, t_pad // block_kv),
+        grid=(bkh, t_pad // block_kv, group * num_q),
         in_specs=dkv_specs,
         out_specs=[
-            pl.BlockSpec((1, block_kv, d), lambda bi, j: (bi, j, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda bi, j: (bi, j, 0)),
+            pl.BlockSpec((1, block_kv, d), lambda bi, j, step: (bi, j, 0)),
+            pl.BlockSpec((1, block_kv, d_v), lambda bi, j, step: (bi, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bkh, t_pad, d), k.dtype),
-            jax.ShapeDtypeStruct((bkh, t_pad, d), v.dtype),
+            jax.ShapeDtypeStruct((bkh, t_pad, d_v), v.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
+                        pltpu.VMEM((block_kv, d_v), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*dkv_args)
 
     dq = dq3[:, :s].reshape(b, h, s, d).transpose(0, 2, 1, 3)
     dk = dk3[:, :t].reshape(b, kh, t, d).transpose(0, 2, 1, 3)
-    dv = dv3[:, :t].reshape(b, kh, t, d).transpose(0, 2, 1, 3)
+    dv = dv3[:, :t].reshape(b, kh, t, d_v).transpose(0, 2, 1, 3)
     return dq, dk, dv
 
 

@@ -17,7 +17,8 @@ def naive_attention(q, k, v, *, causal: bool = True,
                     segment_ids=None, segment_ids_kv=None,
                     mask=None, softcap: float = 0.0,
                     windowed=None, k_scale=None, v_scale=None) -> jax.Array:
-    """q: [B,S,H,D]; k,v: [B,T,KH,D] with H % KH == 0; fp32 softmax.
+    """q: [B,S,H,D]; k: [B,T,KH,D]; v: [B,T,KH,Dv] with H % KH == 0 (Dv may
+    differ from D: the result is [B,S,H,Dv]); fp32 softmax.
     Causality is masked by absolute positions when given (packed/offset
     sequences), else by array index. `segment_ids` [B,S] (and optionally a
     separate kv set) additionally confine attention within equal-id spans
@@ -91,4 +92,4 @@ def naive_attention(q, k, v, *, causal: bool = True,
         probs = probs * v_scale.transpose(0, 2, 1)[:, :, None, None, :]
     probs = probs.astype(q.dtype)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
-    return out.reshape(b, s, h, d)
+    return out.reshape(b, s, h, v.shape[-1])

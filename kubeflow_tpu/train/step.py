@@ -369,11 +369,13 @@ def make_train_step(
         else:
             main = cross_entropy_loss(out, batch["targets"],
                                       batch.get("mask"))
-        return main + aux, aux
+        return main + aux, {"aux_loss": aux}
 
     def compute_loss(params, batch):
         # mutable=["aux_loss"]: MoE routers sow load-balance penalties there
-        # (models/moe.py); dense models leave it empty.
+        # (models/moe.py); dense models leave it empty. "counters": scalars
+        # a model wants on the log rows (models/kimi_linear.py sows its
+        # routing counters there); they ride beside aux_loss.
         kwargs = dict(model_kwargs)
         # Packed-sequence batches carry their own segment ids and
         # per-segment restarting positions (models honor both; the fused
@@ -385,8 +387,8 @@ def make_train_step(
         if loss_impl == "chunked":
             kwargs["return_hidden"] = True
         out, mutated = model.apply(
-            {"params": params}, batch["inputs"], mutable=["aux_loss"],
-            **kwargs)
+            {"params": params}, batch["inputs"],
+            mutable=["aux_loss", "counters"], **kwargs)
         if loss_impl == "chunked":
             head, vocab_major = _unembed_head(params)
             main = chunked_cross_entropy(
@@ -405,7 +407,9 @@ def make_train_step(
         aux = jnp.zeros((), jnp.float32)
         for leaf in jax.tree.leaves(mutated.get("aux_loss", {})):
             aux = aux + jnp.sum(leaf)
-        return main + aux, aux
+        counters = {name: jnp.sum(jnp.stack(sown)).astype(jnp.float32)
+                    for name, sown in mutated.get("counters", {}).items()}
+        return main + aux, {"aux_loss": aux, **counters}
 
     def constrain_batch(x):
         # dim 0 is always the batch; dim 1 is the sequence only for
@@ -440,9 +444,11 @@ def make_train_step(
             return inner_loss_fn(fsdp.gather_params(master), b)
 
     def loss_and_grads(loss_fn, target, batch):
-        """(loss, aux, grads) w.r.t. `target`, with the gradient-
+        """(loss, extras, grads) w.r.t. `target`, with the gradient-
         accumulation scan when accum_steps > 1 — ONE copy of the
-        microbatching machinery shared by full fine-tune and LoRA."""
+        microbatching machinery shared by full fine-tune and LoRA.
+        `extras` is the loss function's dict of scalars for the log row
+        (`aux_loss` and a model's counters), averaged over microbatches."""
         if accum_steps > 1:
             # Scan over row-slices; the grad carry costs one extra
             # target-sized buffer.
@@ -468,14 +474,18 @@ def make_train_step(
                     mgrads = fsdp.constrain_master_grads(mgrads)
                 gsum, lsum, asum = carry
                 return (jax.tree.map(jnp.add, gsum, mgrads), lsum + mloss,
-                        asum + maux), None
+                        jax.tree.map(jnp.add, asum, maux)), None
 
             zeros = jax.tree.map(jnp.zeros_like, target)
+            extras0 = jax.tree.map(
+                lambda x: jnp.zeros(x.shape, x.dtype), jax.eval_shape(
+                    lambda: loss_fn(target, jax.tree.map(
+                        lambda x: x[0], micro))[1]))
             (gsum, lsum, asum), _ = jax.lax.scan(
-                body, (zeros, jnp.zeros((), jnp.float32),
-                       jnp.zeros((), jnp.float32)), micro)
+                body, (zeros, jnp.zeros((), jnp.float32), extras0), micro)
             grads = jax.tree.map(lambda g: g / accum_steps, gsum)
-            return lsum / accum_steps, asum / accum_steps, grads
+            return (lsum / accum_steps,
+                    jax.tree.map(lambda a: a / accum_steps, asum), grads)
         batch = jax.tree.map(constrain_batch, batch)
         (loss, aux), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(target, batch)
@@ -501,7 +511,7 @@ def make_train_step(
         new_state = state.replace(
             step=state.step + 1, params=combine(new_train, frozen),
             opt_state=new_opt)
-        return new_state, {"loss": loss, "aux_loss": aux,
+        return new_state, {"loss": loss, **aux,
                            "grad_norm": optax.global_norm(grads),
                            "step": new_state.step}
 
@@ -509,7 +519,7 @@ def make_train_step(
         loss, aux, grads = loss_and_grads(loss_impl_fn, state.params, batch)
         new_state = state.apply_gradients(grads)
         gnorm = optax.global_norm(grads)
-        return new_state, {"loss": loss, "aux_loss": aux,
+        return new_state, {"loss": loss, **aux,
                            "grad_norm": gnorm, "step": new_state.step}
 
     jitted = jax.jit(lora_step if trainable == "lora" else step,

@@ -1039,6 +1039,11 @@ class Trainer:
                         # tpk-lint: allow(host-sync) reason=log-boundary only, value already on host after the window fetch
                         last_metrics["aux_loss"] = float(
                             metrics["aux_loss"])
+                    # A model's own counters (the `counters` collection).
+                    for name in metrics.keys() - {"loss", "grad_norm",
+                                                  "aux_loss", "step"}:
+                        # tpk-lint: allow(host-sync) reason=log-boundary only, value already on host after the window fetch
+                        last_metrics[name] = float(metrics[name])
                     self.logger.log(step + 1, last_metrics)
                     timer.start()
                     win_reset()

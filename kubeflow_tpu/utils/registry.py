@@ -112,6 +112,32 @@ def _ensure_builtin() -> None:
         import dataclasses
         return _moe(dataclasses.replace(moe.mixtral_8x7b(), **kw))
 
+    from kubeflow_tpu.models import kimi_linear
+
+    def _kimi_linear(cfg):
+        # num_params feeds the trainer's own `mfu` row (6 * num_params *
+        # tokens/s): the weights a token is multiplied by on this chip,
+        # not the experts held (most idle for any one token) and not the
+        # published model's (most of them on other chips).
+        return kimi_linear.KimiLinear(cfg), {
+            "task": "lm", "example_shape": (1, 16), "example_dtype": "int32",
+            "num_params": cfg.active_params,
+            "held_params": cfg.held_params,
+            "published_params": cfg.published_params,
+            "vocab_size": cfg.vocab_size, "config": cfg}
+
+    @register_model("kimi_linear_tiny")
+    def _kimi_linear_tiny(**kw):
+        import dataclasses
+        return _kimi_linear(
+            dataclasses.replace(kimi_linear.kimi_linear_tiny(), **kw))
+
+    @register_model("kimi_linear_48b")
+    def _kimi_linear_48b(**kw):
+        import dataclasses
+        return _kimi_linear(
+            dataclasses.replace(kimi_linear.kimi_linear_48b(), **kw))
+
     @register_model("bert_tiny")
     def _bert_tiny(**kw):
         import dataclasses

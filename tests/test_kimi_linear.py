@@ -1,0 +1,492 @@
+"""Kimi-Linear (models/kimi_linear.py) against its plain reference
+(benchmarks/reference/kimi_linear.py) on seeded random weights at toy widths:
+the chunked KDA core against the recurrence, latent attention through the
+flash kernels against a masked softmax, the held-experts layer against a loop
+over experts, the shares of an expert-parallel layer adding up to the uncut
+layer, the whole model's loss and gradients; and the flash backward this model
+forced (value width != key width, q-side rows streamed through the grid).
+"""
+
+import dataclasses
+import functools
+import importlib.util
+import json
+import os
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental import pallas as pl
+
+from kubeflow_tpu.models import kimi_linear as kl
+from kubeflow_tpu.models import moe
+from kubeflow_tpu.models.moe import HeldExpertsBlock
+from kubeflow_tpu.ops import flash_attention as fa
+from kubeflow_tpu.ops.kda import kda_chunked
+from kubeflow_tpu.ops.reference import naive_attention
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load_reference():
+    spec = importlib.util.spec_from_file_location(
+        "kimi_linear_reference",
+        os.path.join(ROOT, "benchmarks", "reference", "kimi_linear.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = _load_reference()
+
+
+def rel(a, b):
+    return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+
+
+def ref_cfg(cfg: kl.KimiLinearConfig) -> dict:
+    return {k: getattr(cfg, k) for k in (
+        "hidden_size", "num_layers", "kda_layers", "full_attn_layers",
+        "first_k_dense_replace", "kda_heads", "kda_head_dim", "kda_norm_eps",
+        "num_heads", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+        "v_head_dim", "num_experts", "experts_per_token", "experts_held",
+        "routed_scaling_factor", "rms_eps")}
+
+
+# -- KDA: chunked against the recurrence -------------------------------------
+
+def kda_inputs(t, decay, seed=0, b=2, h=3, dk=16, dv=8):
+    r = np.random.default_rng(seed)
+    q = r.normal(size=(b, t, h, dk))
+    k = r.normal(size=(b, t, h, dk))
+    q = q / np.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(dk)
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+    v = r.normal(size=(b, t, h, dv))
+    g = -decay * np.abs(r.normal(size=(b, t, h, dk)))
+    beta = 1 / (1 + np.exp(-r.normal(size=(b, t, h))))
+    return [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta)]
+
+
+@pytest.mark.parametrize("t,decay", [
+    (128, 0.1),   # chunk-aligned
+    (100, 0.1),   # ragged: the tail is padded with steps that change nothing
+    (128, 10.0),  # sum of g over a chunk far below -300: the textbook
+    (70, 10.0),   # factorisation's exp(-G) is inf in fp32 here
+])
+def test_kda_chunked_matches_recurrence(t, decay):
+    args = kda_inputs(t, decay)
+    if decay > 1:
+        assert float(jnp.min(jnp.sum(args[3][:, :64], axis=1))) < -300
+
+    def scalar(fn):
+        weights = jnp.cos(jnp.arange(args[2].shape[-1]))
+        return lambda *a: jnp.sum(fn(*a) * weights)
+
+    out, want = kda_chunked(*args), ref.kda_recurrence(*args)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    assert float(jnp.max(jnp.abs(out - want))) < 1e-5
+    got = jax.grad(scalar(kda_chunked), argnums=(0, 1, 2, 3, 4))(*args)
+    exp = jax.grad(scalar(ref.kda_recurrence), argnums=(0, 1, 2, 3, 4))(*args)
+    for name, a, e in zip("q k v g beta".split(), got, exp):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert rel(a, e) < 1e-4, name
+
+
+@pytest.mark.parametrize("chunk,sub", [(64, 24), (40, 16)])
+def test_kda_refuses_a_chunk_its_blocks_do_not_tile(chunk, sub):
+    """The Neumann product needs a power-of-two block, the block
+    substitution whole blocks in a chunk."""
+    with pytest.raises(ValueError, match="multiple of sub"):
+        kda_chunked(*kda_inputs(64, 0.1), chunk=chunk, sub=sub)
+
+
+# -- MLA through the flash kernels against the masked softmax ----------------
+
+@pytest.mark.parametrize("t", [64, 50])
+def test_mla_matches_masked_softmax(t):
+    cfg = dataclasses.replace(kl.kimi_linear_tiny(), dtype=jnp.float32,
+                              flash_block_q=32, flash_block_kv=32)
+    x = jax.random.normal(jax.random.key(1), (2, t, cfg.hidden_size))
+    mixer = kl.MLAMixer(cfg)
+    params = nn.meta.unbox(mixer.init(jax.random.key(2), x)["params"])
+
+    def prog(p, x):
+        return mixer.apply({"params": p}, x)
+
+    def plain(p, x):
+        with jax.default_matmul_precision("highest"):
+            return ref.mla_mixer(x, p, {**ref.DEFAULTS, **ref_cfg(cfg)},
+                                 q_block=16)
+
+    assert rel(prog(params, x), plain(params, x)) < 1e-5
+    probe = jnp.sin(jnp.arange(cfg.hidden_size))
+    got = jax.grad(lambda p, x: jnp.sum(prog(p, x) * probe),
+                   argnums=(0, 1))(params, x)
+    exp = jax.grad(lambda p, x: jnp.sum(plain(p, x) * probe),
+                   argnums=(0, 1))(params, x)
+    for a, e in zip(jax.tree.leaves(got), jax.tree.leaves(exp)):
+        assert rel(a, e) < 1e-4
+
+
+# -- the held-experts layer ---------------------------------------------------
+
+@pytest.fixture
+def two_blocks(monkeypatch):
+    """The expert tests' 128 tokens walked as two blocks of 64."""
+    monkeypatch.setattr(moe, "_ROUTED_BLOCK_TOKENS", 64)
+
+
+def expert_block(num_experts, held, top, shared=32):
+    return HeldExpertsBlock(
+        hidden_size=48, expert_width=32, num_experts=num_experts,
+        experts_per_token=top, experts_held=held, routed_scale=2.446,
+        shared_width=shared, dtype=jnp.float32)
+
+
+def expert_ref_cfg(block):
+    return {**ref.DEFAULTS, "experts_held": block.experts_held,
+            "experts_per_token": block.experts_per_token,
+            "routed_scaling_factor": block.routed_scale}
+
+
+@pytest.mark.parametrize("biased", [True, False])
+def test_expert_layer_matches_loop_reference(biased, two_blocks):
+    block = expert_block(32, (8, 8), 4)
+    x = jax.random.normal(jax.random.key(3), (2, 64, 48))
+    params = nn.meta.unbox(block.init(jax.random.key(4), x)["params"])
+    if biased:  # every token also picks held expert 10
+        params["e_score_correction_bias"] = (
+            jnp.zeros((32,)).at[10].set(5.0))
+
+    def prog(p, x):
+        return block.apply({"params": p}, x)
+
+    def plain(p, x):
+        with jax.default_matmul_precision("highest"):
+            return ref.moe_ffn(x, p, expert_ref_cfg(block))
+
+    (y, share, load), (want, want_share, counts) = prog(params, x), plain(
+        params, x)
+    counts = counts.astype(jnp.float32)
+    assert rel(y, want) < 1e-5
+    assert float(share) == pytest.approx(float(want_share))
+    assert float(load) == pytest.approx(
+        float(jnp.max(counts) / jnp.mean(counts)))
+    if biased:
+        assert float(load) >= 4.0  # one held expert at 4x the mean and more
+        assert int(counts[2]) == 128  # dropless: all 128 tokens reached it
+    probe = jnp.cos(jnp.arange(48))
+    got = jax.grad(lambda p, x: jnp.sum(prog(p, x)[0] * probe),
+                   argnums=(0, 1))(params, x)
+    exp = jax.grad(lambda p, x: jnp.sum(plain(p, x)[0] * probe),
+                   argnums=(0, 1))(params, x)
+    flat_got = jax.tree_util.tree_leaves_with_path(got)
+    for (path, a), e in zip(flat_got, jax.tree.leaves(exp)):
+        if not float(jnp.linalg.norm(e)):  # the bias takes no gradient
+            assert not float(jnp.linalg.norm(a)), path
+        else:
+            assert rel(a, e) < 1e-4, path
+
+
+@pytest.mark.parametrize("num_experts,held", [(16, 4), (32, 1)])
+def test_the_shares_add_up_to_the_uncut_layer(num_experts, held):
+    """What every chip of the expert-parallel job computes alike (the shared
+    expert) counted once, the routed parts of all the shares summed: the
+    uncut layer."""
+    top = 4
+    whole = expert_block(num_experts, (0, num_experts), top)
+    x = jax.random.normal(jax.random.key(5), (1, 64, 48))
+    params = nn.meta.unbox(whole.init(jax.random.key(6), x)["params"])
+    params["e_score_correction_bias"] = 0.1 * jax.random.normal(
+        jax.random.key(7), (num_experts,))
+    uncut, share_all, _ = whole.apply({"params": params}, x)
+    assert float(share_all) == pytest.approx(1.0)
+    routed = {k: v for k, v in params.items() if k != "shared_expert"}
+    uncut_routed, _, _ = expert_block(
+        num_experts, (0, num_experts), top, shared=0).apply(
+            {"params": routed}, x)
+    shared_part = uncut - uncut_routed
+    total, shares = jnp.zeros_like(uncut), 0.0
+    for start in range(0, num_experts, held):
+        mine = dict(routed, **{k: routed[k][start:start + held]
+                               for k in ("w_gate", "w_up", "w_down")})
+        y, share, _ = expert_block(
+            num_experts, (start, held), top, shared=0).apply(
+                {"params": mine}, x)
+        total, shares = total + y, shares + float(share)
+    assert shares == pytest.approx(1.0)
+    assert rel(total, uncut_routed) < 1e-5
+    assert rel(total + shared_part, uncut) < 1e-5
+
+
+# -- the whole model ----------------------------------------------------------
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_model_loss_and_gradients_match_reference(seed):
+    cfg = dataclasses.replace(kl.kimi_linear_tiny(), dtype=jnp.float32)
+    model = kl.KimiLinear(cfg)
+    toks = jnp.asarray(np.random.default_rng(seed).integers(
+        0, cfg.vocab_size, (2, 41)), jnp.int32)
+    inputs, targets = toks[:, :-1], toks[:, 1:]
+    params = nn.meta.unbox(
+        model.init(jax.random.key(seed), inputs)["params"])
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, p: (0.1 * jax.random.normal(jax.random.key(9), p.shape)
+                         if "e_score_correction_bias" in str(path) else p),
+        params)
+
+    from kubeflow_tpu.train.step import cross_entropy_loss
+
+    def prog(p):
+        out, sown = model.apply({"params": p}, inputs, mutable=["counters"])
+        return cross_entropy_loss(out, targets), sown["counters"]
+
+    (loss, counters), grads = jax.value_and_grad(prog, has_aux=True)(params)
+    (want, want_counters), want_grads = ref.loss_and_grads(
+        params, inputs, targets, ref_cfg(cfg))
+    assert float(loss) == pytest.approx(float(want), abs=1e-5)
+    for name, value in want_counters.items():
+        assert float(counters[name][0]) == pytest.approx(float(value))
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    assert len(flat) > 100
+    for (path, g), w in zip(flat, jax.tree.leaves(want_grads)):
+        if "e_score_correction_bias" in str(path):
+            assert not float(jnp.linalg.norm(g)), path
+        else:
+            assert rel(g, w) < 2e-4, path
+
+
+def test_registry_reports_held_and_published_parameters_apart():
+    from kubeflow_tpu.utils import registry
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "kimi-linear-48b-a3b-d5e8.json")) as fh:
+        entry = json.load(fh)
+    _, info = registry.build_model(entry["registry_model"],
+                                   **entry["model_kwargs"])
+    # ISSUE 28's arithmetic: 602M held, 336M multiplied a token, and the
+    # published model's 48B with every expert of every layer.
+    assert info["held_params"] == pytest.approx(602e6, rel=0.005)
+    assert info["num_params"] == pytest.approx(336e6, rel=0.005)
+    assert info["num_params"] < info["held_params"]
+    _, full = registry.build_model("kimi_linear_48b")
+    assert full["published_params"] == pytest.approx(49.1e9, rel=0.01)
+
+
+#: One layer of each mixer, the second with experts: enough for the step's
+#: plumbing, a third of the compile.
+TWO_LAYERS = {"num_layers": 2, "kda_layers": [1], "full_attn_layers": [2]}
+
+
+def test_trainer_rows_carry_the_routing_counters(tmp_path):
+    from kubeflow_tpu.train.trainer import Trainer, TrainJobSpec
+
+    path = tmp_path / "metrics.jsonl"
+    Trainer(TrainJobSpec(
+        model="kimi_linear_tiny", model_kwargs=TWO_LAYERS, steps=2,
+        batch_size=2, seq_len=32,
+        log_every=1, loss_impl="chunked", loss_chunk=32, prefetch=0,
+        metrics_path=str(path))).run()
+    rows = [json.loads(line) for line in path.read_text().splitlines()]
+    rows = [r for r in rows if "loss" in r and "event" not in r]
+    assert len(rows) == 2
+    for row in rows:
+        # 4 of 16 experts held: a quarter of the pairs under a fair router.
+        assert 0.1 < row["moe_local_pair_share"] < 0.45
+        assert row["moe_load_max_over_mean"] >= 1.0
+        assert np.isfinite(row["loss"]) and np.isfinite(row["grad_norm"])
+
+
+def test_counters_survive_gradient_accumulation():
+    import optax
+
+    from kubeflow_tpu.parallel.mesh import single_device_mesh
+    from kubeflow_tpu.train.step import init_train_state, make_train_step
+
+    model = kl.KimiLinear(dataclasses.replace(kl.kimi_linear_tiny(),
+                                              **TWO_LAYERS))
+    mesh = single_device_mesh()
+    toks = jnp.asarray(np.random.default_rng(0).integers(0, 512, (4, 33)),
+                       jnp.int32)
+    batch = {"inputs": toks[:, :-1], "targets": toks[:, 1:]}
+    metrics = {}
+    for accum in (1, 2):
+        state = init_train_state(model, optax.adamw(1e-3),
+                                 jax.random.key(0), (batch["inputs"],), mesh)
+        _, metrics[accum] = make_train_step(
+            model, mesh, accum_steps=accum)(state, batch)
+    for name in ("moe_local_pair_share", "loss"):
+        assert float(metrics[2][name]) == pytest.approx(
+            float(metrics[1][name]), rel=2e-2)
+
+
+# -- the flash backward: value width of its own, q rows streamed -------------
+
+def qkv(b, s, h, kh, d, dv, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    return (jax.random.normal(ks[0], (b, s, h, d)),
+            jax.random.normal(ks[1], (b, s, kh, d)),
+            jax.random.normal(ks[2], (b, s, kh, dv)))
+
+
+@pytest.mark.parametrize("h,kh,s", [(4, 4, 96), (4, 2, 80)])
+def test_flash_value_width_differs_from_key_width(h, kh, s):
+    q, k, v = qkv(1, s, h, kh, 24, 16)
+    probe = jnp.cos(jnp.arange(16))
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * probe)
+
+    flash = functools.partial(fa.flash_attention, causal=True, block_q=32,
+                              block_kv=32)
+    out = flash(q, k, v)
+    assert out.shape == (1, s, h, 16)
+    assert rel(out, naive_attention(q, k, v)) < 1e-5
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    exp = jax.grad(loss(naive_attention), argnums=(0, 1, 2))(q, k, v)
+    for a, e in zip(got, exp):
+        assert a.shape == e.shape and rel(a, e) < 1e-4
+
+
+def test_flash_backward_past_the_old_row_wall():
+    """6 q heads on one kv head at 1,024 rows: 6,144 grouped rows, which the
+    dk/dv kernel used to hold whole (and could not, compiled, past ~5,000)."""
+    q, k, v = qkv(1, 1024, 6, 1, 16, 16, seed=2)
+    flash = functools.partial(fa.flash_attention, causal=True, block_q=512,
+                              block_kv=512)
+    got = jax.grad(lambda *a: jnp.sum(jnp.sin(flash(*a))),
+                   argnums=(0, 1, 2))(q, k, v)
+    exp = jax.grad(lambda *a: jnp.sum(jnp.sin(naive_attention(*a))),
+                   argnums=(0, 1, 2))(q, k, v)
+    for a, e in zip(got, exp):
+        assert rel(a, e) < 1e-4
+
+
+def _whole_rows_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                           dk_ref, dv_ref, *, block_q, block_kv, seq_q,
+                           seq_kv, seq_q_pad, group, mask, sm_scale):
+    """The dk/dv kernel as it was before the rows were streamed (PR 27's
+    tree), kept here as the oracle for "unchanged to the bit": grouped q, dO,
+    LSE and delta as whole rows, one fori_loop a head of the group."""
+    j = pl.program_id(1)
+    k = k_ref[0].astype(jnp.float32)
+    v = v_ref[0].astype(jnp.float32)
+    cols = jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_kv), 1) + j * block_kv
+    kv_valid = cols < seq_kv
+    first, num_q_blocks = fa._kv_visible(j, block_q, block_kv, seq_q_pad,
+                                         mask)
+    d = q_ref.shape[-1]
+
+    def make_body(g):
+        base = g * seq_q_pad
+
+        def body(qi, carry):
+            dk, dv = carry
+            off = base + qi * block_q
+            q = q_ref[0, pl.ds(off, block_q), :].astype(
+                jnp.float32) * sm_scale
+            do = do_ref[0, pl.ds(off, block_q), :].astype(jnp.float32)
+            lse = lse_ref[0, pl.ds(off, block_q), :]
+            delta = delta_ref[0, pl.ds(off, block_q), :]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            rows = jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_kv), 0) + qi * block_q
+            valid = fa._apply_mask(
+                jnp.logical_and(kv_valid, rows < seq_q), rows, cols, mask)
+            p = jnp.where(valid, jnp.exp(s - lse), 0.0)
+            dv = dv + jax.lax.dot_general(
+                p, do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            dk = dk + jax.lax.dot_general(
+                p * (dp - delta), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return dk, dv
+
+        return body
+
+    dk = jnp.zeros((block_kv, d), jnp.float32)
+    dv = jnp.zeros((block_kv, d), jnp.float32)
+    for g in range(group):
+        dk, dv = jax.lax.fori_loop(first, num_q_blocks, make_body(g),
+                                   (dk, dv))
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+
+
+@pytest.mark.parametrize("kind", ["causal", "full"])
+def test_flash_grouped_backward_unchanged_to_the_bit(kind):
+    """6:1 groups (the Qwen cell's shape at toy sizes): dk and dv of the
+    streamed kernel equal the whole-row kernel's bit for bit — the same
+    products, added in the same order."""
+    b, s, h, kh, d, blk = 2, 128, 6, 1, 16, 64
+    q, k, v = qkv(b, s, h, kh, d, d, seed=4)
+    g = jax.random.normal(jax.random.key(5), (b, s, h, d))
+    causal = kind == "causal"
+    _, vjp = jax.vjp(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal, blk, blk), q, k, v)
+    _, dk, dv = vjp(g)
+
+    _, (o3, lse) = fa._attn_impl(q, k, v, causal, blk, blk, True)
+    q3, k3, v3 = fa._flatten_heads(q, k, v)
+    do3 = g.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+    delta = jnp.sum(do3 * o3, axis=-1, keepdims=True)
+    group, bkh = h // kh, b * kh
+    rows = lambda x: x.reshape(bkh, group * s, x.shape[-1])
+    whole = lambda w: pl.BlockSpec((1, group * s, w), lambda bi, j: (bi, 0, 0))
+    block = pl.BlockSpec((1, blk, d), lambda bi, j: (bi, j, 0))
+    dk3, dv3 = pl.pallas_call(
+        functools.partial(
+            _whole_rows_dkv_kernel, block_q=blk, block_kv=blk, seq_q=s,
+            seq_kv=s, seq_q_pad=s, group=group, mask=fa.MaskSpec(kind),
+            sm_scale=1.0 / d ** 0.5),
+        grid=(bkh, s // blk),
+        in_specs=[whole(d), whole(d), whole(1), whole(1), block, block],
+        out_specs=[block, block],
+        out_shape=[jax.ShapeDtypeStruct((bkh, s, d), k.dtype)] * 2,
+        interpret=True,
+    )(rows(q3), rows(do3), rows(lse), rows(delta), k3, v3)
+    unflat = lambda x: x.reshape(b, kh, s, d).transpose(0, 2, 1, 3)
+    assert np.array_equal(np.asarray(dk), np.asarray(unflat(dk3)))
+    assert np.array_equal(np.asarray(dv), np.asarray(unflat(dv3)))
+
+
+# -- the kernels at the cell's widths, compiled for the chip ------------------
+# (the TPU's compiler is installed; the chip is described, not attached)
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("b,s,h,kh,d,dv", [
+    (2, 8192, 32, 32, 192, 128),  # this model's latent attention at 8k
+    (8, 1024, 12, 2, 128, 128),   # 6:1 groups at s1024: PR 24's VMEM wall
+])
+def test_flash_compiles_for_the_chip_at_real_widths(one_chip, b, s, h, kh, d,
+                                                    dv):
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, True, 512, 512, False).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grads).lower(
+        shape(b, s, h, d), shape(b, s, kh, d), shape(b, s, kh, dv)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3  # forward, dq, dk/dv
