@@ -12,6 +12,7 @@ import functools
 import importlib.util
 import json
 import os
+import re
 
 import flax.linen as nn
 import jax
@@ -69,14 +70,20 @@ def kda_inputs(t, decay, seed=0, b=2, h=3, dk=16, dv=8):
     return [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta)]
 
 
-@pytest.mark.parametrize("t,decay", [
-    (128, 0.1),   # chunk-aligned
-    (100, 0.1),   # ragged: the tail is padded with steps that change nothing
-    (128, 10.0),  # sum of g over a chunk far below -300: the textbook
-    (70, 10.0),   # factorisation's exp(-G) is inf in fp32 here
+#: Toy heads, and the cell's (dk = dv = 128, chunks of 64), two side by side.
+KDA_SHAPES = {"toy": {}, "cell": dict(b=1, h=2, dk=128, dv=128)}
+
+
+@pytest.mark.parametrize("t,decay,shape", [
+    (128, 0.1, "toy"),   # chunk-aligned
+    (100, 0.1, "toy"),   # ragged: the tail is padded with steps that do nothing
+    (128, 10.0, "toy"),  # sum of g over a chunk far below -300: the textbook
+    (70, 10.0, "toy"),   # factorisation's exp(-G) is inf in fp32 here
+    (256, 1.0, "cell"),
+    (200, 1.0, "cell"),  # ragged at the cell's widths
 ])
-def test_kda_chunked_matches_recurrence(t, decay):
-    args = kda_inputs(t, decay)
+def test_kda_chunked_matches_recurrence(t, decay, shape):
+    args = kda_inputs(t, decay, **KDA_SHAPES[shape])
     if decay > 1:
         assert float(jnp.min(jnp.sum(args[3][:, :64], axis=1))) < -300
 
@@ -92,6 +99,72 @@ def test_kda_chunked_matches_recurrence(t, decay):
     for name, a, e in zip("q k v g beta".split(), got, exp):
         assert bool(jnp.all(jnp.isfinite(a))), name
         assert rel(a, e) < 1e-4, name
+
+
+def test_kda_decay_factors_keep_fp32_under_a_large_cumulative_sum():
+    """g in [-14, -6] a step through whole chunks, so that G = cumsum(g) passes
+    -600 while the decay between neighbouring steps, exp(G_i - G_j), is a
+    difference of two such sums. Each (writer j, reader i) pair below has a
+    channel of its own: k_j = q_i = that unit vector, beta_j = 1 and 0
+    elsewhere, so o_i = exp(sum_{j < s <= i} g_s) v_j, the bare factor. g is
+    drawn on a grid of 2^-14 (up to 18 bits a value, every partial sum exact
+    in fp32): an exact fp32 sum leaves the matmuls' 2^-17 and exp's own
+    rounding; a sum over g cut to two bf16 parts (16 bits), or one, does
+    not pass."""
+    t, dk, dv = 128, 16, 8
+    pairs = [(60, 61), (60, 63), (47, 48), (45, 50), (30, 33), (62, 64),
+             (63, 66), (120, 122), (2, 7), (10, 15), (17, 21), (23, 28),
+             (70, 75), (81, 85), (97, 102), (106, 111)]  # inside a sub-chunk,
+    # across sub-chunks, into the next chunk: a channel each
+    r = np.random.default_rng(0)
+    g = -(6 + r.integers(0, 2 ** 17, (1, t, 2, dk)) / 2.0 ** 14)
+    v = r.normal(size=(1, t, 2, dv))
+    q, k, beta = np.zeros_like(g), np.zeros_like(g), np.zeros((1, t, 2))
+    for c, (j, i) in enumerate(pairs):
+        k[0, j, :, c], beta[0, j, :], q[0, i, :, c] = 1.0, 1.0, 1.0
+    # The recurrence in float64, head by head.
+    want = np.zeros((1, t, 2, dv))
+    for h in range(2):
+        S = np.zeros((dk, dv))
+        for s in range(t):
+            S = np.exp(g[0, s, h])[:, None] * S
+            S += np.outer(k[0, s, h], beta[0, s, h] * (v[0, s, h]
+                                                       - S.T @ k[0, s, h]))
+            want[0, s, h] = S.T @ q[0, s, h]
+    out = np.asarray(kda_chunked(*(jnp.asarray(x, jnp.float32)
+                                   for x in (q, k, v, g, beta))), np.float64)
+    assert float(np.sum(g[0, :64, 0, 0])) < -600
+    for j, i in pairs:
+        err = np.linalg.norm(out[0, i] - want[0, i]) / np.linalg.norm(
+            want[0, i])
+        assert err < 3e-5, (j, i, err)
+
+
+@pytest.mark.parametrize("dims", ["nn", "nt", "tn"])
+def test_kda_compiled_matmul_is_three_bf16_passes(dims):
+    """What the compiled kernels multiply with (the interpreted ones take the
+    host's fp32 product): hi*hi + hi*lo + lo*hi, within 2^-15 of the fp32
+    product where one bf16 pass is 2^-10 and more off; its cotangents too."""
+    from kubeflow_tpu.ops import kda
+
+    contract = {"nn": kda._NN, "nt": kda._NT, "tn": kda._TN}[dims]
+    ka, kb = jax.random.split(jax.random.key(0))
+    a = jax.random.normal(ka, (64, 48) if dims == "tn" else (48, 64))
+    b = jax.random.normal(kb, (32, 64) if dims == "nt" else (64, 32))
+    probe = jnp.sin(jnp.arange(32.0))
+
+    def value_and_cotangents(product):
+        out, vjp = jax.vjp(product, a, b)
+        return (out,) + vjp(jnp.broadcast_to(probe, out.shape))
+
+    three = value_and_cotangents(lambda a, b: kda._dot(a, b, contract, False))
+    exact = value_and_cotangents(lambda a, b: jax.lax.dot_general(
+        a, b, (contract, ((), ())), precision="highest"))
+    one = value_and_cotangents(lambda a, b: kda._pass(
+        a.astype(jnp.bfloat16), b.astype(jnp.bfloat16), contract))
+    for got, want, coarse in zip(three, exact, one):
+        assert rel(got, want) < 2.0 ** -15
+        assert rel(coarse.astype(jnp.float32), want) > 2.0 ** -10
 
 
 @pytest.mark.parametrize("chunk,sub", [(64, 24), (40, 16)])
@@ -490,3 +563,20 @@ def test_flash_compiles_for_the_chip_at_real_widths(one_chip, b, s, h, kh, d,
         shape(b, s, h, d), shape(b, s, kh, d), shape(b, s, kh, dv)
     ).compile().as_text()
     assert text.count("tpu_custom_call") >= 3  # forward, dq, dk/dv
+
+
+def test_kda_compiles_for_the_chip_at_real_widths(one_chip):
+    """The cell's KDA layer, forward and backward: two Mosaic kernels, and
+    nothing of a chunk's pairwise products [.., 16, 16, 128] outside them."""
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    def grads(*args):
+        return jax.grad(lambda *a: jnp.sum(kda_chunked(*a, interpret=False)),
+                        argnums=(0, 1, 2, 3, 4))(*args)
+
+    wide = shape(2, 8192, 32, 128)
+    text = jax.jit(grads).lower(wide, wide, wide, wide,
+                                shape(2, 8192, 32)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2  # kda_fwd, kda_bwd
+    assert not re.search(r"\[[0-9,]*16,16,128\]", text)
