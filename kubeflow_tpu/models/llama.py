@@ -474,10 +474,8 @@ class Attention(nn.Module):
 
                 qmode = ("int8" if cache["k"].dtype == jnp.int8
                          else "fp8")
-                # tpk-sync: begin kv-quant-scatter decode
                 kq, ks = kv_quantize_rows(k, qmode)
                 vq, vs = kv_quantize_rows(v, qmode)
-                # tpk-sync: end kv-quant-scatter
                 new_cache = {
                     "k": _update_rows(cache["k"], kq, cache_index),
                     "v": _update_rows(cache["v"], vq, cache_index),

@@ -137,6 +137,33 @@ def sample_tokens(logits: jax.Array, temperature: jax.Array,
     return jnp.where(temperature > 0, sampled, greedy)
 
 
+def gather_view(pool, tables):
+    """Paged pool -> contiguous per-row view, leaf by leaf:
+    [L, NB, bs, ...] x [B, nb] -> [L, B, nb*bs, ...]. View row j*bs + r is
+    block tables[b, j] row r, which is logical position j*bs + r because
+    tables are position-ordered."""
+    def leaf(p):
+        g = jnp.take(p, tables, axis=1)  # [L, B, nb, bs, ...]
+        return g.reshape(g.shape[0], g.shape[1],
+                         g.shape[2] * g.shape[3], *g.shape[4:])
+    return jax.tree.map(leaf, pool)
+
+
+def scatter_view(pool, view, tables):
+    """Write a `gather_view` view back to its blocks. Duplicate ids (shared
+    prefix blocks across rows, NULL-block pads) are benign: a shared
+    block is immutable, so every row writes its original values; the
+    NULL block receives garbage nobody reads."""
+    b, nb = tables.shape
+
+    def leaf(p, v):
+        bs = p.shape[2]
+        v = v.reshape(v.shape[0], b, nb, bs, *v.shape[3:])
+        v = v.reshape(v.shape[0], b * nb, bs, *v.shape[4:])
+        return p.at[:, tables.reshape(-1)].set(v)
+    return jax.tree.map(leaf, pool, view)
+
+
 def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
                      prefill_buckets: Sequence[int],
                      offset_writes: bool,
@@ -168,28 +195,29 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
     `kv_block_size` > 0 additionally builds the PAGED variants (serve/
     paging.py design note): the persistent cache is a pool of fixed-size
     blocks `[L, n_blocks, block_size, KH, D]` and each decode row's
-    history lives wherever its block table points. The jitted step
-    gathers the table into a contiguous `[L, B, bucket, ...]` view, runs
-    the EXACT flat decode computation on it (view row t IS logical
-    position t, so masking/positions/sampling are untouched — paged
-    greedy/seeded decode is token-identical to flat), then scatters the
-    view back block-by-block. Scatter-back rewrites shared (immutable)
-    blocks with their own values and pads through the reserved NULL
-    block 0, so duplicate scatter indices can only ever disagree on
-    garbage nobody reads (absolute-position masking hides every row past
-    a request's write index, exactly as it hides stale flat slots).
+    history lives wherever its block table points. Flat and paged decode
+    run the one `decode_scan` below over a cache VIEW: flat slices the
+    first `bucket` rows and writes them back, paged gathers the block
+    tables into a contiguous `[L, B, bucket, ...]` view (`gather_view`;
+    view row t IS logical position t, so masking/positions/sampling
+    need no paged case) and scatters it back block-by-block.
+    Scatter-back rewrites shared (immutable) blocks with their own
+    values and pads through the reserved NULL block 0, so duplicate
+    scatter indices can only ever disagree on garbage nobody reads
+    (absolute-position masking hides every row past a request's write
+    index, exactly as it hides stale flat slots).
 
     `kv_quant` != "none" (ISSUE 19, paged only) stores the pool as
     int8/fp8 payloads with per-row f32 scale planes "ks"/"vs" addressed
-    by the same block ids. The decode path is UNCHANGED TEXT: gather/
-    scatter and the scan carry are tree-generic, so the quantized view
-    (values + scales) flows through `make_decode_paged` verbatim and
-    the model applies scales output-side (models/llama.py decode
-    branch) — no full-width dequantized cache ever exists in the scan.
-    Only the admission boundary changes: `insert_paged` quantizes the
-    fragment's rows (the identical encode as the scan's row writes —
-    tpk-sync pins it) and `frag_from_pool` dequantizes into the full-
-    precision fragment (admission-side, outside any scan).
+    by the same block ids. Gather/scatter and the scan carry are
+    tree-generic, so the quantized view (values + scales) flows through
+    the same decode and the model applies scales output-side
+    (models/llama.py decode branch) — no full-width dequantized cache
+    ever exists in the scan. Only the admission boundary differs:
+    `insert_paged_quant` quantizes the fragment's rows with
+    `kv_quantize_rows`, the encode the scan's row writes call too, and
+    `frag_from_pool_quant` dequantizes into the full-precision fragment
+    (admission-side, outside any scan).
     """
     from kubeflow_tpu.models.llama import init_cache
 
@@ -278,43 +306,51 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
                     c.dtype),
                 (0, slot) + (0,) * (c.ndim - 2)), cache, frag)
 
+    def decode_scan(truncate, bucket, params, view, last_tok, index,
+                    temperature, top_k, top_p, key, aid):
+        """`chunk` decode steps over a cache view whose row t is logical
+        position t — the one scan behind flat and paged `decode_chunk`.
+        The non-truncating variant skips the full-vocab sort/cumsum:
+        all-greedy/plain-temperature traffic (the defaults) must not pay
+        O(V log V) per token. Rolling mode passes the index through RAW
+        — the model wraps it modularly and needs the absolute value for
+        positions. Returns (view, tokens [K, B], logprobs [K, B])."""
+        def step(carry, _):
+            view, tok, idx, key = carry
+            key, sub = jax.random.split(key)
+            logits, view = model.apply(
+                {"params": params}, tok[:, None], cache=view,
+                cache_index=(idx if rolling
+                             else jnp.minimum(idx, bucket - 1)),
+                **apply_kw(aid))
+            if truncate:
+                nxt = sample_tokens(logits[:, 0], temperature, sub,
+                                    top_k, top_p)
+            else:
+                nxt = sample_tokens(logits[:, 0], temperature, sub)
+            lp = _chosen_logprob(logits[:, 0], nxt)
+            return (view, nxt, idx + 1, key), (nxt, lp)
+
+        (view, _, _, _), (toks, lps) = jax.lax.scan(
+            step, (view, last_tok, index, key), None, length=chunk)
+        return view, toks, lps
+
     def make_decode(truncate: bool, bucket: int):
         def decode_chunk(params, cache, last_tok, index, temperature,
                          top_k, top_p, key, aid=None):
             """K decode steps under one dispatch; on-device sampling.
             last_tok/index/temperature [B]; returns (cache,
-            tokens [B, K], logprobs [B, K]). The non-truncating variant
-            skips the full-vocab sort/cumsum — all-greedy/
-            plain-temperature traffic (the defaults) must not pay
-            O(V log V) per token. Attention runs over the first `bucket`
-            cache rows only (the loop picks the smallest bucket covering
-            every active sequence), then the slice is written back.
-            Rolling mode: the cache is `window` rows (never sliced) and
-            the index passes through RAW — the model wraps it modularly
-            and needs the absolute value for positions."""
+            tokens [B, K], logprobs [B, K]). Attention runs over the
+            first `bucket` cache rows only (the loop picks the smallest
+            bucket covering every active sequence), then the slice is
+            written back. A rolling cache is `window` rows, its one
+            bucket, and is never sliced."""
             sliced = (cache if bucket == cache_len else jax.tree.map(
                 lambda c: jax.lax.slice_in_dim(c, 0, bucket, axis=2),
                 cache))
-
-            def step(carry, _):
-                sliced, tok, idx, key = carry
-                key, sub = jax.random.split(key)
-                logits, sliced = model.apply(
-                    {"params": params}, tok[:, None], cache=sliced,
-                    cache_index=(idx if rolling
-                                 else jnp.minimum(idx, bucket - 1)),
-                    **apply_kw(aid))
-                if truncate:
-                    nxt = sample_tokens(logits[:, 0], temperature, sub,
-                                        top_k, top_p)
-                else:
-                    nxt = sample_tokens(logits[:, 0], temperature, sub)
-                lp = _chosen_logprob(logits[:, 0], nxt)
-                return (sliced, nxt, idx + 1, key), (nxt, lp)
-
-            (sliced, _, _, _), (toks, lps) = jax.lax.scan(
-                step, (sliced, last_tok, index, key), None,
-                length=chunk)
+            sliced, toks, lps = decode_scan(
+                truncate, bucket, params, sliced, last_tok, index,
+                temperature, top_k, top_p, key, aid)
             if bucket != cache_len:
                 cache = jax.tree.map(
                     lambda c, s: jax.lax.dynamic_update_slice(
@@ -334,59 +370,15 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
         bs = int(kv_block_size)
         mb = max_len // bs  # blocks covering one full-length request
 
-        def _gather_view(pool_leaf, tables):
-            """[L, NB, bs, ...] × [B, nb] -> [L, B, nb*bs, ...]: view row
-            j*bs + r is block tables[b, j] row r — logical position
-            j*bs + r, because tables are position-ordered."""
-            g = jnp.take(pool_leaf, tables, axis=1)  # [L, B, nb, bs, ...]
-            return g.reshape(g.shape[0], g.shape[1],
-                             g.shape[2] * g.shape[3], *g.shape[4:])
-
-        def _scatter_view(pool_leaf, view_leaf, tables):
-            """Write the view back to its blocks. Duplicate ids (shared
-            prefix blocks across rows, NULL-block pads) are benign: a
-            shared block is immutable, so every row writes its original
-            values; the NULL block receives garbage nobody reads."""
-            b, nb = tables.shape
-            v = view_leaf.reshape(view_leaf.shape[0], b, nb, bs,
-                                  *view_leaf.shape[3:])
-            v = v.reshape(v.shape[0], b * nb, bs, *v.shape[4:])
-            return pool_leaf.at[:, tables.reshape(-1)].set(v)
-
         def make_decode_paged(truncate: bool, bucket: int):
-            nb = bucket // bs
-
             def decode_chunk(params, pool, tables, last_tok, index,
                              temperature, top_k, top_p, key, aid=None):
-                """Flat `decode_chunk` semantics over a gathered block
-                view: tables [B, nb] (pad entries 0 = NULL block). The
-                scan body is the flat step verbatim — paged decode is
-                token-identical to flat decode by construction."""
-                view = jax.tree.map(lambda p: _gather_view(p, tables),
-                                    pool)
-
-                def step(carry, _):
-                    view, tok, idx, key = carry
-                    key, sub = jax.random.split(key)
-                    logits, view = model.apply(
-                        {"params": params}, tok[:, None], cache=view,
-                        cache_index=jnp.minimum(idx, bucket - 1),
-                        **apply_kw(aid))
-                    if truncate:
-                        nxt = sample_tokens(logits[:, 0], temperature,
-                                            sub, top_k, top_p)
-                    else:
-                        nxt = sample_tokens(logits[:, 0], temperature,
-                                            sub)
-                    lp = _chosen_logprob(logits[:, 0], nxt)
-                    return (view, nxt, idx + 1, key), (nxt, lp)
-
-                (view, _, _, _), (toks, lps) = jax.lax.scan(
-                    step, (view, last_tok, index, key), None,
-                    length=chunk)
-                pool = jax.tree.map(
-                    lambda p, v: _scatter_view(p, v, tables), pool, view)
-                return pool, toks.T, lps.T
+                """Flat `decode_chunk` over a gathered block view:
+                tables [B, bucket // bs] (pad entries 0 = NULL block)."""
+                view, toks, lps = decode_scan(
+                    truncate, bucket, params, gather_view(pool, tables),
+                    last_tok, index, temperature, top_k, top_p, key, aid)
+                return scatter_view(pool, view, tables), toks.T, lps.T
             return decode_chunk
 
         def insert_paged(pool, frag, table):
@@ -446,21 +438,16 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
         def insert_paged_quant(pool, frag, table):
             """`insert_paged` for the quantized pool: the fragment
             arrives at FULL precision (admission computes exact rows),
-            and the scatter quantizes them — with the IDENTICAL encode
-            as the decode scan's per-row writes (models/llama.py), so a
-            row reaches the same bytes whether it was admitted or
-            decoded; the tpk-sync twin pins that equivalence. Shared
-            prefix blocks are masked to NULL exactly as in the plain
-            path — their committed bytes never change."""
-            qmode = kv_quant
+            and the scatter quantizes them with `kv_quantize_rows`, the
+            encode the decode scan's per-row writes call
+            (models/llama.py), so a row reaches the same bytes whether
+            it was admitted or decoded. Shared prefix blocks are masked
+            to NULL exactly as in the plain path — their committed
+            bytes never change."""
             rows_k = jax.lax.slice_in_dim(frag["k"], 0, mb * bs, axis=2)
             rows_v = jax.lax.slice_in_dim(frag["v"], 0, mb * bs, axis=2)
-            # tpk-sync: begin kv-quant-scatter admit
-            # tpk-sync: sub kv_quantize_rows(k, qmode) -> kv_quantize_rows(rows_k, qmode)
-            # tpk-sync: sub kv_quantize_rows(v, qmode) -> kv_quantize_rows(rows_v, qmode)
-            kq, ks = kv_quantize_rows(rows_k, qmode)
-            vq, vs = kv_quantize_rows(rows_v, qmode)
-            # tpk-sync: end kv-quant-scatter
+            kq, ks = kv_quantize_rows(rows_k, kv_quant)
+            vq, vs = kv_quantize_rows(rows_v, kv_quant)
 
             def blocked(r):
                 return r.reshape(r.shape[0], mb, bs, *r.shape[3:])
@@ -499,9 +486,7 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
                    export_blocks=export_blocks,
                    import_blocks=import_blocks)
         if kv_quant != "none":
-            # The plain fns above stay textually untouched (the
-            # kv_quant="none" bit-exactness pin); quantized pools swap
-            # ONLY the admission boundary.
+            # Quantized pools differ at the admission boundary only.
             fns.update(insert_paged=insert_paged_quant,
                        frag_from_pool=frag_from_pool_quant)
     return fns
@@ -608,29 +593,16 @@ def build_spec_decode(model, draft_model, *, gamma: int, n_spec: int,
     the PAGED signature instead — spec_chunk(params, dparams, pool,
     dpool, tables, dtables, last_tok, index, temperature, key) — which
     gathers per-row block views of the target AND draft pools (tables /
-    dtables [B, bucket//bs], pad entries 0 = NULL block), runs the flat
-    spec core on the views verbatim, and scatters both back. Paged spec
-    decode is token-identical to flat spec decode by construction, the
-    same argument as make_decode_paged; the draft pool shares the
-    target's block-id space but its tables are per-slot and never
-    prefix-shared (a draft cache is private working state)."""
+    dtables [B, bucket//bs], pad entries 0 = NULL block; `gather_view`),
+    calls the flat `spec_chunk` on the views and scatters both back.
+    The draft pool shares the target's block-id space but its tables
+    are per-slot and never prefix-shared (a draft cache is private
+    working state)."""
     rolling = int(rolling_window) > 0
     bs = int(kv_block_size)
     if bs and rolling:
         raise ValueError(
             "paged spec decode does not compose with the rolling cache")
-
-    def _gather_view(pool_leaf, tables):
-        g = jnp.take(pool_leaf, tables, axis=1)  # [L, B, nb, bs, ...]
-        return g.reshape(g.shape[0], g.shape[1],
-                         g.shape[2] * g.shape[3], *g.shape[4:])
-
-    def _scatter_view(pool_leaf, view_leaf, tables):
-        b, nb = tables.shape
-        v = view_leaf.reshape(view_leaf.shape[0], b, nb, bs,
-                              *view_leaf.shape[3:])
-        v = v.reshape(v.shape[0], b * nb, bs, *v.shape[4:])
-        return pool_leaf.at[:, tables.reshape(-1)].set(v)
 
     def make(bucket: int):
         def spec_chunk(params, dparams, cache, dcache, last_tok, index,
@@ -737,17 +709,12 @@ def build_spec_decode(model, draft_model, *, gamma: int, n_spec: int,
         def spec_chunk_paged(params, dparams, pool, dpool, tables,
                              dtables, last_tok, index, temperature, key,
                              aid=None):
-            cache = jax.tree.map(lambda p: _gather_view(p, tables), pool)
-            dcache = jax.tree.map(lambda p: _gather_view(p, dtables),
-                                  dpool)
             cache, dcache, toks, lps, ks = spec_chunk(
-                params, dparams, cache, dcache, last_tok, index,
+                params, dparams, gather_view(pool, tables),
+                gather_view(dpool, dtables), last_tok, index,
                 temperature, key, aid)
-            pool = jax.tree.map(
-                lambda p, v: _scatter_view(p, v, tables), pool, cache)
-            dpool = jax.tree.map(
-                lambda p, v: _scatter_view(p, v, dtables), dpool, dcache)
-            return pool, dpool, toks, lps, ks
+            return (scatter_view(pool, cache, tables),
+                    scatter_view(dpool, dcache, dtables), toks, lps, ks)
         return spec_chunk_paged
     return make
 
@@ -947,7 +914,7 @@ class GenerationEngine:
         # block ids, so ≈2× kv_blocks fit the same HBM, host-tier
         # spills charge about half the block units, and TPKV1 fmt-3
         # ships quantized bytes. "none" (default) is the bit-exact
-        # escape hatch — the unquantized code paths, textually.
+        # escape hatch: the unquantized pool.
         self.kv_quant = str(kv_quant or "none")
         if self.kv_quant not in KV_QUANT_MODES:
             raise ValueError(
@@ -1587,23 +1554,6 @@ class GenerationEngine:
             raise ValueError(f"top_p must be in (0, 1], got {top_p}")
         if top_k < 0:
             raise ValueError(f"top_k must be >= 0, got {top_k}")
-        if self._paged:
-            need = blocks_for(
-                self._paged_need_tokens(len(input_ids), int(max_tokens)),
-                self._kv_bs)
-            if (self._spec is not None and int(top_k) == 0
-                    and float(top_p) >= 1.0):
-                # Spec-able: the draft pool reserves the same worst-case
-                # footprint again (ISSUE 18 move 1).
-                need *= 2
-            if need > self._kv_alloc.n_blocks:
-                # Permanent: even an empty pool can't cover it — shed
-                # now (503), don't let it camp in the queue to 504.
-                raise KVCapacityExceeded(
-                    f"request needs {need} KV blocks worst-case "
-                    f"(prompt {len(input_ids)} + max_tokens "
-                    f"{int(max_tokens)}) but the pool has "
-                    f"{self._kv_alloc.n_blocks}")
         req = {
             "input_ids": [int(t) for t in input_ids],
             "max_tokens": int(max_tokens),
@@ -1625,6 +1575,7 @@ class GenerationEngine:
             "t_enq": time.perf_counter(),
             "cb": on_tokens,
         }
+        self._refuse_oversized(req)
         self._queue.put(req)
         self._wake.set()
         wait_s = timeout
@@ -1688,14 +1639,6 @@ class GenerationEngine:
             raise ValueError(f"top_p must be in (0, 1], got {top_p}")
         if top_k < 0:
             raise ValueError(f"top_k must be >= 0, got {top_k}")
-        need = blocks_for(len(input_ids), self._kv_bs)
-        if (self._spec is not None and int(top_k) == 0
-                and float(top_p) >= 1.0):
-            need *= 2  # prompt-width draft blocks ride the shipment
-        if need > self._kv_alloc.n_blocks:
-            raise KVCapacityExceeded(
-                f"prompt needs {need} KV blocks but the pool has "
-                f"{self._kv_alloc.n_blocks}")
         req = {
             "mode": "ship",
             "input_ids": [int(t) for t in input_ids],
@@ -1718,6 +1661,7 @@ class GenerationEngine:
             "t_enq": time.perf_counter(),
             "cb": None,
         }
+        self._refuse_oversized(req)
         self._queue.put(req)
         self._wake.set()
         wait_s = deadline.bound(timeout) if deadline is not None else timeout
@@ -1882,15 +1826,6 @@ class GenerationEngine:
         if timeout is None:
             timeout = float(meta.get("timeout", 300.0))
         max_tokens = int(meta.get("max_tokens", 32))
-        need = blocks_for(self._paged_need_tokens(len(ids), max_tokens),
-                          self._kv_bs)
-        if (self._spec is not None and int(meta.get("top_k", 0)) == 0
-                and float(meta.get("top_p", 1.0)) >= 1.0):
-            need *= 2  # worst-case: the draft table mirrors the target's
-        if need > self._kv_alloc.n_blocks:
-            raise KVCapacityExceeded(
-                f"shipped request needs {need} KV blocks worst-case but "
-                f"the pool has {self._kv_alloc.n_blocks}")
         req = {
             "mode": "remote",
             "input_ids": ids,
@@ -1919,6 +1854,7 @@ class GenerationEngine:
             "t_enq": time.perf_counter(),
             "cb": on_tokens,
         }
+        self._refuse_oversized(req)
         self._queue.put(req)
         self._wake.set()
         wait_s = deadline.bound(timeout) if deadline is not None else timeout
@@ -1952,6 +1888,12 @@ class GenerationEngine:
             if n <= b:
                 return b
         return self.prefill_buckets[-1]
+
+    def _decode_bucket_for(self, need: int) -> int:
+        """The smallest decode bucket holding `need` cache rows — short
+        conversations never pay max_len-wide attention."""
+        return next((b for b in self.decode_buckets if b >= need),
+                    self.decode_buckets[-1])
 
     # -- prefix cache --------------------------------------------------------
 
@@ -2029,6 +1971,32 @@ class GenerationEngine:
         chunks = -(-max(int(max_tokens), 1) // self.chunk)
         return min(self.max_len, prompt + chunks * self.chunk)
 
+    def _need_blocks(self, req: dict) -> int:
+        """Target pool blocks a request reserves whole at admission: the
+        worst case of `_paged_need_tokens`, or the prompt alone in ship
+        mode (the decode replica reserves the decode budget at
+        submit_remote)."""
+        n = len(req["input_ids"])
+        if req.get("mode") != "ship":
+            n = self._paged_need_tokens(n, req["max_tokens"])
+        return blocks_for(n, self._kv_bs)
+
+    def _refuse_oversized(self, req: dict) -> None:
+        """Shed at submit (503) a request whose reserve even an empty
+        pool can't cover: permanent, so it must not camp in the queue
+        to a 504. Spec-able requests count the draft pool's equal
+        footprint (ISSUE 18 move 1)."""
+        if not self._paged:
+            return
+        need = self._need_blocks(req) + self._draft_need_blocks(req)
+        if need > self._kv_alloc.n_blocks:
+            budget = ("" if req.get("mode") == "ship"
+                      else f" + max_tokens {req['max_tokens']}")
+            raise KVCapacityExceeded(
+                f"request needs {need} KV blocks worst-case (prompt "
+                f"{len(req['input_ids'])}{budget}) but the pool has "
+                f"{self._kv_alloc.n_blocks}")
+
     def _spec_able(self, req: dict) -> bool:
         """A request rides the spec sub-batch iff it has no truncated
         sampling: greedy and plain-temperature rows compose with the
@@ -2043,17 +2011,37 @@ class GenerationEngine:
         top of the target's (ISSUE 18 move 1): the same bound as the
         target's, because the draft cache mirrors the committed index.
         Draft blocks are per-slot private working state — never
-        prefix-shared, never discounted by a hit. Ship-mode reserves
-        prompt blocks only, like the target (the decode replica reserves
-        the decode budget)."""
+        prefix-shared, never discounted by a hit."""
         if not (self._paged and self._spec_able(req)):
             return 0
-        ids = req["input_ids"]
-        if req.get("mode") == "ship":
-            return blocks_for(len(ids), self._kv_bs)
-        return blocks_for(
-            self._paged_need_tokens(len(ids), req["max_tokens"]),
-            self._kv_bs)
+        return self._need_blocks(req)
+
+    def _reserve_blocks(self, req: dict,
+                        n_shared: int = 0) -> tuple[list, list | None]:
+        """Take a request's whole worst-case block need off the pool,
+        less `n_shared` prefix blocks it maps by reference — the one
+        reserve behind local, ship-mode and remote admission, so a
+        shipped request can neither out- nor under-reserve a local one.
+        Returns (fresh target blocks, draft blocks or None); raises
+        _NeedKVBlocks with nothing held. _admit_waiting's _kv_fits
+        precheck (which counts both) makes the failure unreachable in
+        the normal flow; defense against future reordering."""
+        fresh = self._kv_alloc.alloc(
+            max(0, self._need_blocks(req) - n_shared))
+        if fresh is None:
+            raise _NeedKVBlocks()
+        # Draft blocks ride the same pool, per-slot and never
+        # prefix-shared (the draft cache holds draft-model activations —
+        # a target prefix block would be garbage to it). Both or
+        # neither, so _kv_fits stays the single admission gate.
+        dtable = None
+        dneed = self._draft_need_blocks(req)
+        if dneed:
+            dtable = self._kv_alloc.alloc(dneed)
+            if dtable is None:
+                self._kv_alloc.decref(fresh)
+                raise _NeedKVBlocks()
+        return fresh, dtable
 
     def _prefix_probe_paged(self, ids: list[int], aid: int, *,
                             touch: bool) -> tuple[int, tuple] | None:
@@ -2153,18 +2141,9 @@ class GenerationEngine:
         freeing nothing, and never destroys its own hit needlessly."""
         ids = req["input_ids"]
         mode = req.get("mode")
-        if mode == "ship":
-            # Prefill-only: the decode budget is the DECODE replica's
-            # to reserve; this pool holds just the prompt blocks until
-            # the shipment serializes.
-            total = blocks_for(len(ids), self._kv_bs)
-        else:
-            total = blocks_for(
-                self._paged_need_tokens(len(ids), req["max_tokens"]),
-                self._kv_bs)
         # Spec-able requests also cover the draft pool's footprint —
         # fresh blocks only, so the prefix-hit discount never applies.
-        total += self._draft_need_blocks(req)
+        total = self._need_blocks(req) + self._draft_need_blocks(req)
         aid = req.get("aid", 0)
         # Remote admissions never discount by a prefix hit: their blocks
         # arrive on the wire and the reserve below allocates the FULL
@@ -2252,10 +2231,11 @@ class GenerationEngine:
                 if self._host_tier is not None else None)
 
     def _admit_inner_paged(self, slot: int, req: dict) -> None:
-        """Paged admission: the fragment pipeline (prefill/extend over a
-        contiguous fragment cache) is IDENTICAL to flat — only where the
-        fragment lands differs (scatter into this request's blocks
-        instead of a slot row), plus the block-table bookkeeping:
+        """Paged admission: the fragment pipeline (`_prefill_chunks`
+        over a contiguous fragment cache) and the slot state (`_seat`)
+        are flat admission's — only where the fragment lands differs
+        (scatter into this request's blocks instead of a slot row), plus
+        the block-table bookkeeping:
 
           * zero-copy prefix hit: the stored prefix's fully-committed
             blocks map into this table by reference (refcount bump);
@@ -2264,31 +2244,16 @@ class GenerationEngine:
             IS the copy-on-write copy (`kv_cow_copies`).
           * the whole worst-case block need is allocated here, off the
             decode critical path (see `_paged_need_tokens`).
-
-        The chunked-prefill loop is a deliberate textual copy of
-        `_admit_inner`'s (flat must stay byte-untouched); the
-        `admit-chunked-prefill` / `admit-slot-state` tpk-sync regions
-        enforce the twinning mechanically — a fix landing in only one
-        loop fails tier-1 (rule sync-regions) instead of breaking the
-        seeded flat-vs-paged identity test at runtime.
         """
         ids = req["input_ids"]
         aid = req.get("aid", 0)
-        aid1 = self._aid1(aid)
         bs = self._kv_bs
         mb = self.max_len // bs
-        sample_args = (
-            jnp.asarray([req["temperature"]], jnp.float32),
-            jnp.asarray([req.get("top_k", 0)], jnp.int32),
-            jnp.asarray([req.get("top_p", 1.0)], jnp.float32),
-        )
-        big = self.prefill_buckets[-1]
-        frag, tok0, done = None, None, 0
+        frag, done = None, 0
         shared: list[int] = []
         gather_tbl: tuple | None = None
         cow_fork = False
         hit = None
-        ship = req.get("mode") == "ship"
         if self._prefix_cap:
             hit = self._prefix_probe_paged(ids, aid, touch=True)
             if hit is None and self._host_tier is not None:
@@ -2305,41 +2270,7 @@ class GenerationEngine:
                 shared = list(hit_blocks[:done // bs])
                 cow_fork = done % bs > 0
                 gather_tbl = hit_blocks
-        if ship:
-            # Prompt blocks only — see _kv_fits; the decode budget is
-            # reserved by the decode replica at submit_remote.
-            need = blocks_for(len(ids), bs)
-            fresh = self._kv_alloc.alloc(max(0, need - len(shared)))
-            if fresh is None:
-                raise _NeedKVBlocks()
-        else:
-            # _admit_waiting's _kv_fits precheck makes the reserve
-            # failure unreachable in the normal flow; defense against
-            # future reordering. The remote-admit twin must reserve by
-            # the IDENTICAL worst-case rule — a drifted copy would let
-            # a shipped request out-reserve (or under-reserve) a local
-            # one and break the pool accounting.
-            # tpk-sync: begin kv-block-reserve admit
-            need = blocks_for(
-                self._paged_need_tokens(len(ids), req["max_tokens"]),
-                bs)
-            fresh = self._kv_alloc.alloc(max(0, need - len(shared)))
-            if fresh is None:
-                raise _NeedKVBlocks()
-            # tpk-sync: end kv-block-reserve
-        # Draft blocks ride the same pool, per-slot and never
-        # prefix-shared (the draft cache holds draft-model activations —
-        # a target prefix block would be garbage to it). Allocated
-        # atomically with the target reserve: both or neither, so the
-        # _kv_fits precheck (which counts both) stays the single
-        # admission gate.
-        dtable: list[int] | None = None
-        dneed = self._draft_need_blocks(req)
-        if dneed:
-            dtable = self._kv_alloc.alloc(dneed)
-            if dtable is None:
-                self._kv_alloc.decref(fresh)
-                raise _NeedKVBlocks()
+        fresh, dtable = self._reserve_blocks(req, len(shared))
         if self._prefix_cap:
             with self._stats_lock:
                 if hit is not None:
@@ -2354,7 +2285,6 @@ class GenerationEngine:
         self._kv_alloc.incref(shared)
         table = shared + fresh
         boundaries: list[int] = []
-        start_done = done
         try:
             if gather_tbl is not None:
                 # Resume chunked prefill mid-prompt: seed the fragment
@@ -2370,41 +2300,10 @@ class GenerationEngine:
                     # can see when prefix-hit traffic pays it.
                     with self._stats_lock:
                         self.stats["kv_dequant_fallbacks"] += 1
-            # tpk-sync: begin admit-chunked-prefill paged
-            # tpk-sync: sub self._prefix_store(aid, tuple(ids[:done]), frag, copy=done < len(ids)) -> boundaries.append(done)
-            while done < len(ids):
-                piece = ids[done:done + big]
-                final = done + len(piece) >= len(ids)
-                bucket = self._bucket_for(len(piece))
-                toks = np.zeros((1, bucket), np.int32)
-                toks[0, :len(piece)] = piece
-                if done == 0:
-                    self._key, sub = jax.random.split(self._key)
-                    frag, tok0, lp0 = self._prefill[bucket](
-                        self._params, jnp.asarray(toks),
-                        jnp.asarray([len(piece)], jnp.int32),
-                        *sample_args, sub, aid=aid1)
-                elif final:
-                    self._key, sub = jax.random.split(self._key)
-                    frag, tok0, lp0 = self._extend(
-                        self._params, frag, jnp.asarray(toks),
-                        jnp.asarray([len(piece)], jnp.int32),
-                        jnp.asarray([done], jnp.int32), *sample_args,
-                        sub, aid=aid1)
-                else:  # intermediate chunk: no sampling, no unembedding
-                    frag = self._extend_mid(
-                        self._params, frag, jnp.asarray(toks),
-                        jnp.asarray([done], jnp.int32), aid=aid1)
-                done += len(piece)
-                if self._prefix_cap:
-                    # Same boundary gate as flat (skip entries a later
-                    # boundary of this admission would immediately
-                    # evict); the store itself is deferred until the
-                    # blocks are written by the insert below.
-                    chunks_left = -(-(len(ids) - done) // big)
-                    if chunks_left < self._prefix_cap:
-                        boundaries.append(done)
-            # tpk-sync: end admit-chunked-prefill
+            # Boundaries are only noted here: the store is deferred
+            # until the insert below has written the blocks.
+            frag, tok0, lp0 = self._prefill_chunks(
+                req, frag, done, lambda m, _: boundaries.append(m))
             # Scatter table: shared prefix blocks masked to NULL (their
             # rows are already resident and immutable), owned blocks
             # receive their fragment rows — including the CoW fork and
@@ -2432,37 +2331,11 @@ class GenerationEngine:
         for m in boundaries:
             self._prefix_store_paged(aid, tuple(ids[:m]),
                                      table[:blocks_for(m, bs)])
-        with self._stats_lock:
-            self.stats["prefill_chunks"] += -(-(len(ids) - start_done)
-                                              // big)
-        if ship:
+        if req.get("mode") == "ship":
             self._finish_ship(req, table, tok0, lp0, dtable)
             return
-        draft_ok = dtable is not None
-        # tpk-sync: begin admit-slot-state paged
-        # tpk-sync: sub 'aid': aid} -> 'aid': aid, 'blocks': table, 'dblocks': dtable}
-        st = {"req": req, "idx": len(ids), "disp": len(ids), "last": None,
-              "pending": None, "draft_ok": draft_ok, "aid": aid,
-              "blocks": table, "dblocks": dtable}
-        if self.pipeline_depth > 1:
-            for arr in (tok0, lp0):
-                getattr(arr, "copy_to_host_async", lambda: None)()
-            st["pending"] = (tok0, lp0)
-            self._slots[slot] = st
-        else:
-            st["last"] = int(tok0[0])
-            self._slots[slot] = st
-        with self._stats_lock:
-            self.stats["requests"] += 1
-            self.stats["prompt_tokens"] += len(ids)
-            if aid:
-                per = dict(self.stats.get("adapter_requests", {}))
-                name = self._ml_names[aid]
-                per[name] = per.get(name, 0) + 1
-                self.stats["adapter_requests"] = per
-        if st["pending"] is None:
-            self._emit(slot, st, [st["last"]], [float(lp0[0])])
-        # tpk-sync: end admit-slot-state
+        self._seat(slot, req, tok0, lp0, draft_ok=dtable is not None,
+                   blocks=table, dblocks=dtable)
 
     def _finish_ship(self, req: dict, table: list[int], tok0,
                      lp0, dtable: list[int] | None = None) -> None:
@@ -2575,23 +2448,7 @@ class GenerationEngine:
         aid = req.get("aid", 0)
         bs = self._kv_bs
         mb = self.max_len // bs
-        shared: list[int] = []
-        # tpk-sync: begin kv-block-reserve remote
-        need = blocks_for(
-            self._paged_need_tokens(len(ids), req["max_tokens"]),
-            bs)
-        fresh = self._kv_alloc.alloc(max(0, need - len(shared)))
-        if fresh is None:
-            raise _NeedKVBlocks()
-        # tpk-sync: end kv-block-reserve
-        dtable: list[int] | None = None
-        dneed = self._draft_need_blocks(req)
-        if dneed:
-            dtable = self._kv_alloc.alloc(dneed)
-            if dtable is None:
-                self._kv_alloc.decref(fresh)
-                raise _NeedKVBlocks()
-        table = shared + fresh
+        table, dtable = self._reserve_blocks(req)
         n_blocks = req["n_blocks"]
         try:
             # Scatter the shipped blocks into the FIRST n_blocks table
@@ -2676,12 +2533,7 @@ class GenerationEngine:
         if n is None:
             return None
         n_blocks = blocks_for(n, self._kv_bs)
-        if req.get("mode") == "ship":
-            total = blocks_for(len(ids), self._kv_bs)
-        else:
-            total = blocks_for(
-                self._paged_need_tokens(len(ids), req["max_tokens"]),
-                self._kv_bs)
+        total = self._need_blocks(req)
         shared_after = n // self._kv_bs  # full blocks mapped zero-copy
         if not self._kv_alloc.can_alloc(n_blocks + total - shared_after):
             return None  # leave it spilled; admission proceeds cold
@@ -2793,25 +2645,7 @@ class GenerationEngine:
             return self._admit_inner_paged(slot, req)
         ids = req["input_ids"]
         aid = req.get("aid", 0)
-        aid1 = self._aid1(aid)
-        sample_args = (
-            jnp.asarray([req["temperature"]], jnp.float32),
-            jnp.asarray([req.get("top_k", 0)], jnp.int32),
-            jnp.asarray([req.get("top_p", 1.0)], jnp.float32),
-        )
-        # Prompts longer than the largest bucket prefill in CHUNKS: the
-        # first chunk is a plain prefill, the rest are continuation
-        # chunks attending over the whole fragment cache — no silent
-        # truncation (submit() already bounds the prompt by max_len).
-        # The recipe (piece slicing, bucket choice, RNG split order,
-        # boundary gating) is duplicated in _admit_inner_paged so the
-        # flat path stays textually untouched; the tpk-sync regions
-        # below enforce the twinning — a change landing in only one
-        # side fails tier-1 (rule sync-regions) instead of breaking the
-        # paged-is-token-identical-to-flat invariant the seeded test
-        # pins.
-        big = self.prefill_buckets[-1]
-        frag, tok0, done = None, None, 0
+        frag, done = None, 0
         if self._prefix_cap:
             hit = self._prefix_lookup(ids, aid)
             if hit is not None:
@@ -2822,52 +2656,16 @@ class GenerationEngine:
             else:
                 with self._stats_lock:
                     self.stats["prefix_misses"] += 1
-        start_done = done
-        # tpk-sync: begin admit-chunked-prefill flat
-        while done < len(ids):
-            piece = ids[done:done + big]
-            final = done + len(piece) >= len(ids)
-            bucket = self._bucket_for(len(piece))
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :len(piece)] = piece
-            if done == 0:
-                self._key, sub = jax.random.split(self._key)
-                frag, tok0, lp0 = self._prefill[bucket](
-                    self._params, jnp.asarray(toks),
-                    jnp.asarray([len(piece)], jnp.int32), *sample_args, sub,
-                    aid=aid1)
-            elif final:
-                self._key, sub = jax.random.split(self._key)
-                frag, tok0, lp0 = self._extend(
-                    self._params, frag, jnp.asarray(toks),
-                    jnp.asarray([len(piece)], jnp.int32),
-                    jnp.asarray([done], jnp.int32), *sample_args, sub,
-                    aid=aid1)
-            else:  # intermediate chunk: no sampling, no unembedding
-                frag = self._extend_mid(
-                    self._params, frag, jnp.asarray(toks),
-                    jnp.asarray([done], jnp.int32), aid=aid1)
-            done += len(piece)
-            if self._prefix_cap:
-                # Skip fragments a LATER boundary of this same admission
-                # would immediately LRU-evict (cap < boundaries left: the
-                # seed copied them only to pop them milliseconds later),
-                # and hand the final fragment over by reference — nothing
-                # donates it after the loop, so the full-fragment HBM
-                # copy the seed paid on every admission is gone.
-                chunks_left = -(-(len(ids) - done) // big)
-                if chunks_left < self._prefix_cap:
-                    self._prefix_store(aid, tuple(ids[:done]), frag,
-                                       copy=done < len(ids))
-        # tpk-sync: end admit-chunked-prefill
-        with self._stats_lock:
-            self.stats["prefill_chunks"] += -(-(len(ids) - start_done)
-                                              // big)
+        # The final fragment is handed over by reference — nothing
+        # donates it after the loop, so the full-fragment HBM copy the
+        # seed paid on every admission is gone.
+        frag, tok0, lp0 = self._prefill_chunks(
+            req, frag, done,
+            lambda m, f: self._prefix_store(aid, tuple(ids[:m]), f,
+                                            copy=m < len(ids)))
         self._cache = self._insert(self._cache, frag, jnp.int32(slot))
-        spec_able = (req.get("top_k", 0) == 0
-                     and req.get("top_p", 1.0) >= 1.0)
-        draft_ok = False
-        if self._spec is not None and spec_able:
+        draft_ok = self._spec_able(req)
+        if draft_ok:
             # The draft must hold the same prompt history: run the chunked
             # admission over its own cache (no sampling — the first
             # generated token reaches the draft as next decode input).
@@ -2878,10 +2676,84 @@ class GenerationEngine:
             self._dcache = self._dinsert(self._dcache,
                                          self._draft_replay(ids),
                                          jnp.int32(slot))
-            draft_ok = True
-        # tpk-sync: begin admit-slot-state flat
+        self._seat(slot, req, tok0, lp0, draft_ok=draft_ok)
+
+    def _pieces(self, ids: list[int], done: int = 0):
+        """Cut `ids[done:]` into admission chunks of the largest prefill
+        bucket, the last one possibly shorter: yields (offset, tokens
+        [1, bucket] right-padded, piece length) — target and draft
+        admission cut a prompt the same way."""
+        big = self.prefill_buckets[-1]
+        while done < len(ids):
+            piece = ids[done:done + big]
+            toks = np.zeros((1, self._bucket_for(len(piece))), np.int32)
+            toks[0, :len(piece)] = piece
+            yield done, jnp.asarray(toks), len(piece)
+            done += len(piece)
+
+    def _prefill_chunks(self, req: dict, frag, done: int, at_boundary):
+        """Chunked prefill of `req`'s prompt from token `done` on (`frag`
+        holds the rows before it: a prefix hit, else None) — the one
+        loop behind flat and paged admission. Prompts longer than the
+        largest bucket prefill in CHUNKS: the first is a plain prefill,
+        the rest are continuation chunks attending over the whole
+        fragment cache — no silent truncation (submit() already bounds
+        the prompt by max_len). The engine key is split for the first
+        chunk (a prefill samples even when its token is dropped) and for
+        the final one, in that order: seeded streams depend on it.
+        `at_boundary(done, frag)` is called after each chunk worth
+        caching as a prefix. Returns (fragment, first sampled token [1],
+        its logprob [1]), both still on the device."""
+        ids = req["input_ids"]
+        aid1 = self._aid1(req.get("aid", 0))
+        sample_args = (
+            jnp.asarray([req["temperature"]], jnp.float32),
+            jnp.asarray([req.get("top_k", 0)], jnp.int32),
+            jnp.asarray([req.get("top_p", 1.0)], jnp.float32),
+        )
+        big = self.prefill_buckets[-1]
+        tok0 = lp0 = None
+        chunks = 0
+        for at, toks, n in self._pieces(ids, done):
+            done = at + n
+            first, final = at == 0, done >= len(ids)
+            if first or final:
+                self._key, sub = jax.random.split(self._key)
+                length = jnp.asarray([n], jnp.int32)
+            if first:
+                frag, tok0, lp0 = self._prefill[toks.shape[1]](
+                    self._params, toks, length, *sample_args, sub,
+                    aid=aid1)
+            elif final:
+                frag, tok0, lp0 = self._extend(
+                    self._params, frag, toks, length,
+                    jnp.asarray([at], jnp.int32), *sample_args, sub,
+                    aid=aid1)
+            else:  # intermediate chunk: no sampling, no unembedding
+                frag = self._extend_mid(
+                    self._params, frag, toks,
+                    jnp.asarray([at], jnp.int32), aid=aid1)
+            chunks += 1
+            if self._prefix_cap:
+                # Skip boundaries a LATER boundary of this same admission
+                # would immediately LRU-evict (cap < boundaries left: the
+                # seed copied them only to pop them milliseconds later).
+                chunks_left = -(-(len(ids) - done) // big)
+                if chunks_left < self._prefix_cap:
+                    at_boundary(done, frag)
+        with self._stats_lock:
+            self.stats["prefill_chunks"] += chunks
+        return frag, tok0, lp0
+
+    def _seat(self, slot: int, req: dict, tok0, lp0, *, draft_ok: bool,
+              **tables) -> None:
+        """Seat a locally prefilled request in `slot`: the slot state
+        (`tables`: a paged request's `blocks` / `dblocks`), its first
+        token, the admission counters."""
+        ids = req["input_ids"]
+        aid = req.get("aid", 0)
         st = {"req": req, "idx": len(ids), "disp": len(ids), "last": None,
-              "pending": None, "draft_ok": draft_ok, "aid": aid}
+              "pending": None, "draft_ok": draft_ok, "aid": aid, **tables}
         if self.pipeline_depth > 1:
             # Off-critical-path admission: do NOT fetch the first sampled
             # token here — that host sync would serialize the prefill
@@ -2892,10 +2764,9 @@ class GenerationEngine:
             for arr in (tok0, lp0):
                 getattr(arr, "copy_to_host_async", lambda: None)()
             st["pending"] = (tok0, lp0)
-            self._slots[slot] = st
         else:
             st["last"] = int(tok0[0])
-            self._slots[slot] = st
+        self._slots[slot] = st
         with self._stats_lock:
             self.stats["requests"] += 1
             self.stats["prompt_tokens"] += len(ids)
@@ -2909,24 +2780,15 @@ class GenerationEngine:
                 self.stats["adapter_requests"] = per
         if st["pending"] is None:
             self._emit(slot, st, [st["last"]], [float(lp0[0])])
-        # tpk-sync: end admit-slot-state
 
     def _draft_replay(self, ids: list[int]) -> Any:
-        """Chunked draft-cache build over a token sequence — the ONE
-        admission recipe shared by initial admission and re-admission
-        (no sampling: _dextend_mid only)."""
-        big = self.prefill_buckets[-1]
+        """Chunked draft-cache build over a token sequence, shared by
+        initial admission and re-admission (no sampling: _dextend_mid
+        only)."""
         dfrag = self._dfrag_init()
-        done = 0
-        while done < len(ids):
-            piece = ids[done:done + big]
-            bucket = self._bucket_for(len(piece))
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :len(piece)] = piece
-            dfrag = self._dextend_mid(self._dparams, dfrag,
-                                      jnp.asarray(toks),
-                                      jnp.asarray([done], jnp.int32))
-            done += len(piece)
+        for at, toks, _ in self._pieces(ids):
+            dfrag = self._dextend_mid(self._dparams, dfrag, toks,
+                                      jnp.asarray([at], jnp.int32))
         return dfrag
 
     def _readmit_worthwhile(self, st: dict) -> bool:
@@ -3114,12 +2976,14 @@ class GenerationEngine:
         if (max(self._slots[i]["disp"] for i in active) + self.chunk
                 > self.max_len):
             return False
-        for i in active:
-            st = self._slots[i]
-            inflight = st["disp"] - st["idx"] + (1 if st["pending"] else 0)
-            if len(st["req"]["out"]) + inflight < st["req"]["max_tokens"]:
-                return True
-        return False
+        return any(self._budget_uncovered(self._slots[i]) for i in active)
+
+    @staticmethod
+    def _budget_uncovered(st: dict) -> bool:
+        """Whether tokens already emitted plus those in flight still fall
+        short of the request's budget."""
+        inflight = st["disp"] - st["idx"] + (1 if st["pending"] else 0)
+        return len(st["req"]["out"]) + inflight < st["req"]["max_tokens"]
 
     def _van_riders_fit(self, van_batch: list[int]) -> bool:
         """Flat-mode guard for the vanilla sub-batch: live rows OUTSIDE
@@ -3201,15 +3065,80 @@ class GenerationEngine:
             # Chain chunk k+1 only while some participant's budget is
             # not already covered in flight (same rule as the vanilla
             # chain) — otherwise the over-dispatch is pure waste.
-            for i in fit:
-                st = self._slots[i]
-                infl = (st["disp"] - st["idx"]
-                        + (1 if st["pending"] else 0))
-                if len(st["req"]["out"]) + infl < st["req"]["max_tokens"]:
-                    break
-            else:
+            if not any(self._budget_uncovered(self._slots[i])
+                       for i in fit):
                 return [], []
         return fit, (tail if not chained else [])
+
+    # tpk-hot: dispatch-rows
+    def _gather_rows(self, rows: list[int]) -> tuple:
+        """Snapshot the slot state one decode dispatch reads, as six
+        [n_slots] host arrays (idx, temps, ks, ps, aids, last): `rows`
+        are the dispatch's participants; `last` holds a row's last token
+        where the host knows it (a pending first token, or a row riding
+        an on-device carry, is spliced in by `_last_tokens`)."""
+        last = np.zeros((self.n_slots,), np.int32)
+        idx = np.zeros((self.n_slots,), np.int32)
+        temps = np.zeros((self.n_slots,), np.float32)
+        ks = np.zeros((self.n_slots,), np.int32)
+        ps = np.ones((self.n_slots,), np.float32)
+        aids = np.zeros((self.n_slots,), np.int32)
+        for i in rows:
+            st = self._slots[i]
+            idx[i] = st["disp"]
+            temps[i] = st["req"]["temperature"]
+            ks[i] = st["req"].get("top_k", 0)
+            ps[i] = st["req"].get("top_p", 1.0)
+            aids[i] = st.get("aid", 0)
+            if st["pending"] is None and st["last"] is not None:
+                last[i] = st["last"]
+        if not self._paged:
+            partset = set(rows)
+            for j, stj in enumerate(self._slots):
+                if stj is None or j in partset:
+                    continue
+                # Rider parking: a live row excluded from this sub-batch
+                # (it belongs to the other one) aims its batch-wide
+                # write at its own uncommitted tail — idx 0 would
+                # clobber committed prompt KV (paged riders write the
+                # NULL block instead and need no parking).
+                idx[j] = stj["disp"]
+        return idx, temps, ks, ps, aids, last
+
+    # tpk-hot: dispatch-last-tokens
+    def _last_tokens(self, rows: list[int], last, carry: dict | None,
+                     carried):
+        """The dispatch's on-device last-token vector: `carried` (the
+        in-flight `carry` record's last column) where there is one, else
+        the host's `last`. Rows that didn't ride the carry — a slot
+        admitted mid-pipe, or one re-synced after a drain — are
+        overridden individually."""
+        last_dev = jnp.asarray(last) if carry is None else carried
+        for i in rows:
+            st = self._slots[i]
+            if carry is not None and carry["parts"].get(i) is st:
+                continue  # row rides the on-device carry
+            if st["pending"] is not None:
+                # Mid-pipe admission: splice the prefill's on-device
+                # first token into the carried vector (a scalar
+                # update dispatch, no host round-trip).
+                last_dev = last_dev.at[i].set(st["pending"][0][0])
+            elif carry is not None:
+                last_dev = last_dev.at[i].set(np.int32(st["last"]))
+        return last_dev
+
+    # tpk-hot: dispatch-tables
+    def _block_tables(self, rows: list[int], nb: int,
+                      which: str = "blocks"):
+        """Per-row block tables [n_slots, nb], padded with the NULL
+        block. Built from host lists fixed at admission — no device
+        sync, so chained pipelined dispatch works exactly as flat."""
+        tables = np.zeros((self.n_slots, nb), np.int32)
+        for i in rows:
+            blk = self._slots[i][which]
+            k = min(len(blk), nb)
+            tables[i, :k] = blk[:k]
+        return jnp.asarray(tables)
 
     # tpk-hot: spec-dispatch
     def _dispatch_spec_chunk(self, parts: list[int],
@@ -3248,82 +3177,27 @@ class GenerationEngine:
         if stale:
             with self._stats_lock:
                 self.stats["spec_stale_rides"] += stale
-        last = np.zeros((self.n_slots,), np.int32)
-        idx = np.zeros((self.n_slots,), np.int32)
-        temps = np.zeros((self.n_slots,), np.float32)
-        ks = np.zeros((self.n_slots,), np.int32)
-        ps = np.ones((self.n_slots,), np.float32)
-        aids = np.zeros((self.n_slots,), np.int32)
-        # The row-gather below is the tpk-sync twin of the vanilla
-        # dispatch loop's: the spec sub-batch must snapshot slot state
-        # by the identical recipe (ks/ps are gathered for the twinning
-        # but never dispatched — spec rows are never truncated).
-        # tpk-sync: begin dispatch-row-gather spec
-        # tpk-sync: sub for i in active: -> for i in parts:
-        for i in parts:
-            st = self._slots[i]
-            idx[i] = st["disp"]
-            temps[i] = st["req"]["temperature"]
-            ks[i] = st["req"].get("top_k", 0)
-            ps[i] = st["req"].get("top_p", 1.0)
-            aids[i] = st.get("aid", 0)
-            if st["pending"] is None and st["last"] is not None:
-                last[i] = st["last"]
-        # tpk-sync: end dispatch-row-gather
+        # Spec rows are never truncated: top_k / top_p go unused.
+        idx, temps, _, _, aids, last = self._gather_rows(parts)
         assumed = {i: self._slots[i]["disp"] for i in parts}
-        partset = set(parts)
-        if not self._paged:
-            for j, stj in enumerate(self._slots):
-                if stj is None or j in partset:
-                    continue
-                # Rider parking: a live row excluded from this sub-batch
-                # aims its batch-wide write at its own uncommitted tail
-                # (idx 0 would clobber committed prompt KV; paged riders
-                # write the NULL block instead and need no parking).
-                idx[j] = stj["disp"]
-        need = int(max(idx)) + worst
-        bucket = next((b for b in self.decode_buckets if b >= need),
-                      self.decode_buckets[-1])
+        bucket = self._decode_bucket_for(int(max(idx)) + worst)
         self._key, sub = jax.random.split(self._key)
         t0 = time.monotonic()
         p0 = time.perf_counter()
         with self._scope():
-            last_dev = (jnp.asarray(last) if carry is None
-                        else carry["toks"][:, -1, -1])
-            for i in parts:
-                st = self._slots[i]
-                if carry is not None and carry["parts"].get(i) is st:
-                    continue  # row rides the on-device worst-case carry
-                if st["pending"] is not None:
-                    last_dev = last_dev.at[i].set(st["pending"][0][0])
-                elif carry is not None:
-                    last_dev = last_dev.at[i].set(np.int32(st["last"]))
+            last_dev = self._last_tokens(
+                parts, last, carry,
+                None if carry is None else carry["toks"][:, -1, -1])
+            tables = ()
             if self._paged:
                 nb = bucket // self._kv_bs
-                tables = np.zeros((self.n_slots, nb), np.int32)
-                dtables = np.zeros((self.n_slots, nb), np.int32)
-                for i in parts:
-                    st = self._slots[i]
-                    blk = st["blocks"]
-                    k = min(len(blk), nb)
-                    tables[i, :k] = blk[:k]
-                    dbl = st["dblocks"]
-                    k = min(len(dbl), nb)
-                    dtables[i, :k] = dbl[:k]
-                self._cache, self._dcache, toks, lps, acc = \
-                    self._spec_decode[bucket](
-                        self._params, self._dparams, self._cache,
-                        self._dcache, jnp.asarray(tables),
-                        jnp.asarray(dtables), last_dev,
-                        jnp.asarray(idx), jnp.asarray(temps), sub,
-                        aid=self._aid_batch(aids))
-            else:
-                self._cache, self._dcache, toks, lps, acc = \
-                    self._spec_decode[bucket](
-                        self._params, self._dparams, self._cache,
-                        self._dcache, last_dev, jnp.asarray(idx),
-                        jnp.asarray(temps), sub,
-                        aid=self._aid_batch(aids))
+                tables = (self._block_tables(parts, nb),
+                          self._block_tables(parts, nb, "dblocks"))
+            self._cache, self._dcache, toks, lps, acc = \
+                self._spec_decode[bucket](
+                    self._params, self._dparams, self._cache,
+                    self._dcache, *tables, last_dev, jnp.asarray(idx),
+                    jnp.asarray(temps), sub, aid=self._aid_batch(aids))
         for arr in (toks, lps, acc):
             getattr(arr, "copy_to_host_async", lambda: None)()
         with self._stats_lock:
@@ -3460,75 +3334,24 @@ class GenerationEngine:
         top-k/top-p. The cache-length bucket is the smallest covering
         every active sequence after this chunk — short conversations
         never pay max_len-wide attention."""
-        last = np.zeros((self.n_slots,), np.int32)
-        idx = np.zeros((self.n_slots,), np.int32)
-        temps = np.zeros((self.n_slots,), np.float32)
-        ks = np.zeros((self.n_slots,), np.int32)
-        ps = np.ones((self.n_slots,), np.float32)
-        aids = np.zeros((self.n_slots,), np.int32)
-        # tpk-sync: begin dispatch-row-gather van
-        for i in active:
-            st = self._slots[i]
-            idx[i] = st["disp"]
-            temps[i] = st["req"]["temperature"]
-            ks[i] = st["req"].get("top_k", 0)
-            ps[i] = st["req"].get("top_p", 1.0)
-            aids[i] = st.get("aid", 0)
-            if st["pending"] is None and st["last"] is not None:
-                last[i] = st["last"]
-        # tpk-sync: end dispatch-row-gather
+        idx, temps, ks, ps, aids, last = self._gather_rows(active)
         trunc = any(ks[i] > 0 or ps[i] < 1.0 for i in active)
-        if not self._paged:
-            partset = set(active)
-            for j, stj in enumerate(self._slots):
-                if stj is None or j in partset:
-                    continue
-                # Rider parking: a live row excluded from this sub-batch
-                # (it belongs to the spec sub-batch) aims its batch-wide
-                # write at its own uncommitted tail — idx 0 would
-                # clobber committed prompt KV (paged riders write the
-                # NULL block instead and need no parking).
-                idx[j] = stj["disp"]
-        need = int(max(idx)) + self.chunk
-        bucket = next((b for b in self.decode_buckets if b >= need),
-                      self.decode_buckets[-1])
+        bucket = self._decode_bucket_for(int(max(idx)) + self.chunk)
         self._key, sub = jax.random.split(self._key)
         t0 = time.monotonic()
         p0 = time.perf_counter()  # span clock for the decode-chunk span
         with self._scope():
-            last_dev = (jnp.asarray(last) if carry is None
-                        else carry["toks"][:, -1])
-            for i in active:
-                st = self._slots[i]
-                if carry is not None and carry["parts"].get(i) is st:
-                    continue  # row rides the on-device carry
-                if st["pending"] is not None:
-                    # Mid-pipe admission: splice the prefill's on-device
-                    # first token into the carried vector (a scalar
-                    # update dispatch, no host round-trip).
-                    last_dev = last_dev.at[i].set(st["pending"][0][0])
-                elif carry is not None:
-                    last_dev = last_dev.at[i].set(np.int32(st["last"]))
+            last_dev = self._last_tokens(
+                active, last, carry,
+                None if carry is None else carry["toks"][:, -1])
+            tables = ()
             if self._paged:
-                # Per-row block tables, padded with the NULL block. Built
-                # from host lists fixed at admission — no device sync, so
-                # chained pipelined dispatch works exactly as flat.
-                nb = bucket // self._kv_bs
-                tables = np.zeros((self.n_slots, nb), np.int32)
-                for i in active:
-                    blk = self._slots[i]["blocks"]
-                    k = min(len(blk), nb)
-                    tables[i, :k] = blk[:k]
-                self._cache, toks, lps = self._decode[(bucket, trunc)](
-                    self._params, self._cache, jnp.asarray(tables),
-                    last_dev, jnp.asarray(idx), jnp.asarray(temps),
-                    jnp.asarray(ks), jnp.asarray(ps), sub,
-                    aid=self._aid_batch(aids))
-            else:
-                self._cache, toks, lps = self._decode[(bucket, trunc)](
-                    self._params, self._cache, last_dev, jnp.asarray(idx),
-                    jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(ps),
-                    sub, aid=self._aid_batch(aids))
+                tables = (self._block_tables(active,
+                                             bucket // self._kv_bs),)
+            self._cache, toks, lps = self._decode[(bucket, trunc)](
+                self._params, self._cache, *tables, last_dev,
+                jnp.asarray(idx), jnp.asarray(temps), jnp.asarray(ks),
+                jnp.asarray(ps), sub, aid=self._aid_batch(aids))
         # Start the D2H transfer now; the fetch a pipeline-depth later
         # should find the bytes already on host.
         for arr in (toks, lps):

@@ -11,8 +11,9 @@ This module is the host half of the PagedAttention-style answer (the
 vLLM design the serve module header cites): the KV tensor becomes a pool
 of fixed-size blocks `[L, n_blocks, block_size, KH, D]`, each request
 owns a *block table* (a host-side list of block ids), and the jitted
-step gathers the table into a contiguous view / scatters it back
-(serve/generation.py `build_engine_fns` paged fns). Everything here is
+step gathers the table into a contiguous view, runs the flat engine's
+decode scan on it and scatters it back (serve/generation.py
+`gather_view` / `scatter_view` around `decode_scan`). Everything here is
 plain-Python bookkeeping mutated only by the engine worker thread —
 block allocation sits at admit/retire, off the decode critical path, so
 pipelined dispatch (`pipeline_depth > 1`) needs no new host syncs.

@@ -84,23 +84,163 @@ def test_allocator_exhaustion_all_or_nothing_and_errors():
 
 # -- flat/paged identity ------------------------------------------------------
 
-def test_flat_vs_paged_token_identical_greedy_and_seeded_sampling(tiny):
-    """The gathered block view runs the EXACT flat decode computation
-    (view row t is logical position t), so paged output — greedy and
-    temperature-sampled under the same seed — must match flat token for
-    token, logprob for logprob."""
-    flat = _engine(tiny, seed=7)
-    paged = _engine(tiny, seed=7, kv_block_size=8)
-    prompt = [5, 9, 2]
-    try:
-        for kw in ({}, {"temperature": 0.8}):
-            a = flat.submit(prompt, max_tokens=12, **kw)
-            b = paged.submit(prompt, max_tokens=12, **kw)
-            assert a["output_ids"] == b["output_ids"], kw
-            assert a["output_logprobs"] == b["output_logprobs"], kw
-    finally:
+PROMPTS = {
+    "3_in_one_bucket": [5, 9, 2],
+    "8_exactly_the_bucket": [11, 4, 7, 30, 2, 19, 6, 8],
+    # prefill + extend_mid + extend at prefill_buckets=(8,)
+    "19_three_chunks": [17, 3, 3, 8, 1, 12, 25, 9, 14, 6, 21, 2, 7, 31,
+                        10, 4, 18, 5, 13],
+}
+
+
+@pytest.fixture(scope="module")
+def twins(tiny):
+    """A flat and a paged engine per pipeline depth, same seed, built on
+    first use and shared by every case: each case submits the same
+    sequence to both, so their keys stay in step whatever the order."""
+    made = {}
+
+    def get(depth):
+        if depth not in made:
+            kw = dict(seed=7, pipeline_depth=depth, prefix_cache=4)
+            made[depth] = (_engine(tiny, **kw),
+                           _engine(tiny, kv_block_size=8, **kw))
+        return made[depth]
+
+    yield get
+    for flat, paged in made.values():
         flat.close()
         paged.close()
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+@pytest.mark.parametrize("sampling", [{}, {"temperature": 0.8}],
+                         ids=["greedy", "t0.8"])
+@pytest.mark.parametrize("shape", list(PROMPTS))
+def test_flat_vs_paged_token_identical_greedy_and_seeded_sampling(
+        twins, shape, sampling, depth):
+    """Flat and paged decode run one scan over a cache view (view row t
+    is logical position t) behind one admission, so paged output —
+    greedy and temperature-sampled under the same seed, one chunk or
+    three, pipelined or not — must match flat token for token, logprob
+    for logprob; and again from a stored prefix boundary."""
+    flat, paged = twins(depth)
+    prompt = PROMPTS[shape]
+
+    def both():
+        before = [e.stats_snapshot() for e in (flat, paged)]
+        a = flat.submit(prompt, max_tokens=12, **sampling)
+        b = paged.submit(prompt, max_tokens=12, **sampling)
+        assert a["output_ids"] == b["output_ids"]
+        assert a["output_logprobs"] == b["output_logprobs"]
+        after = [e.stats_snapshot() for e in (flat, paged)]
+        moved = [{k: y[k] - x[k] for k in ("prefill_chunks",
+                                           "prefix_hit_tokens")}
+                 for x, y in zip(before, after)]
+        assert moved[0] == moved[1]
+        return a, moved[0]
+
+    first, moved = both()
+    assert len(first["output_ids"]) == 12
+    # The second submission resumes from the stored 16-token boundary
+    # where there is one; on both engines alike.
+    again, moved = both()
+    resumed = 16 if len(prompt) == 19 else 0
+    assert moved == {"prefill_chunks": -(-(len(prompt) - resumed) // 8),
+                     "prefix_hit_tokens": resumed}
+    if not sampling:
+        assert again["output_ids"] == first["output_ids"]
+        assert again["output_logprobs"] == first["output_logprobs"]
+
+
+# -- the dispatch snapshot and the reserve (host only) -------------------------
+
+def _bare_engine(**attrs):
+    """An engine object with no thread, no model and no device state:
+    only what the host-side helper under test reads."""
+    eng = GenerationEngine.__new__(GenerationEngine)
+    for k, v in attrs.items():
+        setattr(eng, k, v)
+    return eng
+
+
+def test_gather_rows_snapshots_dispatch_state():
+    """One row with its first token still on the device, one with a
+    host-known last token, an empty slot and a live row outside the
+    sub-batch: the six arrays both dispatchers read."""
+    slots = [
+        {"req": {"temperature": 0.7, "top_k": 5, "top_p": 0.9},
+         "disp": 11, "idx": 11, "last": None,
+         "pending": (object(), object()), "aid": 2},
+        {"req": {"temperature": 0.0}, "disp": 23, "idx": 19, "last": 42,
+         "pending": None},
+        None,
+        {"req": {"temperature": 0.3, "top_k": 9}, "disp": 30, "idx": 30,
+         "last": 7, "pending": None, "aid": 1},
+    ]
+    eng = _bare_engine(n_slots=4, _slots=slots, _paged=False)
+    idx, temps, ks, ps, aids, last = eng._gather_rows([0, 1])
+    # Flat: the rider parks its batch-wide write at its own disp.
+    assert idx.tolist() == [11, 23, 0, 30]
+    assert temps.tolist() == pytest.approx([0.7, 0.0, 0.0, 0.0])
+    assert ks.tolist() == [5, 0, 0, 0]
+    assert ps.tolist() == pytest.approx([0.9, 1.0, 1.0, 1.0])
+    assert aids.tolist() == [2, 0, 0, 0]
+    # The pending row's token is spliced in on the device, not here.
+    assert last.tolist() == [0, 42, 0, 0]
+    assert [a.dtype.name for a in (idx, temps, ks, ps, aids, last)] == [
+        "int32", "float32", "int32", "float32", "int32", "int32"]
+    # Paged riders write the NULL block: nothing is parked.
+    eng._paged = True
+    assert eng._gather_rows([0, 1])[0].tolist() == [11, 23, 0, 0]
+
+
+def test_reserve_blocks_is_one_rule_for_local_ship_and_remote():
+    """Local and remote admission of the same (prompt, max_tokens) hold
+    the same number of pool blocks; ship mode holds the prompt's alone;
+    a draft reserve that fails gives the target's blocks back."""
+    from kubeflow_tpu.serve.generation import _NeedKVBlocks
+
+    alloc = BlockAllocator(24, 8)
+    eng = _bare_engine(_kv_alloc=alloc, _kv_bs=8, _paged=True, _spec=None,
+                       max_len=64, chunk=4)
+    req = {"input_ids": list(range(1, 18)), "max_tokens": 40}
+    held = {}
+    for mode in (None, "remote", "ship"):
+        fresh, dtable = eng._reserve_blocks(dict(req, mode=mode))
+        assert dtable is None and alloc.used_blocks == len(fresh)
+        held[mode] = len(fresh)
+        alloc.decref(fresh)
+    # 17 + 40 tokens in whole chunks, 8 to a block; the prompt alone: 3.
+    assert held == {None: 8, "remote": 8, "ship": 3}
+    fresh, _ = eng._reserve_blocks(req, 2)  # two blocks shared by a hit
+    assert len(fresh) == 6
+    alloc.decref(fresh)
+    # With a draft, a spec-able request reserves as much again; 24
+    # blocks hold one such pair, and the second fails whole.
+    eng._spec = {"gamma": 2}
+    fresh, dtable = eng._reserve_blocks(req)
+    assert len(fresh) == len(dtable) == 8 and alloc.used_blocks == 16
+    extra = alloc.alloc(1)  # 7 free: the target fits, the draft cannot
+    with pytest.raises(_NeedKVBlocks):
+        eng._reserve_blocks(req)
+    assert alloc.used_blocks == 17
+    alloc.decref(extra)
+    # A truncated-sampling request never speculates: no draft blocks.
+    assert eng._reserve_blocks(dict(req, top_k=5))[1] is None
+    # Submit sheds by the same count what an empty pool could never
+    # hold: 16 of 15 blocks with the draft's, 6 of 5 for a shipment.
+    for n_blocks, mode, draft, fits in ((15, None, False, True),
+                                        (15, "remote", True, False),
+                                        (6, "ship", True, True),
+                                        (5, "ship", True, False)):
+        eng._kv_alloc = BlockAllocator(n_blocks, 8)
+        eng._spec = {"gamma": 2} if draft else None
+        if fits:
+            eng._refuse_oversized(dict(req, mode=mode))
+        else:
+            with pytest.raises(KVCapacityExceeded, match="KV blocks"):
+                eng._refuse_oversized(dict(req, mode=mode))
 
 
 def test_flat_escape_hatch_seeded_determinism(tiny):

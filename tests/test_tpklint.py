@@ -222,93 +222,6 @@ def test_pragma_unknown_rule_is_a_finding(tmp_path):
     assert "unknown rule" in fs[0].message
 
 
-# -- rule: sync-regions -----------------------------------------------------
-
-
-TWINS_OK = """\
-    def flat(self, ids):
-        # tpk-sync: begin recipe flat
-        for i in ids:
-            self.push(i, mode="flat")
-        # tpk-sync: end recipe
-        return 1
-
-    def paged(self, ids):
-        # tpk-sync: begin recipe paged
-        for i in ids:
-            # a comment never counts as drift
-            self.push(
-                i, mode="flat")
-        # tpk-sync: end recipe
-        return 2
-    """
-
-
-def test_sync_regions_match_modulo_comments_and_wrapping(tmp_path):
-    assert lint(tmp_path, {"m.py": TWINS_OK}, ["sync-regions"]) == []
-
-
-def test_sync_regions_drift_fires(tmp_path):
-    drifted = TWINS_OK.replace('self.push(\n                i, mode="flat")',
-                               'self.push(i, mode="paged")')
-    fs = lint(tmp_path, {"m.py": drifted}, ["sync-regions"])
-    assert len(fs) == 1 and "drifted" in fs[0].message
-    assert "recipe" in fs[0].message
-
-
-def test_sync_regions_declared_substitution(tmp_path):
-    fs = lint(tmp_path, {"m.py": """\
-        def flat(self, ids):
-            # tpk-sync: begin r flat
-            self.store(ids, frag)
-            # tpk-sync: end r
-            return 1
-
-        def paged(self, ids):
-            # tpk-sync: begin r paged
-            # tpk-sync: sub self.store(ids, frag) -> table.append(ids)
-            table.append(ids)
-            # tpk-sync: end r
-            return 2
-        """}, ["sync-regions"])
-    assert fs == []
-
-
-def test_sync_regions_stale_substitution_fires(tmp_path):
-    fs = lint(tmp_path, {"m.py": """\
-        def flat(self, ids):
-            # tpk-sync: begin r flat
-            self.keep(ids)
-            # tpk-sync: end r
-            return 1
-
-        def paged(self, ids):
-            # tpk-sync: begin r paged
-            # tpk-sync: sub self.store(ids) -> table.append(ids)
-            table.append(ids)
-            # tpk-sync: end r
-            return 2
-        """}, ["sync-regions"])
-    assert any("no longer appears" in f.message for f in fs)
-
-
-def test_sync_regions_single_side_fires(tmp_path):
-    fs = lint(tmp_path, {"m.py": """\
-        # tpk-sync: begin lonely flat
-        x = 1
-        # tpk-sync: end lonely
-        """}, ["sync-regions"])
-    assert len(fs) == 1 and "exactly 2 variants" in fs[0].message
-
-
-def test_sync_regions_unclosed_begin_fires(tmp_path):
-    fs = lint(tmp_path, {"m.py": """\
-        # tpk-sync: begin open flat
-        x = 1
-        """}, ["sync-regions"])
-    assert len(fs) == 1 and "never closed" in fs[0].message
-
-
 # -- rule: spec-schema ------------------------------------------------------
 
 
@@ -711,32 +624,16 @@ def test_metrics_shim_keeps_cli_and_api():
 
 
 def _copy_engine_tree(tmp_path):
-    # models/llama.py rides along since ISSUE 19: the kv-quant-scatter
-    # twin's canonical side (the decode scan's row quantize) lives
-    # there, and a tree holding only the admit side would rightly fire
-    # the single-sided-tag finding.
-    for rel in ("kubeflow_tpu/serve/generation.py",
-                "kubeflow_tpu/models/llama.py"):
-        dst = tmp_path / rel
-        dst.parent.mkdir(parents=True, exist_ok=True)
-        shutil.copy(os.path.join(REPO, rel), dst)
-    return tmp_path / "kubeflow_tpu/serve/generation.py"
+    rel = "kubeflow_tpu/serve/generation.py"
+    dst = tmp_path / rel
+    dst.parent.mkdir(parents=True, exist_ok=True)
+    shutil.copy(os.path.join(REPO, rel), dst)
+    return dst
 
 
 def test_real_engine_copy_is_clean(tmp_path):
     _copy_engine_tree(tmp_path)
-    assert lint(tmp_path, rules=["host-sync", "sync-regions"]) == []
-
-
-def test_mutating_a_twin_turns_red(tmp_path):
-    dst = _copy_engine_tree(tmp_path)
-    src = dst.read_text()
-    # First occurrence is inside the paged twin of admit-chunked-prefill.
-    assert src.count("done += len(piece)") == 3
-    dst.write_text(src.replace("done += len(piece)",
-                               "done += len(piece) + 0", 1))
-    fs = lint(tmp_path, rules=["sync-regions"])
-    assert len(fs) == 1 and "admit-chunked-prefill" in fs[0].message
+    assert lint(tmp_path, rules=["host-sync"]) == []
 
 
 def test_bare_item_in_hot_path_turns_red(tmp_path):
@@ -753,21 +650,6 @@ def test_deleting_hot_markers_turns_red(tmp_path):
     dst.write_text(dst.read_text().replace("# tpk-hot: engine-fetch\n", ""))
     fs = lint(tmp_path, rules=["host-sync"])
     assert any("engine-fetch" in f.message for f in fs)
-
-
-def test_mutating_kv_reserve_twin_turns_red(tmp_path):
-    """ISSUE 13: the decode-side remote admission must reserve pool
-    blocks by the exact local-admission rule — drifting the remote copy
-    alone is a tier-1 finding, not a latent accounting bug."""
-    dst = _copy_engine_tree(tmp_path)
-    src = dst.read_text()
-    needle = "fresh = self._kv_alloc.alloc(max(0, need - len(shared)))"
-    # ship-mode reserve + the admit twin + the remote twin.
-    assert src.count(needle) == 3
-    head, _, tail = src.rpartition(needle)
-    dst.write_text(head + needle.replace("need", "need + 1", 1) + tail)
-    fs = lint(tmp_path, rules=["sync-regions"])
-    assert len(fs) == 1 and "kv-block-reserve" in fs[0].message
 
 
 def test_deleting_remote_admit_marker_turns_red(tmp_path):
@@ -792,69 +674,6 @@ def test_host_fetch_in_remote_admit_turns_red(tmp_path):
     assert len(fs) == 1 and "remote-admit" in fs[0].message
 
 
-def test_mutating_dispatch_row_gather_twin_turns_red(tmp_path):
-    """ISSUE 18: the spec sub-batch must gather per-row dispatch state
-    by the IDENTICAL recipe as the vanilla dispatch loop — drifting one
-    side alone (e.g. reading idx where the twin reads disp) is a tier-1
-    finding, not a depth-2 race found in production."""
-    dst = _copy_engine_tree(tmp_path)
-    src = dst.read_text()
-    needle = 'temps[i] = st["req"]["temperature"]'
-    assert src.count(needle) == 2  # spec gather + van gather
-    dst.write_text(src.replace(
-        needle, 'temps[i] = float(st["req"]["temperature"])', 1))
-    fs = lint(tmp_path, rules=["sync-regions"])
-    assert len(fs) == 1 and "dispatch-row-gather" in fs[0].message
-
-
-def test_mutating_kv_quant_encode_twin_turns_red(tmp_path):
-    """ISSUE 19: admission's scatter must quantize fragment rows with
-    the IDENTICAL encode as the decode scan's per-row writes — a
-    drifted admit-side encode would make prefix-hit / restored rows
-    numerically diverge from decoded rows of the same tokens."""
-    dst = _copy_engine_tree(tmp_path)
-    src = dst.read_text()
-    needle = "kq, ks = kv_quantize_rows(rows_k, qmode)"
-    assert src.count(needle) == 1  # the admit twin (insert_paged_quant)
-    dst.write_text(src.replace(
-        needle, "kq, ks = kv_quantize_rows(rows_k * 1, qmode)"))
-    fs = lint(tmp_path, rules=["sync-regions"])
-    assert len(fs) == 1 and "kv-quant-scatter" in fs[0].message
-
-
-def test_mutating_kv_quant_decode_side_turns_red(tmp_path):
-    """The canonical (decode-write) side drifting out from under the
-    admit side's declared substitutions is equally loud."""
-    _copy_engine_tree(tmp_path)
-    llama = tmp_path / "kubeflow_tpu/models/llama.py"
-    src = llama.read_text()
-    needle = "kq, ks = kv_quantize_rows(k, qmode)"
-    assert src.count(needle) == 1
-    llama.write_text(src.replace(
-        needle, "kq, ks = kv_quantize_rows(k * 1, qmode)"))
-    fs = lint(tmp_path, rules=["sync-regions"])
-    assert len(fs) >= 1
-    assert all("kv-quant-scatter" in f.message for f in fs)
-
-
-def test_deleting_kv_quant_markers_turns_red(tmp_path):
-    """kv-quant-scatter is a REQUIRED tag: stripping both sides'
-    markers (the lazy way out of the drift finding) is itself a
-    finding on the home file."""
-    dst = _copy_engine_tree(tmp_path)
-    llama = tmp_path / "kubeflow_tpu/models/llama.py"
-    # begin/end lines name the tag; the admit side's sub lines name
-    # the substituted call — both families must go.
-    strip = re.compile(
-        r"^\s*# tpk-sync: (?:(?:begin|end) kv-quant-scatter"
-        r"|sub kv_quantize_rows).*\n", re.M)
-    dst.write_text(strip.sub("", dst.read_text()))
-    llama.write_text(strip.sub("", llama.read_text()))
-    fs = lint(tmp_path, rules=["sync-regions"])
-    assert len(fs) == 1 and "kv-quant-scatter" in fs[0].message
-    assert fs[0].path == "kubeflow_tpu/serve/generation.py"
-
-
 def test_deleting_spec_hot_markers_turns_red(tmp_path):
     for label in ("spec-dispatch", "spec-reconcile"):
         dst = _copy_engine_tree(tmp_path / label)
@@ -862,6 +681,34 @@ def test_deleting_spec_hot_markers_turns_red(tmp_path):
             f"    # tpk-hot: {label}\n", ""))
         fs = lint(tmp_path / label, rules=["host-sync"])
         assert any(label in f.message for f in fs)
+
+
+def test_deleting_dispatch_helper_markers_turns_red(tmp_path):
+    """What both dispatchers call runs once per dispatch: each helper's
+    marker is required, so the rule keeps reading the code that moved
+    out of the dispatchers."""
+    for label in ("dispatch-rows", "dispatch-last-tokens",
+                  "dispatch-tables"):
+        dst = _copy_engine_tree(tmp_path / label)
+        dst.write_text(dst.read_text().replace(
+            f"    # tpk-hot: {label}\n", ""))
+        fs = lint(tmp_path / label, rules=["host-sync"])
+        assert any(label in f.message for f in fs)
+
+
+def test_host_fetch_in_gather_rows_turns_red(tmp_path):
+    """Reading a row's pending first token on the host, in the snapshot
+    both dispatchers take, would sync every dispatch behind the prefill
+    it was meant to overlap."""
+    dst = _copy_engine_tree(tmp_path)
+    needle = '                last[i] = st["last"]'
+    src = dst.read_text()
+    assert src.count(needle) == 1
+    dst.write_text(src.replace(
+        needle, needle + '\n            else:\n'
+        '                last[i] = int(st["pending"][0][0])'))
+    fs = lint(tmp_path, rules=["host-sync"])
+    assert len(fs) == 1 and "dispatch-rows" in fs[0].message
 
 
 def test_host_fetch_in_spec_reconcile_turns_red(tmp_path):
@@ -919,7 +766,7 @@ def test_staling_real_schema_turns_red(tmp_path):
 
 def test_tree_is_clean_tier1_gate():
     """THE gate: `python -m tools.tpklint` on the real tree exits 0.
-    Any rule regression, stale artifact, twin drift, bare hot-path sync,
+    Any rule regression, stale artifact, bare hot-path sync,
     or reasonless pragma in the repo turns this (and tier-1) red."""
     out = subprocess.run([sys.executable, "-m", "tools.tpklint"],
                          cwd=REPO, capture_output=True, text=True)

@@ -5,7 +5,6 @@
 Rules (see each module's docstring for the full contract):
 
   host-sync        no host syncs inside `# tpk-hot:` regions
-  sync-regions     `# tpk-sync:` twin regions match modulo declared subs
   spec-schema      generated schema artifacts match KNOBS tables
   lock-discipline  `# guarded-by:` fields only touched under their lock
   cpp-checked-io   fwrite/fsync/rename/ftruncate returns checked in cpp/
@@ -22,7 +21,6 @@ from .core import (Context, Finding, PRAGMA_RULE, RULES, RULE_DOCS,
 
 # Importing the rule modules registers them.
 from . import rules_host_sync      # noqa: F401,E402
-from . import rules_sync_regions   # noqa: F401,E402
 from . import rules_spec_schema    # noqa: F401,E402
 from . import rules_lock           # noqa: F401,E402
 from . import rules_cpp_io         # noqa: F401,E402

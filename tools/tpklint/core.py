@@ -1,9 +1,9 @@
 """tpklint core: rule registry, findings, suppression pragmas, runner.
 
 The platform's correctness rests on invariants that used to live in
-review comments — "zero added host syncs on the hot paths", "these two
-loops are deliberate textual twins", "this field is only touched under
-its lock", "regenerate the spec schema after editing KNOBS". tpklint
+review comments — "zero added host syncs on the hot paths", "this field
+is only touched under its lock", "regenerate the spec schema after
+editing KNOBS". tpklint
 turns each into a machine-checked tier-1 gate (the generalization of
 tools/check_metrics.py, which is rule `metrics` here).
 

@@ -73,6 +73,12 @@ REQUIRED_HOT_PATHS = {
     # sub-batch chains, not just the spec one.
     "spec-dispatch": "kubeflow_tpu/serve/generation.py",
     "spec-reconcile": "kubeflow_tpu/serve/generation.py",
+    # What both dispatchers call (ISSUE 30): the slot-state snapshot,
+    # the on-device last-token splice, the block tables. They run once
+    # per dispatch, so the rule reads them like the dispatchers.
+    "dispatch-rows": "kubeflow_tpu/serve/generation.py",
+    "dispatch-last-tokens": "kubeflow_tpu/serve/generation.py",
+    "dispatch-tables": "kubeflow_tpu/serve/generation.py",
 }
 
 _MARK = re.compile(r"#\s*tpk-hot:\s*(.+?)\s*$")
