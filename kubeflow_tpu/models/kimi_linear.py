@@ -20,7 +20,8 @@ the next layer. Nothing here stands in for the absent chips.
 The trunk is unrolled (the layers' parameter trees differ, so no `nn.scan`)
 with each layer under `jax.checkpoint`. Activations and matmul operands are
 `cfg.dtype` (bf16) with fp32 accumulation; norms, the softmax, the router,
-the gates and the whole KDA core are fp32.
+the gates and the whole KDA core are fp32 (the KDA mixer's inside the two
+kernels of ops/kda.py, see `KDAMixer`).
 """
 
 from __future__ import annotations
@@ -35,7 +36,7 @@ import jax.numpy as jnp
 from kubeflow_tpu.models.llama import LlamaConfig, MLPBlock, RMSNorm
 from kubeflow_tpu.models.moe import HeldExpertsBlock
 from kubeflow_tpu.ops.flash_attention import flash_attention
-from kubeflow_tpu.ops.kda import kda_chunked
+from kubeflow_tpu.ops.kda import kda_mixer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -193,69 +194,73 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
-def causal_conv_silu(x: jax.Array, w: jax.Array) -> jax.Array:
-    """SiLU of a causal depthwise convolution over time, fp32: y_t = sum_i
-    w_i x_{t-K+1+i}. x [B, T, C]; w [K, C]."""
-    kernel = w.shape[0]
-    x = x.astype(jnp.float32)
-    t = x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (kernel - 1, 0), (0, 0)))
-    y = sum(xp[:, i:i + t] * w[i].astype(jnp.float32)
-            for i in range(kernel))
-    return jax.nn.silu(y)
+class _NormScale(nn.Module):
+    """`RMSNorm`'s parameter (`scale`, ones, fp32) without its arithmetic,
+    for a norm that a kernel applies."""
+
+    width: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.with_logical_partitioning(
+            nn.initializers.ones, ("norm",)), (self.width,), jnp.float32)
 
 
 class KDAMixer(nn.Module):
+    """Six projections of x in `cfg.dtype` (q, k, v; the decay's and the
+    output gate's low-rank pairs; beta), `ops/kda.py:kda_mixer`, `o_proj`.
+    What lies between the matmuls runs inside the two KDA kernels, fp32 in
+    VMEM: the short convolutions of q, k and v with their SiLU, the per-head
+    L2 norms of q and k, the decay g = -exp(A_log) softplus(f + dt_bias), the
+    recurrence, the per-head RMS norm of o (`o_norm`) and the sigmoid gate. So
+    the kernels read q, k, v, f and the gate as the matmuls round them
+    (`cfg.dtype`), in place as [B, T, H * d], and write o in the dtype
+    `o_proj` multiplies: each crosses HBM once. Those two roundings are the
+    only ones; the backward rounds the five cotangents once, on the way out
+    of `kda_bwd`. (At a kernel boundary the rounding of the matmuls' results
+    is real. While XLA fused them into this fp32 work it kept them unrounded
+    on the chip: PERF.md, section 6, PR 31.) beta's sigmoid (one value a head
+    a step) stays here."""
+
     cfg: KimiLinearConfig
 
     @nn.compact
     def __call__(self, x):  # [B, T, hidden]
         cfg = self.cfg
-        b, t, _ = x.shape
         heads, dk = cfg.kda_heads, cfg.kda_head_dim
         width = heads * dk
 
-        def conv_w(name):
-            return self.param(
-                name, nn.with_logical_partitioning(
+        def head_proj(name):  # the projection, and its convolution's taps
+            y = _dense(cfg, width, ("embed", "mlp"), name)(x)
+            return y, self.param(
+                name.replace("proj", "conv"), nn.with_logical_partitioning(
                     nn.initializers.variance_scaling(
                         1.0, "fan_in", "uniform", in_axis=0, out_axis=1),
                     (None, "mlp")), (cfg.kda_conv, width), cfg.param_dtype)
 
-        def head_proj(name):
-            y = _dense(cfg, width, ("embed", "mlp"), name)(x)
-            y = causal_conv_silu(y, conv_w(name.replace("proj", "conv")))
-            return y.reshape(b, t, heads, dk)
-
-        q, k, v = head_proj("q_proj"), head_proj("k_proj"), head_proj("v_proj")
-
-        def l2(y):
-            return y * jax.lax.rsqrt(
-                jnp.sum(y * y, axis=-1, keepdims=True) + cfg.kda_norm_eps)
-
-        q, k = l2(q) * dk ** -0.5, l2(k)
+        (q, q_conv), (k, k_conv), (v, v_conv) = (
+            head_proj("q_proj"), head_proj("k_proj"), head_proj("v_proj"))
 
         def low_rank(name):
             y = _dense(cfg, cfg.kda_lowrank, ("embed", None), name + "_a")(x)
-            return _dense(cfg, width, (None, "mlp"), name + "_b")(y).astype(
-                jnp.float32)
+            return _dense(cfg, width, (None, "mlp"), name + "_b")(y)
 
         a_log = self.param("A_log", nn.with_logical_partitioning(
             _a_log_init, (None,)), (heads,), jnp.float32)
         dt_bias = self.param("dt_bias", nn.with_logical_partitioning(
             _dt_bias_init, ("mlp",)), (width,), jnp.float32)
-        g = (-jnp.exp(a_log)[:, None]
-             * jax.nn.softplus(low_rank("f") + dt_bias).reshape(
-                 b, t, heads, dk))
+        f, gate = low_rank("f"), low_rank("g")
         beta = jax.nn.sigmoid(_dense(
             cfg, heads, ("embed", None), "b_proj")(x).astype(jnp.float32))
         with jax.named_scope("kda_scan"):
-            o = kda_chunked(q, k, v, g, beta, chunk=cfg.kda_chunk,
-                            sub=min(16, cfg.kda_chunk))
-        o = RMSNorm(cfg.rms_eps, jnp.float32, name="o_norm")(o)
-        o = o * jax.nn.sigmoid(low_rank("g")).reshape(b, t, heads, dk)
-        return _dense(cfg, cfg.hidden_size, ("mlp", "embed"), "o_proj")(
-            o.reshape(b, t, width).astype(cfg.dtype))
+            o = kda_mixer(
+                q, k, v, f, gate, beta,
+                convs=(q_conv, k_conv, v_conv), dt_bias=dt_bias, a_log=a_log,
+                o_scale=_NormScale(dk, name="o_norm")(),
+                l2_eps=cfg.kda_norm_eps, rms_eps=cfg.rms_eps,
+                out_dtype=cfg.dtype, chunk=cfg.kda_chunk,
+                sub=min(16, cfg.kda_chunk))
+        return _dense(cfg, cfg.hidden_size, ("mlp", "embed"), "o_proj")(o)
 
 
 class MLAMixer(nn.Module):

@@ -1,5 +1,6 @@
-"""Kimi Delta Attention core: the gated delta rule with a per-channel decay,
-in its chunked (WY) form, as two Pallas TPU kernels behind one custom_vjp.
+"""Kimi Delta Attention: the gated delta rule with a per-channel decay, in
+its chunked (WY) form, as two Pallas TPU kernels behind one custom_vjp, with
+the mixer's elementwise work around the rule inside the same two kernels.
 
 Per head, with state S in R^{dk x dv}, S_0 = 0:
 
@@ -37,18 +38,41 @@ u_b.
 
 **What runs where.** `kda_fwd` walks the grid (batch, head group, chunk),
 the chunks in turn ("arbitrary") with the group's states [dv, dk] fp32 in
-VMEM scratch; a step reads the chunk's rows of q, k, g, v in place from the
-[B, T, H * d] arrays (a block of C rows by the group's lanes: no transpose
-on either side of the call) and beta, computes everything above in VMEM and
-registers, and writes o and the state the chunk started from. `kda_bwd`
-walks the same grid from the last chunk with dS in the scratch: it reads
-the chunk's inputs, its start state and dO, takes `jax.vjp` of the same
-chunk function inside the kernel body (so the chunk is recomputed there,
-never stored) and writes dq, dk, dv, dg, dbeta. Only those arrays and the
-start states (dk * dv * 4 bytes a chunk a head, live while the layer's
-backward runs) cross HBM. A head group is 128 / C heads (two at C = 64),
-stacked: time runs along all 128 lanes in the pairwise part, and the
-matmuls that do not involve a head's state are shared, block-diagonal.
+VMEM scratch; a step reads the chunk's rows of its wide operands in place
+from the [B, T, H * d] arrays (a block of C rows by the group's lanes: no
+transpose on either side of the call) and beta, computes everything in VMEM
+and registers, and writes o and the state the chunk started from. `kda_bwd`
+walks the same grid from the last chunk with dS in the scratch: it reads the
+chunk's inputs, its start state and dO, takes `jax.vjp` of the same chunk
+function inside the kernel body (so the chunk is recomputed there, never
+stored) and writes the operands' cotangents. Only those arrays and the start
+states (dk * dv * 4 bytes a chunk a head, live while the layer's backward
+runs) cross HBM. A head group is 128 / C heads (two at C = 64), stacked:
+time runs along all 128 lanes in the pairwise part, and the matmuls that do
+not involve a head's state are shared, block-diagonal.
+
+The chunk function is one of two. `kda_chunked` runs `_chunk`, the rule
+alone, on q, k, v, g as given (fp32). `kda_mixer` runs `_mixer_chunk`: a
+prologue, `_chunk`, an epilogue, which is everything `KDAMixer` does between
+its matmuls. The kernels then read what the five projections put out (q, k,
+v, the decay's f, the output gate), and per chunk: the causal depthwise
+convolutions of q, k, v and their SiLU; the per-head L2 norms of q and k and
+q's d^-1/2; g = -exp(A_log) softplus(f + dt_bias); the rule; the per-head
+RMS norm of o with its learned scale; times sigmoid(gate). The small
+parameters ride in as rows of one [16, H * d] table (`_channel_table`), a
+channel a lane; `kda_bwd` adds their gradients up over the chunks in an
+output block that stays in VMEM, and what leaves the call is [B, 16, H * d].
+
+**The convolution's history.** A chunk's first K - 1 rows need the rows
+before the chunk. Both kernels get them through one more block spec a
+convolved operand: the last 16 rows of the chunk before (whole bf16 tiles;
+the first chunk's block is zeroed in the kernel, which is `jnp.pad`'s zeros).
+In `kda_fwd` and in `kda_bwd`'s recomputation alike that is the *earlier*
+chunk in time. The cotangent of those rows belongs to the earlier chunk,
+which `kda_bwd` visits next: it waits in VMEM scratch beside dS and is added
+to that chunk's cotangent before the write. A padded tail (T not a multiple
+of C) leaves the state alone after the prologue: beta is padded with 0 and f
+with a value whose softplus is 0, so g = 0 there.
 
 **Inside a chunk.** The pairs of a sub-chunk are taken by diagonals with
 time along the lanes and the channels down the rows: the d-th diagonal is a
@@ -59,19 +83,26 @@ unit. Nothing of a chunk that is larger than its inputs exists outside the
 kernel.
 
 **Precision**, as `assumed.precision` of the Kimi configuration states it or
-finer: everything is held in fp32. The matmuls (`_dot`: the cross-sub-chunk
-products, the products with the state, inv A, inv rhs, the substitution, B U,
-the state's update, and the same in the backward) are three bf16 passes with
-fp32 accumulation, hi*hi + hi*lo + lo*hi, what `Precision.HIGH` is, written
-out because Mosaic in jax 0.9.0 lowers only DEFAULT and HIGHEST (interpreted
-they are the host's fp32 product, what HIGH is on a CPU). G's
-cumulative sum is a 0/1 triangular matrix times g cut into *three* bf16
-parts: every product exact, the sums fp32 (G passes -600 in a chunk; two
-parts would put 1e-4 on a decay factor, tests pin it). The pairwise
-products, the Neumann products and beta's placement are fp32 multiplies and
-sums on the vector unit. Against the recurrence at `highest` on the chip
-the outputs and all five gradients read 5e-6 (the `jnp` form this replaced:
-1.3e-5; my chip run, PR 29).
+finer: between a kernel's reads and its writes everything is fp32. Blocks are
+converted as they are loaded, so the operands may be bf16: `KDAMixer` hands
+over the projections' outputs as the matmuls round them and gets o in the
+dtype `o_proj` multiplies, and those are the only roundings to bf16 on the
+way forward. On the way back dO arrives in o's dtype and each operand's
+cotangent is rounded to the operand's dtype once, at the write, after the
+chunk's own part and the history's part have been added in fp32; the small
+parameters' gradients never leave fp32. The matmuls (`_dot`: the
+cross-sub-chunk products, the products with the state, inv A, inv rhs, the
+substitution, B U, the state's update, and the same in the backward) are
+three bf16 passes with fp32 accumulation, hi*hi + hi*lo + lo*hi, what
+`Precision.HIGH` is, written out because Mosaic in jax 0.9.0 lowers only
+DEFAULT and HIGHEST (interpreted they are the host's fp32 product, what HIGH
+is on a CPU). G's cumulative sum is a 0/1 triangular matrix times g cut into
+*three* bf16 parts: every product exact, the sums fp32 (G passes -600 in a
+chunk; two parts would put 1e-4 on a decay factor, tests pin it). The
+pairwise products, the Neumann products, beta's placement, the convolutions,
+norms and gates are fp32 on the vector unit. Against the recurrence at
+`highest` on the chip the core's outputs and all five gradients read 5e-6
+(the `jnp` form this replaced: 1.3e-5; my chip run, PR 29).
 
 On anything but a TPU the same kernels run interpreted (`on_tpu()`, as in
 ops/flash_attention.py). Compiled, the group's lanes must be whole tiles:
@@ -82,6 +113,7 @@ the toy widths of the tests other than (3 heads, dv 8) compile too.
 from __future__ import annotations
 
 import functools
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -322,137 +354,312 @@ def _chunk(q, k, v, g, beta, st, *, pack: int, sub: int, interpret: bool):
                             axis=1), jnp.concatenate(states, axis=0))
 
 
-# -- the two kernels ----------------------------------------------------------
+# -- the mixer's elementwise work around a chunk ------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, st_ref, *,
-                chunk_fn):
-    @pl.when(pl.program_id(2) == 0)
+def _per_head(x, pack: int, fn):
+    """`fn` over each head's lanes of x [C, pack * d], side by side again."""
+    d = x.shape[1] // pack
+    return jnp.concatenate(
+        [fn(x[:, h * d:(h + 1) * d]) for h in range(pack)], axis=1)
+
+
+def _conv_silu(x, before, w, roll):
+    """SiLU of the causal depthwise convolution y_t = sum_i w_i x_{t-K+1+i}.
+    x [C, n] the chunk's rows, `before` [R, n] the R >= K - 1 rows that
+    precede them (zeros before the sequence starts), w [K, n]. A tap is a
+    roll down the sublanes of the two stacked."""
+    taps, lead = w.shape[0], before.shape[0]
+    rows = jnp.concatenate([before, x], axis=0)
+    y = w[taps - 1:taps] * x
+    for s in range(1, taps):
+        y = y + w[taps - 1 - s:taps - s] * roll(rows, s, 0)[lead:]
+    return y * jax.nn.sigmoid(y)
+
+
+def _softplus(x):
+    return jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x)))
+
+
+def _mixer_chunk(q, k, v, f, gate, beta, q_before, k_before, v_before, table,
+                 st, *, taps: int, l2_eps: float, rms_eps: float, pack: int,
+                 sub: int, interpret: bool):
+    """`_chunk` between the KDA mixer's prologue and epilogue, all fp32.
+    q, k, v, f, gate [C, pack * d]: what the five projections put out for the
+    chunk's rows; *_before [R, pack * d]: the rows before the chunk, for the
+    convolutions; table: the group's lanes of `_channel_table`."""
+    roll = functools.partial(_roll, interpret=interpret)
+    d = q.shape[1] // pack
+
+    def conv(x, before, which):
+        return _conv_silu(x, before, table[which * taps:(which + 1) * taps],
+                          roll)
+
+    def l2(y):
+        return y * jax.lax.rsqrt(jnp.sum(y * y, axis=1, keepdims=True)
+                                 + l2_eps)
+
+    def rms(y):
+        return y * jax.lax.rsqrt(jnp.mean(y * y, axis=1, keepdims=True)
+                                 + rms_eps)
+
+    dt_bias, a_log, o_scale = (table[3 * taps + r:3 * taps + r + 1]
+                               for r in range(3))
+    q = _per_head(conv(q, q_before, 0), pack, l2) * d ** -0.5
+    k = _per_head(conv(k, k_before, 1), pack, l2)
+    g = -jnp.exp(a_log) * _softplus(f + dt_bias)
+    o, st = _chunk(q, k, conv(v, v_before, 2), g, beta, st, pack=pack,
+                   sub=sub, interpret=interpret)
+    return _per_head(o, pack, rms) * o_scale * jax.nn.sigmoid(gate), st
+
+
+def _channel_table(convs, dt_bias, a_log, o_scale):
+    """The mixer's small parameters as rows of one fp32 [R, H * d] array, a
+    channel a lane: the taps of the q, k and v convolutions, dt_bias, A_log
+    (a head's value on each of its lanes), the output norm's scale (the same
+    d values under every head); zero rows up to a multiple of 8. Built in
+    `jnp` outside the kernels, so that autodiff spreads the table's cotangent
+    back over the parameters."""
+    heads, d = a_log.shape[0], o_scale.shape[0]
+    table = jnp.concatenate(
+        [*convs, dt_bias[None], jnp.repeat(a_log, d)[None],
+         jnp.tile(o_scale, heads)[None]]).astype(_F32)
+    return jnp.pad(table, ((0, -table.shape[0] % 8), (0, 0)))
+
+
+# -- the two kernels ----------------------------------------------------------
+#
+# Both take a chunk function `fn(*rows, *before, *consts, st) -> (o, st)` and
+# its operands in three kinds: `rows`, a block a chunk (the wide arrays and
+# beta); `before`, the last rows of the chunk before, one for each of the first
+# len(before) of `rows`; `consts`, blocks that every chunk of a head group
+# shares. The core alone has rows only.
+
+def _operands(refs, n_rows: int, n_before: int, has_before):
+    """The blocks in fp32, whatever dtype the arrays have, `before` zeroed
+    where the chunk is the sequence's first (its block spec then points at
+    rows that are not before it)."""
+    live = jnp.where(has_before, 1.0, 0.0).astype(_F32)
+    return [r[...].astype(_F32) * live if n_rows <= j < n_rows + n_before
+            else r[...].astype(_F32) for j, r in enumerate(refs)]
+
+
+def _fwd_kernel(*refs, chunk_fn, n_rows, n_before):
+    *ins, o_ref, s_ref, st_ref = refs
+    i = pl.program_id(2)
+
+    @pl.when(i == 0)
     def _():
         st_ref[...] = jnp.zeros_like(st_ref)
 
     st = st_ref[...]
     s_ref[...] = st
-    o, st_ref[...] = chunk_fn(q_ref[...], k_ref[...], v_ref[...], g_ref[...],
-                              b_ref[...], st)
-    o_ref[...] = o
+    o, st_ref[...] = chunk_fn(*_operands(ins, n_rows, n_before, i > 0), st)
+    o_ref[...] = o.astype(o_ref.dtype)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dst_ref, *, chunk_fn):
-    @pl.when(pl.program_id(2) == 0)
+def _bwd_kernel(*refs, chunk_fn, n_rows, n_before, n_consts):
+    """Walks the chunks from the last. Cotangents of `rows` are written a
+    block a chunk, rounded to the array's dtype once everything has been added
+    in fp32; those of `before` belong to the chunk visited next and wait in
+    scratch beside dS; those of `consts` add up over the chunks in an output
+    block that stays in VMEM."""
+    n_in = n_rows + n_before + n_consts
+    ins, (s_ref, do_ref) = refs[:n_in], refs[n_in:n_in + 2]
+    outs = refs[n_in + 2:]
+    d_rows, d_consts = outs[:n_rows], outs[n_rows:n_rows + n_consts]
+    dst_ref, *d_before = outs[n_rows + n_consts:]
+    step = pl.program_id(2)
+
+    @pl.when(step == 0)
     def _():
-        dst_ref[...] = jnp.zeros_like(dst_ref)
+        for ref in (dst_ref, *d_before, *d_consts):
+            ref[...] = jnp.zeros_like(ref)
 
-    _, vjp = jax.vjp(chunk_fn, q_ref[...], k_ref[...], v_ref[...], g_ref[...],
-                     b_ref[...], s_ref[...])
-    (dq_ref[...], dk_ref[...], dv_ref[...], dg_ref[...], db_ref[...],
-     dst_ref[...]) = vjp((do_ref[...], dst_ref[...]))
+    _, vjp = jax.vjp(chunk_fn, *_operands(
+        ins, n_rows, n_before, step < pl.num_programs(2) - 1), s_ref[...])
+    *cts, dst_ref[...] = vjp((do_ref[...].astype(_F32), dst_ref[...]))
+    for j, ref in enumerate(d_rows):
+        ct = cts[j]
+        if j < n_before:  # what the chunk after this one left for its tail
+            lead = ct.shape[0] - d_before[j].shape[0]
+            tail = ct[lead:] + d_before[j][...]
+            ct = jnp.concatenate([ct[:lead], tail]) if lead else tail
+        ref[...] = ct.astype(ref.dtype)
+    for ref, ct in zip(d_before, cts[n_rows:]):
+        ref[...] = ct
+    for ref, ct in zip(d_consts, cts[n_rows + n_before:]):
+        ref[...] += ct
 
 
-def _specs(n: int, chunk: int, pack: int, dk: int, dv: int, reverse: bool):
-    """Block specs on the grid (batch, head group, chunk): [B, T, H * d]
-    arrays read in place, a head group's `pack * d` lanes of a chunk's rows;
-    beta [B, H / pack, N, 1, pack * C]; states [B, H / pack, N, pack * dv,
-    dk]. `reverse` walks the chunks from the last."""
-    def at(i):
-        return n - 1 - i if reverse else i
+class _Plan(NamedTuple):
+    """What a pair of kernels is traced for; hashable, a static argument."""
+    chunk: int
+    sub: int
+    pack: int
+    interpret: bool
+    out_dtype: Any
+    #: (taps, l2_eps, rms_eps) of the mixer around the chunk; None: the core
+    #: alone.
+    mixer: tuple | None
 
-    def wide(d):
-        return pl.BlockSpec((None, chunk, pack * d),
-                            lambda b, h, i: (b, at(i), h))
+    @property
+    def n_before(self) -> int:
+        return 3 if self.mixer else 0  # q, k and v pass a convolution
 
-    beta = pl.BlockSpec((None, None, None, 1, pack * chunk),
-                        lambda b, h, i: (b, h, at(i), 0, 0))
-    state = pl.BlockSpec((None, None, None, pack * dv, dk),
-                         lambda b, h, i: (b, h, at(i), 0, 0))
-    return wide(dk), wide(dv), beta, state
+    @property
+    def before_rows(self) -> int:
+        """Rows of the block that brings a chunk the rows before it: whole
+        (16, 128) tiles of bf16, and a divisor of the chunk."""
+        return 16 if self.chunk % 16 == 0 else self.chunk
+
+    def chunk_fn(self):
+        kw = dict(pack=self.pack, sub=self.sub, interpret=self.interpret)
+        if not self.mixer:
+            return functools.partial(_chunk, **kw)
+        taps, l2_eps, rms_eps = self.mixer
+        return functools.partial(_mixer_chunk, taps=taps, l2_eps=l2_eps,
+                                 rms_eps=rms_eps, **kw)
+
+
+class _Specs:
+    """Block specs on the grid (batch, head group, chunk). [B, T, H * d]
+    arrays are read in place, a head group's `pack * d` lanes of a chunk's
+    rows; beta is [B, H / pack, N, 1, pack * C] and the states [B, H / pack,
+    N, pack * dv, dk]. `reverse` walks the chunks from the last."""
+
+    def __init__(self, plan: _Plan, groups: int, n: int, reverse: bool):
+        self.plan, self.groups = plan, groups
+        self.at = (lambda i: n - 1 - i) if reverse else (lambda i: i)
+
+    def rows(self, x):
+        return pl.BlockSpec(
+            (None, self.plan.chunk, x.shape[2] // self.groups),
+            lambda b, h, i: (b, self.at(i), h))
+
+    def before(self, x):
+        """The last rows of the chunk before this one. The first chunk has
+        none: the index stays in the array and the kernel zeroes the block."""
+        tail = self.plan.before_rows
+        per_chunk = self.plan.chunk // tail
+        return pl.BlockSpec(
+            (None, tail, x.shape[2] // self.groups),
+            lambda b, h, i: (b, jnp.maximum(self.at(i) * per_chunk - 1, 0), h))
+
+    def per_chunk(self, *dims):  # beta, states
+        return pl.BlockSpec((None, None, None) + dims,
+                            lambda b, h, i: (b, h, self.at(i), 0, 0))
+
+    def const(self, x):  # [R, H * d]: the group's lanes, the same every chunk
+        return pl.BlockSpec((x.shape[0], x.shape[1] // self.groups),
+                            lambda b, h, i: (0, h))
+
+    def d_const(self, x):  # [B, R, H * d]: one block while the chunks run
+        return pl.BlockSpec((None, x.shape[0], x.shape[1] // self.groups),
+                            lambda b, h, i: (b, 0, h))
 
 
 def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
           interpret):
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, scratch_shapes=[scratch], name=name,
+        out_shape=out_shape, scratch_shapes=scratch, name=name,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret)
+
+
+def _layout(rows, plan: _Plan):
+    """(batch, head groups, chunks), the state's shape [pack * dv, dk], and
+    the arrays whose rows before a chunk the kernels also read."""
+    q, _, v, *_, beta = rows
+    b, groups, n = beta.shape[:3]
+    heads = groups * plan.pack
+    state = (v.shape[2] // heads * plan.pack, q.shape[2] // heads)
+    return (b, groups, n), state, rows[:plan.n_before]
 
 
 # The two calls are jitted on their own: tracing a chunk and lowering it for
 # Mosaic takes seconds, and every layer of a model, its rematerialised
 # forward included, then shares one trace of each kernel.
 
-@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
-def _forward(q, k, v, g, beta, chunk, sub, pack, interpret):
-    """q, k, g [B, T, H * dk], v [B, T, H * dv], beta [B, H / pack, N, 1,
-    pack * C], T = N * C. Returns o [B, T, H * dv] and the state every
-    chunk started from, [B, H / pack, N, pack * dv, dk]."""
-    b = q.shape[0]
-    groups, n = beta.shape[1], beta.shape[2]
-    dk, dv = q.shape[2] // (groups * pack), v.shape[2] // (groups * pack)
-    wide_k, wide_v, beta_s, state_s = _specs(n, chunk, pack, dk, dv, False)
-    chunk_fn = functools.partial(_chunk, pack=pack, sub=sub,
-                                 interpret=interpret)
+@functools.partial(jax.jit, static_argnums=(2,))
+def _forward(rows, consts, plan: _Plan):
+    """rows: the wide arrays [B, T, H * d] (q, k, v first), T = N * C, then
+    beta; consts: arrays [R, H * d]. Returns o [B, T, H * dv] and the state
+    every chunk started from."""
+    grid, state, before = _layout(rows, plan)
+    specs = _Specs(plan, grid[1], grid[2], False)
+    *wide, beta = rows
+    v = rows[2]
     return _call(
-        functools.partial(_fwd_kernel, chunk_fn=chunk_fn), "kda_fwd",
-        (b, groups, n), [wide_k, wide_k, wide_v, wide_k, beta_s],
-        [wide_v, state_s],
-        [jax.ShapeDtypeStruct(v.shape, _F32),
-         jax.ShapeDtypeStruct((b, groups, n, pack * dv, dk), _F32)],
-        pltpu.VMEM((pack * dv, dk), _F32), interpret)(q, k, v, g, beta)
+        functools.partial(_fwd_kernel, chunk_fn=plan.chunk_fn(),
+                          n_rows=len(rows), n_before=len(before)),
+        "kda_fwd", grid,
+        [*map(specs.rows, wide), specs.per_chunk(1, beta.shape[-1]),
+         *map(specs.before, before), *map(specs.const, consts)],
+        [specs.rows(v), specs.per_chunk(*state)],
+        [jax.ShapeDtypeStruct(v.shape, plan.out_dtype),
+         jax.ShapeDtypeStruct(grid + state, _F32)],
+        [pltpu.VMEM(state, _F32)], plan.interpret)(*rows, *before, *consts)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
-def _backward(q, k, v, g, beta, starts, do, chunk, sub, pack, interpret):
-    b, groups, n = starts.shape[:3]
-    dk, dv = starts.shape[4], starts.shape[3] // pack
-    wide_k, wide_v, beta_s, state_s = _specs(n, chunk, pack, dk, dv, True)
-    chunk_fn = functools.partial(_chunk, pack=pack, sub=sub,
-                                 interpret=interpret)
-    return tuple(_call(
-        functools.partial(_bwd_kernel, chunk_fn=chunk_fn), "kda_bwd",
-        (b, groups, n),
-        [wide_k, wide_k, wide_v, wide_k, beta_s, state_s, wide_v],
-        [wide_k, wide_k, wide_v, wide_k, beta_s],
-        [jax.ShapeDtypeStruct(x.shape, _F32) for x in (q, k, v, g, beta)],
-        pltpu.VMEM((pack * dv, dk), _F32), interpret)(
-            q, k, v, g, beta, starts, do))
+@functools.partial(jax.jit, static_argnums=(4,))
+def _backward(rows, consts, starts, do, plan: _Plan):
+    """Cotangents of `rows`, each in its array's dtype, and of `consts`."""
+    grid, state, before = _layout(rows, plan)
+    specs = _Specs(plan, grid[1], grid[2], True)
+    *wide, beta = rows
+    row_specs = [*map(specs.rows, wide), specs.per_chunk(1, beta.shape[-1])]
+    outs = _call(
+        functools.partial(_bwd_kernel, chunk_fn=plan.chunk_fn(),
+                          n_rows=len(rows), n_before=len(before),
+                          n_consts=len(consts)),
+        "kda_bwd", grid,
+        [*row_specs, *map(specs.before, before), *map(specs.const, consts),
+         specs.per_chunk(*state), specs.rows(do)],
+        [*row_specs, *map(specs.d_const, consts)],
+        [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in rows]
+        + [jax.ShapeDtypeStruct(grid[:1] + c.shape, _F32) for c in consts],
+        [pltpu.VMEM(state, _F32)]
+        + [pltpu.VMEM((plan.before_rows, x.shape[2] // grid[1]), _F32)
+           for x in before],
+        plan.interpret)(*rows, *before, *consts, starts, do)
+    return (tuple(outs[:len(rows)]),
+            tuple(jnp.sum(d, axis=0) for d in outs[len(rows):]))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _kda(q, k, v, g, beta, chunk, sub, pack, interpret):
-    return _forward(q, k, v, g, beta, chunk, sub, pack, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _kda(rows, consts, plan):
+    return _forward(rows, consts, plan)[0]
 
 
-def _kda_fwd(q, k, v, g, beta, chunk, sub, pack, interpret):
-    o, starts = _forward(q, k, v, g, beta, chunk, sub, pack, interpret)
-    return o, (q, k, v, g, beta, starts)
+def _kda_fwd(rows, consts, plan):
+    o, starts = _forward(rows, consts, plan)
+    return o, (rows, consts, starts)
 
 
-def _kda_bwd(chunk, sub, pack, interpret, res, do):
-    return _backward(*res, do, chunk, sub, pack, interpret)
+def _kda_bwd(plan, res, do):
+    return _backward(*res, do, plan)
 
 
 _kda.defvjp(_kda_fwd, _kda_bwd)
 
+#: A decay pre-activation whose softplus is exactly 0 in fp32 and in bf16.
+_NO_DECAY = -1e30
 
-def kda_chunked(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
-                interpret: bool | None = None):
-    """The recurrence above over a whole sequence.
 
-    q, k [B, T, H, dk] (already normalised and scaled), v [B, T, H, dv],
-    g [B, T, H, dk] the log-decay (<= 0), beta [B, T, H]. Returns o
-    [B, T, H, dv] in fp32.
-    T need not be a multiple of `chunk`: the tail is padded with steps that
-    leave the state as it is (g = 0, beta = 0) and is cut off again.
-    `interpret` None: compiled on a TPU, interpreted anywhere else."""
+def _scan(wide, fills, beta, consts, *, chunk, sub, mixer, out_dtype,
+          interpret):
+    """`_kda` over a whole sequence: the wide arrays [B, T, H * d] and beta
+    [B, T, H] padded to whole chunks (each wide array with its `fills`), beta
+    laid out by head group, the result cut to T again."""
     if chunk % sub or sub & (sub - 1):
         raise ValueError(f"chunk {chunk} must be a multiple of sub {sub}, "
                          "a power of two")
     if interpret is None:
         interpret = not on_tpu()
-    b, t, h, dk = q.shape
-    dv = v.shape[-1]
+    b, t, h = beta.shape
+    dk, dv = wide[0].shape[2] // h, wide[2].shape[2] // h
     pad = -t % chunk
     n = (t + pad) // chunk
     # Heads side by side along the 128 lanes of the pairwise part; all of
@@ -463,13 +670,58 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
     if pack * dk % 128 or pack * dv % 128:
         pack = h
 
-    def flat(x):  # [B, T, H, d] -> [B, N * C, H * d] fp32: a reshape
-        x = x.astype(_F32).reshape(b, t, -1)
-        return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+    def whole_chunks(x, fill=0.0):
+        return jnp.pad(x, ((0, 0), (0, pad), (0, 0)),
+                       constant_values=fill) if pad else x
 
     # beta is the one small array: [B, H / pack, N, 1, pack * C].
-    bt = flat(beta).reshape(b, n, chunk, h // pack, pack)
+    bt = whole_chunks(beta.astype(_F32)).reshape(b, n, chunk, h // pack, pack)
     bt = bt.transpose(0, 3, 1, 4, 2).reshape(b, h // pack, n, 1, pack * chunk)
-    o = _kda(flat(q), flat(k), flat(v), flat(g), bt, chunk, sub, pack,
-             interpret)
-    return o[:, :t].reshape(b, t, h, dv)
+    o = _kda((*map(whole_chunks, wide, fills), bt), tuple(consts),
+             _Plan(chunk, sub, pack, interpret, out_dtype, mixer))
+    return o[:, :t]
+
+
+def kda_chunked(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
+                interpret: bool | None = None):
+    """The recurrence above over a whole sequence: the core alone.
+
+    q, k [B, T, H, dk] (already normalised and scaled), v [B, T, H, dv],
+    g [B, T, H, dk] the log-decay (<= 0), beta [B, T, H]. Returns o
+    [B, T, H, dv] in fp32.
+    T need not be a multiple of `chunk`: the tail is padded with steps that
+    leave the state as it is (g = 0, beta = 0) and is cut off again.
+    `interpret` None: compiled on a TPU, interpreted anywhere else."""
+    b, t, h, _ = q.shape
+    o = _scan([x.astype(_F32).reshape(b, t, -1) for x in (q, k, v, g)],
+              (0.0,) * 4, beta, (), chunk=chunk, sub=sub, mixer=None,
+              out_dtype=_F32, interpret=interpret)
+    return o.reshape(b, t, h, -1)
+
+
+def kda_mixer(q, k, v, f, gate, beta, *, convs, dt_bias, a_log, o_scale,
+              l2_eps: float, rms_eps: float, out_dtype=None, chunk: int = 64,
+              sub: int = 16, interpret: bool | None = None):
+    """The KDA mixer between its projections, in the two kernels:
+
+        q, k, v <- SiLU(causal depthwise conv(.))      convs: three [K, H * d]
+        q <- q / sqrt(sum_head q^2 + l2_eps) * d^-1/2,  k likewise, unscaled
+        g  = -exp(A_log) * softplus(f + dt_bias)        a_log [H], dt_bias [H * d]
+        o  = the recurrence above
+        o <- o / sqrt(mean_head o^2 + rms_eps) * o_scale * sigmoid(gate)
+
+    q, k, v, f, gate [B, T, H * d] are what the five projections put out, in
+    their own dtype; beta [B, T, H] (after its sigmoid); o_scale [d]. Returns
+    o [B, T, H * d] in `out_dtype` (None: q's). Everything between the reads
+    and the write is fp32. T need not be a multiple of `chunk`: the padded
+    tail gets beta = 0 and an f whose softplus is 0, so g = 0 there."""
+    mixer = (convs[0].shape[0], float(l2_eps), float(rms_eps))
+    if mixer[0] - 1 > min(chunk, 16):
+        raise ValueError(f"a convolution of {mixer[0]} taps reaches past the "
+                         f"{min(chunk, 16)} rows a chunk is given of the one "
+                         "before it")
+    return _scan([q, k, v, f, gate], (0.0, 0.0, 0.0, _NO_DECAY, 0.0), beta,
+                 [_channel_table(convs, dt_bias, a_log, o_scale)],
+                 chunk=chunk, sub=sub, mixer=mixer,
+                 out_dtype=jnp.dtype(out_dtype or q.dtype),
+                 interpret=interpret)

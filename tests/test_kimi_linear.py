@@ -25,7 +25,7 @@ from kubeflow_tpu.models import kimi_linear as kl
 from kubeflow_tpu.models import moe
 from kubeflow_tpu.models.moe import HeldExpertsBlock
 from kubeflow_tpu.ops import flash_attention as fa
-from kubeflow_tpu.ops.kda import kda_chunked
+from kubeflow_tpu.ops.kda import kda_chunked, kda_mixer
 from kubeflow_tpu.ops.reference import naive_attention
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -173,6 +173,165 @@ def test_kda_refuses_a_chunk_its_blocks_do_not_tile(chunk, sub):
     substitution whole blocks in a chunk."""
     with pytest.raises(ValueError, match="multiple of sub"):
         kda_chunked(*kda_inputs(64, 0.1), chunk=chunk, sub=sub)
+
+
+# -- KDA: the fused mixer call against the reference's pieces -----------------
+
+L2_EPS, RMS_EPS = 1e-6, 1e-5
+#: As KDA_SHAPES, one width a head: the mixer's q, k, v, f and gate share it.
+MIXER_SHAPES = {"toy": {}, "cell": dict(b=1, h=2, d=128)}
+
+
+def mixer_inputs(t, seed=0, b=2, h=3, d=16, taps=4):
+    """(the five pre-activations, beta, the three convolutions' taps, dt_bias,
+    A_log, the output norm's scale), fp32, drawn as the model draws them."""
+    r = np.random.default_rng(seed)
+    w = h * d
+    pre = tuple(r.normal(size=(b, t, w)) for _ in range(5))
+    beta = 1 / (1 + np.exp(-r.normal(size=(b, t, h))))
+    convs = tuple(r.uniform(-0.5, 0.5, size=(taps, w)) for _ in range(3))
+    small = (r.normal(size=(w,)) - 3, np.log(r.uniform(1, 16, size=(h,))),
+             1 + 0.1 * r.normal(size=(d,)))
+    return jax.tree.map(lambda x: jnp.asarray(x, jnp.float32),
+                        (pre, beta, convs) + small)
+
+
+def mixer_fused(pre, beta, convs, dt_bias, a_log, o_scale):
+    return kda_mixer(*pre, beta, convs=convs, dt_bias=dt_bias, a_log=a_log,
+                     o_scale=o_scale, l2_eps=L2_EPS, rms_eps=RMS_EPS)
+
+
+def mixer_plain(pre, beta, convs, dt_bias, a_log, o_scale):
+    """`ref.kda_mixer` between its projections, from its own pieces."""
+    q, k, v, f, gate = pre
+    (b, t, w), h = q.shape, a_log.shape[0]
+    d = w // h
+
+    def head(x, taps):
+        return jax.nn.silu(ref.causal_conv(x, taps)).reshape(b, t, h, d)
+
+    def l2(y):
+        return y * jax.lax.rsqrt(jnp.sum(y * y, axis=-1, keepdims=True)
+                                 + L2_EPS)
+
+    g = (-jnp.exp(a_log)[:, None]
+         * jax.nn.softplus(f + dt_bias).reshape(b, t, h, d))
+    o = ref.kda_recurrence(l2(head(q, convs[0])) * d ** -0.5,
+                           l2(head(k, convs[1])), head(v, convs[2]), g, beta)
+    o = ref.rms_norm(o, o_scale, RMS_EPS)
+    return (o * jax.nn.sigmoid(gate).reshape(b, t, h, d)).reshape(b, t, w)
+
+
+def mixer_grads(fn, args, weights):
+    return jax.grad(lambda *a: jnp.sum(fn(*a) * weights),
+                    argnums=tuple(range(len(args))))(*args)
+
+
+def assert_grads_match(got, want, limit=1e-4):
+    flat = jax.tree_util.tree_leaves_with_path(got)
+    assert len(flat) == 12  # 5 pre-activations, beta, 3 x taps, 3 small
+    for (path, a), e in zip(flat, jax.tree.leaves(want)):
+        name = jax.tree_util.keystr(path)
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert rel(a, e) < limit, name
+
+
+@pytest.mark.parametrize("t,shape", [  # four chunks each: a shape's two
+    (256, "toy"), (200, "toy"),        # lengths share one trace of the kernels
+    (256, "cell"), (200, "cell"),      # chunk-aligned; ragged: a padded tail
+])
+def test_kda_mixer_matches_reference_pieces(t, shape):
+    """Convolutions, SiLU, L2 norms, the decay, the recurrence, the output
+    norm and the gate in the two kernels, against the same in plain `jnp`:
+    the output, and the gradient with respect to every input and parameter."""
+    args = mixer_inputs(t, **MIXER_SHAPES[shape])
+    out, want = mixer_fused(*args), mixer_plain(*args)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    assert rel(out, want) < 1e-5
+    weights = jnp.cos(jnp.arange(out.shape[-1]))
+    assert_grads_match(mixer_grads(mixer_fused, args, weights),
+                       mixer_grads(mixer_plain, args, weights))
+
+
+@pytest.mark.parametrize("t,row", [
+    (256, 63),   # the last row of chunk 0, into chunk 1
+    (200, 191),  # the last row of chunk 2, into a chunk with 8 real rows
+    (256, 15),   # the row a first chunk would take for its history, unmasked
+])
+def test_kda_convolution_history_crosses_chunks(t, row):
+    """v's pre-activation is one impulse at `row`, where beta is 0: the state
+    learns nothing of it, and all that o can hold of it comes from the three
+    rows after it, through the convolution's history. The loss reads only the
+    rows after `row`: the impulse's gradient is what comes back that way."""
+    pre, beta, *rest = mixer_inputs(t)
+    impulse = jnp.zeros_like(pre[2]).at[:, row].set(1.0)
+    args = ((*pre[:2], impulse, *pre[3:]), beta.at[:, row].set(0.0), *rest)
+    out, want = mixer_fused(*args), mixer_plain(*args)
+    assert not float(jnp.max(jnp.abs(out[:, :row + 1])))  # nothing before it
+    after = out[:, row + 1:row + 4]
+    assert float(jnp.min(jnp.linalg.norm(after, axis=(0, 2)))) > 1e-2
+    assert rel(after, want[:, row + 1:row + 4]) < 1e-5
+    assert rel(out, want) < 1e-5
+    weights = (jnp.arange(t) > row)[:, None] * jnp.cos(
+        jnp.arange(out.shape[-1]))
+    got = mixer_grads(mixer_fused, args, weights)
+    exp = mixer_grads(mixer_plain, args, weights)
+    assert float(jnp.linalg.norm(exp[0][2][:, row])) > 1e-3
+    assert rel(got[0][2][:, row], exp[0][2][:, row]) < 1e-4
+    assert_grads_match(got, exp)
+
+
+def test_kda_mixer_refuses_a_convolution_longer_than_its_history():
+    """A chunk is handed 16 rows of the one before it, so 17 taps at most."""
+    pre, beta, convs, *small = mixer_inputs(32, taps=18)
+    with pytest.raises(ValueError, match="18 taps"):
+        mixer_fused(pre, beta, convs, *small)
+
+
+def _pallas_calls(jaxpr):
+    """Every `pallas_call` equation in a jaxpr, through the calls it nests
+    (but not into a kernel's body)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _pallas_calls(sub)
+
+
+def test_kda_kernels_take_and_return_the_activation_dtype():
+    """`KDAMixer` at bf16, forward and backward, read off the jaxpr: the two
+    kernels take the five pre-activations as the projections round them and
+    hand o back as `o_proj` multiplies it, and nothing fp32 of [B, T, H * d]
+    goes into or comes out of either."""
+    cfg = kl.kimi_linear_tiny()
+    assert cfg.dtype == jnp.bfloat16
+    mixer = kl.KDAMixer(cfg)
+    b, t, width = 2, 32, cfg.kda_heads * cfg.kda_head_dim
+    assert width != cfg.hidden_size
+    x = jnp.ones((b, t, cfg.hidden_size), cfg.dtype)
+    params = nn.meta.unbox(mixer.init(jax.random.key(0), x)["params"])
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(
+        mixer.apply({"params": p}, x).astype(jnp.float32)),
+        argnums=(0, 1)))(params, x)
+    calls = {eqn.params["name"]: eqn for eqn in _pallas_calls(jaxpr.jaxpr)}
+    assert sorted(calls) == ["kda_bwd", "kda_fwd"]
+
+    def wide(variables, dtype):
+        return [v for v in variables if v.aval.shape == (b, t, width)
+                and v.aval.dtype == dtype]
+
+    fwd, bwd = calls["kda_fwd"], calls["kda_bwd"]
+    # q, k, v, f, gate, and q, k, v again for the rows before a chunk
+    assert len(wide(fwd.invars, jnp.bfloat16)) == 8
+    assert len(wide(fwd.outvars, jnp.bfloat16)) == 1           # o
+    assert len(wide(bwd.invars, jnp.bfloat16)) == 9            # and dO
+    assert len(wide(bwd.outvars, jnp.bfloat16)) == 5
+    for eqn in (fwd, bwd):
+        assert not wide(eqn.invars + eqn.outvars, jnp.float32)
 
 
 # -- MLA through the flash kernels against the masked softmax ----------------
@@ -565,18 +724,31 @@ def test_flash_compiles_for_the_chip_at_real_widths(one_chip, b, s, h, kh, d,
     assert text.count("tpu_custom_call") >= 3  # forward, dq, dk/dv
 
 
-def test_kda_compiles_for_the_chip_at_real_widths(one_chip):
-    """The cell's KDA layer, forward and backward: two Mosaic kernels, and
-    nothing of a chunk's pairwise products [.., 16, 16, 128] outside them."""
-    def shape(*dims):
-        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+@pytest.mark.parametrize("call", ["core", "mixer"])
+def test_kda_compiles_for_the_chip_at_real_widths(one_chip, call):
+    """The cell's KDA layer, forward and backward, the core alone (fp32) and
+    with the mixer's elementwise work around it (bf16 in and out): two Mosaic
+    kernels, and nothing of a chunk's pairwise products [.., 16, 16, 128]
+    outside them."""
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    def grads(*args):
-        return jax.grad(lambda *a: jnp.sum(kda_chunked(*a, interpret=False)),
-                        argnums=(0, 1, 2, 3, 4))(*args)
+    b, t, h, d = 2, 8192, 32, 128
+    if call == "core":
+        def loss(*a):
+            return jnp.sum(kda_chunked(*a, interpret=False))
 
-    wide = shape(2, 8192, 32, 128)
-    text = jax.jit(grads).lower(wide, wide, wide, wide,
-                                shape(2, 8192, 32)).compile().as_text()
+        args = (shape(b, t, h, d),) * 4 + (shape(b, t, h),)
+    else:
+        def loss(pre, beta, convs, dt_bias, a_log, o_scale):
+            return jnp.sum(kda_mixer(
+                *pre, beta, convs=convs, dt_bias=dt_bias, a_log=a_log,
+                o_scale=o_scale, l2_eps=L2_EPS, rms_eps=RMS_EPS,
+                interpret=False).astype(jnp.float32))
+
+        args = ((shape(b, t, h * d, dtype=jnp.bfloat16),) * 5, shape(b, t, h),
+                (shape(4, h * d),) * 3, shape(h * d), shape(h), shape(d))
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(len(args))))).lower(
+        *args).compile().as_text()
     assert text.count("tpu_custom_call") >= 2  # kda_fwd, kda_bwd
     assert not re.search(r"\[[0-9,]*16,16,128\]", text)
