@@ -297,6 +297,42 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int | None = None,
     return cache
 
 
+class RowState:
+    """What the serving engine asks of a model's decode state
+    (serve/paging.py `serving_state`), for the cache above: one K row and
+    one V row a token a layer, so one kind of block, and a request holds
+    `ceil(n / block_size)` of them for its n rows until it retires."""
+
+    kinds = ("rows",)
+    #: Nothing comes or goes while a request decodes: its whole worst case
+    #: is taken at admission.
+    grows = False
+    #: Names of the counters the engine keeps for this state: none.
+    counters = ()
+
+    def __init__(self, cfg, block_size: int, max_len: int):
+        self.cfg, self.bs = cfg, int(block_size)
+
+    def held(self, n: int) -> tuple[int]:
+        return (-(-max(int(n), 0) // self.bs),)
+
+    def peak(self, n: int) -> int:
+        return self.held(n)[0]
+
+    def slots(self, n_slots: int, max_len: int) -> dict:
+        """The flat engine's cache: `max_len` rows a slot."""
+        return init_cache(self.cfg, n_slots, max_len)
+
+    def pool(self, n_blocks: int, kv_quant: str = "none") -> dict:
+        """The paged pool, block 0 the reserved NULL block."""
+        return init_cache(self.cfg, n_blocks + 1, self.bs,
+                          kv_quant=kv_quant)
+
+    def fragment(self, length: int) -> dict:
+        """One request's rows as prefill builds them, contiguous."""
+        return init_cache(self.cfg, 1, length)
+
+
 def _update_cache(cache_k, cache_v, k, v, index):
     """Write new k/v [B,S,KH,D] into per-layer cache [B,T,KH,D] at per-row
     sequence offsets index [B] (rows advance independently under continuous

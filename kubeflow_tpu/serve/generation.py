@@ -36,6 +36,7 @@ plain chunked decode.
 
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 import time
@@ -50,7 +51,8 @@ from kubeflow_tpu.serve.kv_transfer import (HostKVTier, ShipmentError,
                                             pack_shipment,
                                             unpack_shipment)
 from kubeflow_tpu.serve.model import Model
-from kubeflow_tpu.serve.paging import BlockAllocator, blocks_for
+from kubeflow_tpu.serve.paging import (BlockAllocator, blocks_for,
+                                       serving_state)
 from kubeflow_tpu.serve.quant import (KV_QUANT_MODES, kv_dequantize_rows,
                                       kv_qdtype, kv_quantize_rows)
 from kubeflow_tpu.utils import devices, obs
@@ -219,8 +221,7 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
     `frag_from_pool_quant` dequantizes into the full-precision fragment
     (admission-side, outside any scan).
     """
-    from kubeflow_tpu.models.llama import init_cache
-
+    state = serving_state(cfg, kv_block_size, max_len)
     prefill_buckets = sorted(prefill_buckets)
     big = prefill_buckets[-1]
     frag_len = max_len + (big if offset_writes else 0)
@@ -252,7 +253,7 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
                 aid=None):
         """tokens [1, S_bucket] right-padded; returns (frag_cache,
         first sampled token [1], its logprob [1])."""
-        cache = _constrain_cache(init_cache(cfg, 1, frag_len))
+        cache = _constrain_cache(state.fragment(frag_len))
         kw = apply_kw(aid)
         if rolling:
             kw["positions"] = _chunk_positions(
@@ -370,15 +371,29 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
         bs = int(kv_block_size)
         mb = max_len // bs  # blocks covering one full-length request
 
+        # What the scan runs over. Rows: a contiguous copy gathered
+        # through the tables and scattered back. A state of several kinds
+        # of block (`tables` a dict, one table a kind): the pool itself
+        # with the tables beside it, which the model reads and writes in
+        # place: no copy of the state a dispatch.
+        if state.grows:
+            def view_of(pool, tables):
+                return {**pool, **tables}
+
+            def write_back(pool, view, tables):
+                return {name: view[name] for name in pool}
+        else:
+            view_of, write_back = gather_view, scatter_view
+
         def make_decode_paged(truncate: bool, bucket: int):
             def decode_chunk(params, pool, tables, last_tok, index,
                              temperature, top_k, top_p, key, aid=None):
                 """Flat `decode_chunk` over a gathered block view:
                 tables [B, bucket // bs] (pad entries 0 = NULL block)."""
                 view, toks, lps = decode_scan(
-                    truncate, bucket, params, gather_view(pool, tables),
+                    truncate, bucket, params, view_of(pool, tables),
                     last_tok, index, temperature, top_k, top_p, key, aid)
-                return scatter_view(pool, view, tables), toks.T, lps.T
+                return write_back(pool, view, tables), toks.T, lps.T
             return decode_chunk
 
         def insert_paged(pool, frag, table):
@@ -406,7 +421,7 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
             stored prefix come back as garbage; safe for the same reason
             stale fragment rows always were (each is overwritten before
             any query position can attend it)."""
-            empty = init_cache(cfg, 1, frag_len)
+            empty = state.fragment(frag_len)
 
             def leaf(f, p):
                 g = jnp.take(p, table, axis=1)  # [L, mb, bs, ...]
@@ -465,7 +480,7 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
             materialize — admission-side reconstruction for a prefix hit
             or continuation, outside any scan (each call is a dequant
             fallback; the engine counts them)."""
-            empty = init_cache(cfg, 1, frag_len)
+            empty = state.fragment(frag_len)
 
             def rowed(g):
                 return g.reshape(g.shape[0], 1, mb * bs, *g.shape[3:])
@@ -489,6 +504,9 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
             # Quantized pools differ at the admission boundary only.
             fns.update(insert_paged=insert_paged_quant,
                        frag_from_pool=frag_from_pool_quant)
+        if state.grows:
+            # The model's own fragment goes into its own kinds of block.
+            fns.update(insert_paged=state.insert)
     return fns
 
 
@@ -869,6 +887,35 @@ class GenerationEngine:
         self._paged = int(kv_block_size) > 0
         self._kv_bs = int(kv_block_size)
         self._kv_stash: deque = deque()  # admissions waiting for blocks
+        # What a request holds between steps is the model's to say
+        # (serve/paging.py `serving_state`): rows of K and V, one kind of
+        # block, or a state of several kinds whose blocks come and go
+        # while the request decodes (`grows`). One mechanism below; what
+        # the engine cannot yet do with the latter it refuses here.
+        if self._state.grows:
+            kinds = " and ".join(self._state.kinds)
+            for given, why in (
+                    (int(prefix_cache) > 0,
+                     "prefix_cache > 0: a stored prefix shares blocks by "
+                     "reference, and no table here maps a prefix of "
+                     f"{kinds} blocks"),
+                    (draft is not None,
+                     "a draft model: a rejected proposal rewinds rows, and "
+                     "a window pooled by a rejected row cannot be unpooled"),
+                    ((kv_quant or "none") != "none",
+                     "kv_quant: the scale planes follow rows, not "
+                     f"{kinds} blocks"),
+                    (role != "unified" or int(kv_host_tier_blocks) > 0,
+                     "disaggregated shipment and the host tier: the wire "
+                     "format carries one table of row blocks")):
+                if given:
+                    raise ValueError(
+                        f"{type(self.model).__name__} keeps {kinds} blocks; "
+                        f"the engine cannot serve that with {why}")
+            self._state.check(self.prefill_buckets)
+            # A row reads a bounded state, whatever its length: one
+            # decode shape.
+            self.decode_buckets = [self.max_len]
         # Disaggregated prefill/decode (ISSUE 13): KV blocks are the
         # wire format, so both split roles and the host-RAM spill tier
         # require the paged pool. role="unified" with no tier is the
@@ -1140,11 +1187,13 @@ class GenerationEngine:
                       "queue_wait_seconds": 0.0, "admitted": 0,
                       "ttft_seconds": 0.0, "first_tokens": 0,
                       "decode_context_tokens": 0}
+        # The state's own counters (what a step reads of each kind, what
+        # was given back mid-request): named and computed by the state.
+        self.stats.update(dict.fromkeys(self._state.counters, 0))
         # The pass number of the engine loop, on its `engine.*` spans.
         self._round = 0
         devices.compile_clock()  # counting before this engine's compiles
         self._compile()
-        from kubeflow_tpu.models.llama import init_cache
         with self._scope():
             cache_sh = None
             if self._cache_sharding is not None:
@@ -1169,13 +1218,12 @@ class GenerationEngine:
                 # axis rides the slot axis's (replicated) spec; heads
                 # still shard over `tensor` under TP.
                 self._cache = jax.jit(
-                    lambda: init_cache(cfg, self._kv_alloc.n_blocks + 1,
-                                       self._kv_bs,
-                                       kv_quant=self.kv_quant),
+                    lambda: self._state.pool(self._kv_alloc.n_blocks,
+                                             kv_quant=self.kv_quant),
                     out_shardings=cache_sh)()
             else:
                 self._cache = jax.jit(
-                    lambda: init_cache(cfg, self.n_slots, self.max_len),
+                    lambda: self._state.slots(self.n_slots, self.max_len),
                     out_shardings=cache_sh)()
             if self._spec is not None:
                 dcache_sh = (None if self._dcache_sharding is None else
@@ -1188,20 +1236,41 @@ class GenerationEngine:
                     # draft blocks are ordinary allocations — per-slot,
                     # never prefix-shared, freed with the slot.
                     self._dcache = jax.jit(
-                        lambda: init_cache(self._spec["cfg"],
-                                           self._kv_alloc.n_blocks + 1,
-                                           self._kv_bs),
+                        lambda: self._dstate.pool(self._kv_alloc.n_blocks),
                         out_shardings=dcache_sh)()
                 else:
                     self._dcache = jax.jit(
-                        lambda: init_cache(self._spec["cfg"], self.n_slots,
-                                           self.max_len),
+                        lambda: self._dstate.slots(self.n_slots,
+                                                   self.max_len),
                         out_shardings=dcache_sh)()
             self._warmup()
         self._slots = [None] * self.n_slots  # per-slot host state
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name="tpk-generate")
         self._thread.start()
+
+    @functools.cached_property
+    def _state(self):
+        """What the model keeps of a request between steps, as its
+        configuration declares it (serve/paging.py `serving_state`). Made
+        at first use and not in `__init__`: the host-only tests of the
+        reserve (tests/test_paged_kv.py) make an engine object without a
+        model, which then holds rows."""
+        return serving_state(getattr(self, "cfg", None), self._kv_bs,
+                             self.max_len)
+
+    @functools.cached_property
+    def _keys(self) -> tuple:
+        """Slot-state keys of a paged request's block tables, one a kind
+        of its state: `blocks`, then `<kind>_blocks`."""
+        return ("blocks",) + tuple(f"{kind}_blocks"
+                                   for kind in self._state.kinds[1:])
+
+    def _count(self, counts: dict) -> None:
+        """Add what the state counted to the engine's `stats`."""
+        with self._stats_lock:
+            for name, n in counts.items():
+                self.stats[name] += n
 
     # -- tensor parallelism --------------------------------------------------
 
@@ -1367,10 +1436,10 @@ class GenerationEngine:
             else:
                 self._dinsert = jax.jit(dfns["insert"], donate_argnums=(0,))
             self._dfrag_len = dfns["frag_len"]
-            from kubeflow_tpu.models.llama import init_cache
-
+            self._dstate = serving_state(self._spec["cfg"], self._kv_bs,
+                                         self.max_len)
             self._dfrag_init = jax.jit(
-                lambda: init_cache(self._spec["cfg"], 1, self._dfrag_len))
+                lambda: self._dstate.fragment(self._dfrag_len))
             spec_make = build_spec_decode(
                 self.model, self._spec["model"],
                 gamma=self._spec["gamma"], n_spec=self._spec["n_spec"],
@@ -1412,7 +1481,7 @@ class GenerationEngine:
             # garbage block, never in allocatable pool blocks.
             mb = self.max_len // self._kv_bs
             self._cache = self._insert(self._cache, frag,
-                                       jnp.zeros((mb,), jnp.int32))
+                                       self._scatter_tables({}))
             if self._prefix_cap:
                 frag = self._frag_from_pool(self._cache,
                                             jnp.zeros((mb,), jnp.int32))
@@ -1429,7 +1498,7 @@ class GenerationEngine:
             for (b, _), fn in self._decode.items():
                 self._cache, _, _ = fn(
                     self._params, self._cache,
-                    jnp.zeros((n, b // self._kv_bs), jnp.int32),
+                    self._block_tables([], b // self._kv_bs),
                     jnp.zeros((n,), jnp.int32),
                     jnp.zeros((n,), jnp.int32),
                     jnp.zeros((n,), jnp.float32),
@@ -1972,14 +2041,32 @@ class GenerationEngine:
         return min(self.max_len, prompt + chunks * self.chunk)
 
     def _need_blocks(self, req: dict) -> int:
-        """Target pool blocks a request reserves whole at admission: the
-        worst case of `_paged_need_tokens`, or the prompt alone in ship
-        mode (the decode replica reserves the decode budget at
-        submit_remote)."""
+        """The most target pool blocks a request ever holds, which
+        admission has to see free: what the worst case of
+        `_paged_need_tokens` holds (rows: reserved whole at admission) or
+        peaks at on its way there (a state that grows), or the prompt
+        alone in ship mode (the decode replica reserves the decode budget
+        at submit_remote)."""
         n = len(req["input_ids"])
         if req.get("mode") != "ship":
             n = self._paged_need_tokens(n, req["max_tokens"])
-        return blocks_for(n, self._kv_bs)
+        return self._state.peak(n)
+
+    def _admit_blocks(self, req: dict) -> tuple:
+        """Blocks of each kind a request takes at admission: all it will
+        ever hold, or, where the state grows, what its prompt holds (the
+        rest is taken as its rows are dispatched, `_grow`)."""
+        n = len(req["input_ids"])
+        if req.get("mode") != "ship" and not self._state.grows:
+            n = self._paged_need_tokens(n, req["max_tokens"])
+        return self._state.held(n)
+
+    def _kv_owed(self) -> int:
+        """Blocks that live requests may still take on their way to their
+        peaks: free blocks that admission must not give away."""
+        return sum(st["peak"] - sum(len(st[key]) for key in self._keys)
+                   for st in self._slots
+                   if st is not None and "peak" in st)
 
     def _refuse_oversized(self, req: dict) -> None:
         """Shed at submit (503) a request whose reserve even an empty
@@ -2027,7 +2114,7 @@ class GenerationEngine:
         precheck (which counts both) makes the failure unreachable in
         the normal flow; defense against future reordering."""
         fresh = self._kv_alloc.alloc(
-            max(0, self._need_blocks(req) - n_shared))
+            max(0, sum(self._admit_blocks(req)) - n_shared))
         if fresh is None:
             raise _NeedKVBlocks()
         # Draft blocks ride the same pool, per-slot and never
@@ -2154,7 +2241,7 @@ class GenerationEngine:
         shared = hit[0] // self._kv_bs if hit is not None else 0
         hit_key = ((aid, hit[0], hash(tuple(ids[:hit[0]])))
                    if hit is not None else None)
-        if self._kv_alloc.can_alloc(total - shared):
+        if self._kv_alloc.can_alloc(total - shared + self._kv_owed()):
             return True
         if not self._prefix_lru:
             return False
@@ -2196,12 +2283,10 @@ class GenerationEngine:
         (device stream order is dispatch order)."""
         if not self._paged:
             return
-        blocks = st.pop("blocks", None)
-        if blocks:
-            self._kv_alloc.decref(blocks)
-        dblocks = st.pop("dblocks", None)
-        if dblocks:
-            self._kv_alloc.decref(dblocks)
+        for key in self._keys + ("dblocks",):
+            blocks = st.pop(key, None)
+            if blocks:
+                self._kv_alloc.decref(blocks)
 
     @property
     def kv_blocks_free(self):
@@ -2219,6 +2304,13 @@ class GenerationEngine:
                 "blocks": self._kv_alloc.n_blocks,
                 "blocks_free": self._kv_alloc.free_blocks,
                 "blocks_used": self._kv_alloc.used_blocks}
+        if self._state.grows:
+            # By kind, in the live requests' tables (a list's length is
+            # one read; the worker thread owns the lists).
+            for kind, _, key in self._kind_tables():
+                info[f"{kind}_blocks_used"] = sum(
+                    len(st.get(key, ())) for st in list(self._slots)
+                    if st is not None)
         if self._host_tier is not None:
             info["host_tier"] = self._host_tier.stats_snapshot()
         return info
@@ -2284,6 +2376,19 @@ class GenerationEngine:
                     self.stats["prefix_misses"] += 1
         self._kv_alloc.incref(shared)
         table = shared + fresh
+        # The request's tables by slot-state key: one of rows, or one a
+        # kind cut from `fresh` in the state's order.
+        owned = {"blocks": table}
+        if self._state.grows:
+            # `peak`: what admission saw free for it; `rows`: the rows
+            # that bought. A chunk dispatched past them (its budget
+            # already covered, the batch still going) takes nothing more.
+            owned = {"peak": self._need_blocks(req),
+                     "rows": self._paged_need_tokens(len(ids),
+                                                     req["max_tokens"])}
+            rest = table
+            for key, n in zip(self._keys, self._admit_blocks(req)):
+                owned[key], rest = rest[:n], rest[n:]
         boundaries: list[int] = []
         try:
             if gather_tbl is not None:
@@ -2308,10 +2413,8 @@ class GenerationEngine:
             # rows are already resident and immutable), owned blocks
             # receive their fragment rows — including the CoW fork and
             # the pad/garbage tail that decode will overwrite in place.
-            st_tbl = np.zeros((mb,), np.int32)
-            st_tbl[len(shared):len(table)] = fresh
-            self._cache = self._insert(self._cache, frag,
-                                       jnp.asarray(st_tbl))
+            self._cache = self._insert(
+                self._cache, frag, self._scatter_tables(owned, len(shared)))
             if dtable is not None:
                 # The draft must hold the same prompt history (flat
                 # admission's rule): chunked replay over the draft's own
@@ -2335,7 +2438,7 @@ class GenerationEngine:
             self._finish_ship(req, table, tok0, lp0, dtable)
             return
         self._seat(slot, req, tok0, lp0, draft_ok=dtable is not None,
-                   blocks=table, dblocks=dtable)
+                   dblocks=dtable, **owned)
 
     def _finish_ship(self, req: dict, table: list[int], tok0,
                      lp0, dtable: list[int] | None = None) -> None:
@@ -3131,14 +3234,85 @@ class GenerationEngine:
     def _block_tables(self, rows: list[int], nb: int,
                       which: str = "blocks"):
         """Per-row block tables [n_slots, nb], padded with the NULL
-        block. Built from host lists fixed at admission — no device
-        sync, so chained pipelined dispatch works exactly as flat."""
-        tables = np.zeros((self.n_slots, nb), np.int32)
-        for i in rows:
-            blk = self._slots[i][which]
-            k = min(len(blk), nb)
-            tables[i, :k] = blk[:k]
-        return jnp.asarray(tables)
+        block. Built from host lists — no device sync, so chained
+        pipelined dispatch works exactly as flat. A state of several
+        kinds of block gets one table a kind, at the state's own widths."""
+        def table(nb, which):
+            tables = np.zeros((self.n_slots, nb), np.int32)
+            for i in rows:
+                blk = self._slots[i][which]
+                k = min(len(blk), nb)
+                tables[i, :k] = blk[:k]
+            return jnp.asarray(tables)
+
+        if self._state.grows:
+            return {kind: table(width, key)
+                    for kind, width, key in self._kind_tables()}
+        return table(nb, which)
+
+    def _kind_tables(self):
+        """(kind, its table's compiled width, its slot-state key) for each
+        kind of block of a state that has several."""
+        return zip(self._state.kinds, self._state.widths, self._keys)
+
+    def _scatter_tables(self, owned: dict, n_shared: int = 0):
+        """The table(s) an admission fragment is scattered through: the
+        blocks the request owns, the `n_shared` it maps by reference
+        masked to the NULL block, padded with it to the program's width."""
+        def padded(width, blocks, skip=0):
+            tbl = np.zeros((width,), np.int32)
+            tbl[skip:len(blocks)] = blocks[skip:]
+            return jnp.asarray(tbl)
+
+        if self._state.grows:
+            return {kind: padded(width, owned.get(key, ()))
+                    for kind, width, key in self._kind_tables()}
+        return padded(self.max_len // self._kv_bs,
+                      owned.get("blocks", ()), n_shared)
+
+    def _grow(self, active: list[int]) -> None:
+        """Take the blocks the rows of the coming chunk need, kind by
+        kind, up to the rows admission reserved for (`st["rows"]`: steps
+        past them are dead and write through NULL-block pads, as a row
+        cache's do). Admission kept the blocks free (`_kv_owed`), so the
+        pool cannot run out here."""
+        for i in active:
+            st = self._slots[i]
+            need = self._state.held(min(st["disp"] + self.chunk,
+                                        st["rows"]))
+            for key, n in zip(self._keys, need):
+                if n > len(st[key]):
+                    got = self._kv_alloc.alloc(n - len(st[key]))
+                    if got is None:
+                        raise RuntimeError(
+                            "the paged pool ran out under a live request: "
+                            "admission gave away blocks it owed")
+                    st[key].extend(got)
+
+    def _release(self, rec: dict) -> None:
+        """At the fetch boundary: give back what no row of a live
+        request, written or in flight, still reads: of each kind, the
+        blocks past the most that any row count from the fetched one to
+        the dispatched one holds (`held`)."""
+        for i, st in rec["parts"].items():
+            if self._slots[i] is not st:
+                continue  # retired: `_free_slot_blocks` gave all back
+            disp = min(st["disp"], st["rows"])
+            if all(len(st[key]) <= n for key, n in zip(
+                    self._keys, self._state.held(disp))):
+                continue
+            keep = [max(col) for col in zip(*(
+                self._state.held(n) for n in range(st["idx"], disp + 1)))]
+            gone = [st[key][n:] for key, n in zip(self._keys, keep)]
+            if not any(gone):
+                continue
+            for key, n in zip(self._keys, keep):
+                del st[key][n:]
+            with obs.span("engine.release", round=self._round,
+                          blocks=sum(map(len, gone))):
+                for blocks in gone:
+                    self._kv_alloc.decref(blocks)
+            self._count(self._state.released(tuple(map(len, gone))))
 
     # tpk-hot: spec-dispatch
     def _dispatch_spec_chunk(self, parts: list[int],
@@ -3346,6 +3520,8 @@ class GenerationEngine:
                 None if carry is None else carry["toks"][:, -1])
             tables = ()
             if self._paged:
+                if self._state.grows:
+                    self._grow(active)
                 tables = (self._block_tables(active,
                                              bucket // self._kv_bs),)
             self._cache, toks, lps = self._decode[(bucket, trunc)](
@@ -3360,6 +3536,10 @@ class GenerationEngine:
             self.stats["decode_dispatches"] += 1
             self.stats["decode_context_tokens"] += sum(
                 self._slots[i]["disp"] for i in active)
+            if self._state.grows:
+                for name, n in self._state.read(
+                        [self._slots[i]["disp"] for i in active]).items():
+                    self.stats[name] += n
         parts: dict[int, dict] = {}
         for i in active:
             st = self._slots[i]
@@ -3448,6 +3628,8 @@ class GenerationEngine:
                 st["draft_ok"] = False
                 self._emit(i, st, [int(t) for t in toks[i]],
                            [float(v) for v in lps[i]])
+            if self._state.grows:
+                self._release(rec)
 
     # tpk-hot: engine-loop
     def _loop(self) -> None:

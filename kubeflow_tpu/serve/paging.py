@@ -41,6 +41,13 @@ Allocation, refcounts, CoW forks, and the NULL block are identical —
 a shared quantized prefix shares its scales by construction, and a
 tail fork copies them through the same admission-fragment scatter.
 Blocks stay opaque above the engine; this module never sees a dtype.
+
+What a block holds is the model's to say (`serving_state`): a row of K and
+V per token (models/llama.py `RowState`: one kind of block, a request's
+whole worst case taken at admission, as everything above describes), or
+two kinds in one pool, taken as the request's rows are dispatched and given
+back while it still decodes (models/evabyte.py `EvaState`). The allocator
+is the same for both: a table entry names a block of either kind.
 """
 
 from __future__ import annotations
@@ -51,6 +58,25 @@ def blocks_for(tokens: int, block_size: int) -> int:
     if tokens <= 0:
         return 0
     return -(-int(tokens) // int(block_size))
+
+
+def serving_state(cfg, block_size: int, max_len: int):
+    """What the model keeps of a request between steps, as the engine asks
+    it: the configuration's own `serving_state`, else the row-per-token
+    cache of models/llama.py (whose contract GPT-2 and the expert models
+    share). It answers `kinds`, `held(n)` (blocks of each kind that n
+    written rows hold), `peak(n)` (the most a request holds on its way to n
+    rows), names its `counters` and makes the device arrays (`pool`,
+    `fragment`). A state that `grows` (blocks taken as rows are dispatched
+    and given back mid-request) also gives its tables' compiled `widths`,
+    scatters its own fragment (`insert`), refuses what it cannot take
+    (`check`) and counts (`read` at a dispatch, `released` at a fetch)."""
+    make = getattr(cfg, "serving_state", None)
+    if make is not None:
+        return make(block_size, max_len)
+    from kubeflow_tpu.models.llama import RowState
+
+    return RowState(cfg, block_size, max_len)
 
 
 class BlockAllocator:

@@ -138,6 +138,24 @@ def _ensure_builtin() -> None:
         return _kimi_linear(
             dataclasses.replace(kimi_linear.kimi_linear_48b(), **kw))
 
+    from kubeflow_tpu.models import evabyte
+
+    def _evabyte(cfg):
+        return evabyte.EvaByte(cfg), {
+            "task": "lm", "example_shape": (1, 16), "example_dtype": "int32",
+            "num_params": cfg.num_params, "vocab_size": cfg.vocab_size,
+            "config": cfg}
+
+    @register_model("evabyte_tiny")
+    def _evabyte_tiny(**kw):
+        import dataclasses
+        return _evabyte(dataclasses.replace(evabyte.evabyte_tiny(), **kw))
+
+    @register_model("evabyte_6_5b")
+    def _evabyte_6_5b(**kw):
+        import dataclasses
+        return _evabyte(dataclasses.replace(evabyte.evabyte_6_5b(), **kw))
+
     @register_model("bert_tiny")
     def _bert_tiny(**kw):
         import dataclasses

@@ -752,3 +752,36 @@ def test_kda_compiles_for_the_chip_at_real_widths(one_chip, call):
         *args).compile().as_text()
     assert text.count("tpu_custom_call") >= 2  # kda_fwd, kda_bwd
     assert not re.search(r"\[[0-9,]*16,16,128\]", text)
+
+
+@pytest.mark.parametrize("form", ["prefill", "decode"])
+def test_eva_cores_compile_for_the_chip_at_real_widths(one_chip, form):
+    """EvaByte's two attention cores (ops/eva.py) at the served cell's
+    widths: a window piece of 2,048 through the flash kernel joined with 768
+    summary rows, and the decode kernel's 16 rows reading 128 + 48 blocks
+    each through their tables out of the 4.43 GB pool. Here, not in tests/test_evabyte.py:
+    the described chip belongs to one test file (one worker loads the TPU's
+    library)."""
+    from kubeflow_tpu.ops import eva
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    h, d = 32, 128
+    if form == "prefill":
+        text = jax.jit(functools.partial(
+            eva.attend_piece, interpret=False)).lower(
+            *(shape(1, 2048, h, d),) * 3, *(shape(1, 768, h, d),) * 2,
+            shape(1, dtype=jnp.int32)).compile().as_text()
+        assert text.count("tpu_custom_call") >= 1  # the flash forward
+    else:
+        pool = shape(6 * 2817, 16, h, d)
+        text = jax.jit(functools.partial(
+            eva.attend_step, exact_blocks=128, interpret=False)).lower(
+            shape(16, h, d), pool, pool, shape(16, 176, dtype=jnp.int32),
+            shape(16, dtype=jnp.int32),
+            shape(16, dtype=jnp.int32)).compile().as_text()
+        # One kernel reads the rows through the tables; nothing of the
+        # state ([16, 2816, 32, 128] of K and of V) is copied outside it.
+        assert text.count("tpu_custom_call") == 1
+        assert not re.search(r"\[16,(2816|176),", text)
