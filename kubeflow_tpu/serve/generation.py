@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from kubeflow_tpu.serve import weights
 from kubeflow_tpu.serve.kv_transfer import (HostKVTier, ShipmentError,
                                             pack_shipment,
                                             unpack_shipment)
@@ -771,6 +772,14 @@ class GenerationEngine:
     every prefill/decode dispatch runs SPMD with XLA-inserted collectives.
     An 8B bf16 model does not fit one chip; TP-8 is how the flagship
     serves. The public API is unchanged: submit() still takes one request.
+
+    **The weights** are held the one way serve/weights.py says: a leaf
+    the model's forward only rounds to `cfg.dtype` is rounded once, here,
+    the rest stay as they came; `eng._params` keeps the tree's structure.
+    `donate_params=True` is a caller's word that it gives its tree (and a
+    draft's) up, as `jax.jit`'s donation is: each device leaf is deleted
+    as its rounded twin exists, so loading never holds a tree twice. The
+    caller's tree is dead afterwards, whether or not construction ended.
     """
 
     def __init__(self, model, params, cfg, *, slots: int = 4,
@@ -782,7 +791,7 @@ class GenerationEngine:
                  adapters: dict | None = None, pipeline_depth: int = 2,
                  kv_block_size: int = 0, kv_blocks: int = 0,
                  role: str = "unified", kv_host_tier_blocks: int = 0,
-                 kv_quant: str = "none"):
+                 kv_quant: str = "none", donate_params: bool = False):
         self.model, self.cfg = model, cfg
         self.max_len, self.chunk, self.n_slots = int(max_len), int(chunk), int(slots)
         msl = int(getattr(cfg, "max_seq_len", 0) or 0)
@@ -1106,21 +1115,21 @@ class GenerationEngine:
         self._rules = tuple(rules)
         self._cache_sharding = None
         self._dcache_sharding = None
-        if mesh is not None:
-            self._params, self._cache_sharding = self._shard_params(params)
-        else:
-            self._params = jax.device_put(params)
+        # The engine's own tree (serve/weights.py): what the forward only
+        # rounds to cfg.dtype is rounded once, here, and every executable
+        # reads the stored leaf in place.
+        self._params, self._cache_sharding = self._hold(
+            params, self.model, self.cfg, self._state, donate_params)
         if self._spec is not None:
             # Spec-decode x TP: the draft shards over the SAME mesh by
             # the same logical rules (its KV heads must divide tensor
             # like the target's) — one SPMD program runs draft proposals
             # and target verify together.
-            if mesh is not None:
-                self._dparams, self._dcache_sharding = self._shard_params(
-                    self._dparams_src, model=self._spec["model"],
-                    cfg=self._spec["cfg"], role="draft")
-            else:
-                self._dparams = jax.device_put(self._dparams_src)
+            self._dstate = serving_state(self._spec["cfg"], self._kv_bs,
+                                         self.max_len)
+            self._dparams, self._dcache_sharding = self._hold(
+                self._dparams_src, self._spec["model"], self._spec["cfg"],
+                self._dstate, donate_params, role="draft")
             del self._dparams_src
         if int(pipeline_depth) < 1:
             raise ValueError(
@@ -1190,6 +1199,10 @@ class GenerationEngine:
         # The state's own counters (what a step reads of each kind, what
         # was given back mid-request): named and computed by the state.
         self.stats.update(dict.fromkeys(self._state.counters, 0))
+        # Two gauges, not counters: what the engine holds of weights, and
+        # how much of that is still fp32 (serve/weights.py).
+        self.stats.update(weights.weight_bytes(
+            self._params, self._dparams if self._spec else None))
         # The pass number of the engine loop, on its `engine.*` spans.
         self._round = 0
         devices.compile_clock()  # counting before this engine's compiles
@@ -1272,24 +1285,44 @@ class GenerationEngine:
             for name, n in counts.items():
                 self.stats[name] += n
 
+    def _hold(self, params, model, cfg, state, donate: bool,
+              role: str = "target"):
+        """Take a weight tree (the target's or the draft's) as the engine
+        keeps it: each leaf the model's forward only rounds to `cfg.dtype`
+        stored so (the rule: serve/weights.py), every leaf on the device
+        or on its shard of the mesh. Returns (tree, cache_sharding), the
+        latter None off a mesh."""
+        dtype = getattr(cfg, "dtype", None)
+        narrow = weights.stored_narrow(
+            model, params, state, dtype, max_len=self.max_len,
+            piece=self.prefill_buckets[-1])
+        shardings = cache_sharding = None
+        if self._mesh is not None:
+            import flax.linen as nn
+
+            shardings, cache_sharding = self._shardings(model, cfg, role)
+            shardings = jax.tree.leaves(shardings)
+            # Callers hand over boxed (fresh init) or plain
+            # (orbax-restored) trees; shardings are derived unboxed.
+            params = nn.meta.unbox(params)
+        return weights.hold(params, narrow, dtype, self._put, shardings,
+                            donate=donate), cache_sharding
+
     # -- tensor parallelism --------------------------------------------------
 
-    def _shard_params(self, params, model=None, cfg=None,
-                      role: str = "target"):
-        """Lay a weight tree out over the mesh by the model's logical
-        axis annotations (the same rules engine training uses) and derive
-        the matching KV-cache sharding: heads over `tensor`, everything
-        else replicated. Each device ends up holding its head group / mlp
-        shard; XLA inserts the collectives. Returns (sharded_params,
-        cache_sharding) — also used for the DRAFT model under
-        spec-decode x TP (role only flavors the error message)."""
+    def _shardings(self, model, cfg, role: str):
+        """How a weight tree lies over the mesh, by the model's logical
+        axis annotations (the same rules engine training uses), and the
+        matching KV-cache sharding: heads over `tensor`, everything else
+        replicated. Each device ends up holding its head group / mlp
+        shard; XLA inserts the collectives. Returns (param shardings,
+        cache_sharding) — also for the DRAFT model under spec-decode x TP
+        (role only flavors the error message)."""
         import flax.linen as nn
 
         from kubeflow_tpu.parallel.sharding import logical_to_spec
         from jax.sharding import NamedSharding
 
-        model = model if model is not None else self.model
-        cfg = cfg if cfg is not None else self.cfg
         mesh = self._mesh
         tp = mesh.shape.get("tensor", 1)
         if cfg.num_kv_heads % tp:
@@ -1308,32 +1341,30 @@ class GenerationEngine:
         cache_sharding = NamedSharding(
             mesh, logical_to_spec(("layers", None, None, "heads", "kv"),
                                   self._rules))
-        # Callers hand over boxed (fresh init) or plain (orbax-restored)
-        # trees; shardings are derived unboxed, so normalize first.
-        from jax.sharding import PartitionSpec
+        return shardings, cache_sharding
 
+    def _put(self, leaf, sh):
+        """One leaf onto the default device (`sh` None), or onto its
+        shard of the mesh."""
         from kubeflow_tpu.serve.quant import Int8Leaf
 
-        def put(leaf, sh):
-            if isinstance(leaf, Int8Leaf):
-                # int8 x TP: the int8 payload shards exactly like the
-                # weight it replaces; the fp32 per-output-channel scales
-                # keep the weight's spec on their >1 dims (the size-1
-                # contraction dims cannot shard, and the dequantize
-                # broadcast needs the scale co-resident with its shard).
-                spec = list(sh.spec) + [None] * (leaf.q.ndim - len(sh.spec))
-                sspec = [ax if d > 1 else None
-                         for ax, d in zip(spec, leaf.scale.shape)]
-                return Int8Leaf(
-                    jax.device_put(leaf.q, sh),
-                    jax.device_put(
-                        leaf.scale,
-                        NamedSharding(mesh, PartitionSpec(*sspec))))
-            return jax.device_put(leaf, sh)
+        if sh is not None and isinstance(leaf, Int8Leaf):
+            from jax.sharding import NamedSharding, PartitionSpec
 
-        return (jax.tree.map(put, nn.meta.unbox(params), shardings,
-                             is_leaf=lambda x: isinstance(x, Int8Leaf)),
-                cache_sharding)
+            # int8 x TP: the int8 payload shards exactly like the
+            # weight it replaces; the fp32 per-output-channel scales
+            # keep the weight's spec on their >1 dims (the size-1
+            # contraction dims cannot shard, and the dequantize
+            # broadcast needs the scale co-resident with its shard).
+            spec = list(sh.spec) + [None] * (leaf.q.ndim - len(sh.spec))
+            sspec = [ax if d > 1 else None
+                     for ax, d in zip(spec, leaf.scale.shape)]
+            return Int8Leaf(
+                jax.device_put(leaf.q, sh),
+                jax.device_put(
+                    leaf.scale,
+                    NamedSharding(self._mesh, PartitionSpec(*sspec))))
+        return jax.device_put(leaf, sh)
 
     def _scope(self):
         """Mesh + logical-rules context for tracing/compiling — a no-op
@@ -1436,8 +1467,6 @@ class GenerationEngine:
             else:
                 self._dinsert = jax.jit(dfns["insert"], donate_argnums=(0,))
             self._dfrag_len = dfns["frag_len"]
-            self._dstate = serving_state(self._spec["cfg"], self._kv_bs,
-                                         self.max_len)
             self._dfrag_init = jax.jit(
                 lambda: self._dstate.fragment(self._dfrag_len))
             spec_make = build_spec_decode(
@@ -3756,9 +3785,14 @@ class GenerativeJAXModel(Model):
     parity (v1/v2 infer on a generative model)."""
 
     def __init__(self, name: str, model, params, cfg, *,
-                 generation: dict | None = None):
+                 generation: dict | None = None,
+                 donate_params: bool = False):
         super().__init__(name)
         self._model, self._params, self.cfg = model, params, cfg
+        # A runtime that made `params` for this wrapper alone says so, and
+        # load() hands the tree over for good (GenerationEngine's
+        # `donate_params`); a tree that others still read is left alive.
+        self._donate_params = bool(donate_params)
         self._gen_cfg = dict(generation or {})
         self.engine: GenerationEngine | None = None
         self.eos_id = self._gen_cfg.pop("eos_id", None)
@@ -3819,8 +3853,13 @@ class GenerativeJAXModel(Model):
             if gamma is not None:
                 draft["gamma"] = int(gamma)
             kwargs["draft"] = draft
+        # `predict()` reads the engine's tree from here on: the forward
+        # rounds the same leaves, so its logits are the same, and this
+        # wrapper keeps no second tree alive.
         self.engine = GenerationEngine(
-            self._model, self._params, self.cfg, **kwargs)
+            self._model, self._params, self.cfg,
+            donate_params=self._donate_params, **kwargs)
+        self._params = self.engine._params
         self.load_time_s = time.monotonic() - t0
         self.ready = True
         return True

@@ -133,7 +133,8 @@ def _jax_registry_runtime(model_dir: str, spec: dict) -> Model:
 
         return GenerativeJAXModel(
             spec.get("name") or spec["model"], module, params,
-            info.get("config"), generation=dict(spec["generative"]))
+            info.get("config"), generation=dict(spec["generative"]),
+            donate_params=True)  # made above, held by nobody else
 
     def apply_fn(params, x):
         out = module.apply({"params": params}, x)
@@ -257,7 +258,7 @@ def _huggingface_runtime(model_dir: str, spec: dict) -> Model:
                 if tok.eos_token_id is not None:
                     gen.setdefault("eos_id", int(tok.eos_token_id))
         return GenerativeJAXModel(name, module, params, cfg,
-                                  generation=gen)
+                                  generation=gen, donate_params=True)
 
     def apply_fn(params, tokens):
         return module.apply({"params": params}, tokens)

@@ -183,14 +183,17 @@ def check_generative_against_engine() -> None:
     (plus the wrapper-level keys GenerativeJAXModel pops) — a new engine
     knob without a schema row would be REJECTED by C++ admission on
     every spec that sets it. `rules` is deliberately schema-less: it
-    takes in-process sharding-rule objects, never JSON."""
+    takes in-process sharding-rule objects, never JSON. So is
+    `donate_params`: a caller's word about who owns a Python tree, which
+    no spec can give (a bundle that names it is refused)."""
     import inspect
 
     from kubeflow_tpu.serve.generation import GenerationEngine
 
     sig = inspect.signature(GenerationEngine.__init__)
     knobs = {n for n in sig.parameters
-             if n not in ("self", "model", "params", "cfg", "rules")}
+             if n not in ("self", "model", "params", "cfg", "rules",
+                          "donate_params")}
     missing = knobs - set(GENERATIVE_KNOBS)
     if missing:
         raise AssertionError(
