@@ -311,7 +311,7 @@ class KimiLayer(nn.Module):
         h = RMSNorm(cfg.rms_eps, cfg.dtype, name="post_attn_norm")(x)
         zero = jnp.zeros((), jnp.float32)
         if cfg.is_moe(self.layer):
-            y, share, load = HeldExpertsBlock(
+            y, share, load, _ = HeldExpertsBlock(
                 hidden_size=cfg.hidden_size,
                 expert_width=cfg.moe_intermediate_size,
                 num_experts=cfg.num_experts,

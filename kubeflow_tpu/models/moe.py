@@ -337,7 +337,8 @@ class HeldExpertsBlock(nn.Module):
     experts would add is left out (models/kimi_linear.py says why).
 
     Sows nothing; returns (y, pairs routed here / all pairs, busiest held
-    expert / mean held expert) for the trunk to report."""
+    expert / mean held expert, held experts that got a pair) for the trunk
+    to report."""
 
     hidden_size: int
     expert_width: int
@@ -348,6 +349,10 @@ class HeldExpertsBlock(nn.Module):
     shared_width: int = 0  # the always-on dense SwiGLU beside them; 0: none
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    #: The score-correction bias at a fresh start. Zeros is where balancing
+    #: starts from in training; a model that is served from a seed gives it
+    #: a spread, so that a check against a reference is not blind to it.
+    bias_init: Any = nn.initializers.zeros_init()
 
     @nn.compact
     def __call__(self, x: jax.Array):
@@ -362,7 +367,7 @@ class HeldExpertsBlock(nn.Module):
             (hidden, self.num_experts), jnp.float32)
         bias = self.param(
             "e_score_correction_bias", nn.with_logical_partitioning(
-                nn.initializers.zeros_init(), (None,)),
+                self.bias_init, (None,)),
             (self.num_experts,), jnp.float32)
 
         def expert_w(name, shape, axes):
@@ -413,10 +418,11 @@ class HeldExpertsBlock(nn.Module):
                     hidden_size=hidden, intermediate_size=self.shared_width,
                     dtype=self.dtype, param_dtype=self.param_dtype),
                     name="shared_expert")(x)
+        touched = jnp.sum(load > 0).astype(jnp.int32)
         load = load.astype(jnp.float32)
         return (y.astype(self.dtype),
                 here.astype(jnp.float32) / (b * s * self.experts_per_token),
-                jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9))
+                jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9), touched)
 
 
 def MoELlama(cfg: MoEConfig, **kwargs: Any) -> Llama:

@@ -50,12 +50,14 @@ import numpy as np
 from kubeflow_tpu.serve import weights
 from kubeflow_tpu.serve.kv_transfer import (HostKVTier, ShipmentError,
                                             pack_shipment,
+                                            require_row_blocks,
                                             unpack_shipment)
 from kubeflow_tpu.serve.model import Model
 from kubeflow_tpu.serve.paging import (BlockAllocator, blocks_for,
-                                       serving_state)
+                                       require_rows, serving_state)
 from kubeflow_tpu.serve.quant import (KV_QUANT_MODES, kv_dequantize_rows,
-                                      kv_qdtype, kv_quantize_rows)
+                                      kv_qdtype, kv_quantize_rows,
+                                      require_kv_planes)
 from kubeflow_tpu.utils import devices, obs
 from kubeflow_tpu.utils.resilience import (Deadline, DeadlineExceeded,
                                            metrics as res_metrics)
@@ -316,33 +318,39 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
         all-greedy/plain-temperature traffic (the defaults) must not pay
         O(V log V) per token. Rolling mode passes the index through RAW
         — the model wraps it modularly and needs the absolute value for
-        positions. Returns (view, tokens [K, B], logprobs [K, B])."""
+        positions. Returns (view, tokens [K, B], logprobs [K, B], what the
+        model counted on the device at the chunk's first step: the scalars
+        it sows into its `counters` collection by name, {} for a model that
+        sows none)."""
         def step(carry, _):
             view, tok, idx, key = carry
             key, sub = jax.random.split(key)
-            logits, view = model.apply(
+            (logits, view), sown = model.apply(
                 {"params": params}, tok[:, None], cache=view,
                 cache_index=(idx if rolling
                              else jnp.minimum(idx, bucket - 1)),
-                **apply_kw(aid))
+                mutable=["counters"], **apply_kw(aid))
             if truncate:
                 nxt = sample_tokens(logits[:, 0], temperature, sub,
                                     top_k, top_p)
             else:
                 nxt = sample_tokens(logits[:, 0], temperature, sub)
             lp = _chosen_logprob(logits[:, 0], nxt)
-            return (view, nxt, idx + 1, key), (nxt, lp)
+            counts = {name: sum(vals) for name, vals
+                      in sown.get("counters", {}).items()}
+            return (view, nxt, idx + 1, key), (nxt, lp, counts)
 
-        (view, _, _, _), (toks, lps) = jax.lax.scan(
+        (view, _, _, _), (toks, lps, counts) = jax.lax.scan(
             step, (view, last_tok, index, key), None, length=chunk)
-        return view, toks, lps
+        return view, toks, lps, jax.tree.map(lambda c: c[0], counts)
 
     def make_decode(truncate: bool, bucket: int):
         def decode_chunk(params, cache, last_tok, index, temperature,
                          top_k, top_p, key, aid=None):
             """K decode steps under one dispatch; on-device sampling.
             last_tok/index/temperature [B]; returns (cache,
-            tokens [B, K], logprobs [B, K]). Attention runs over the
+            tokens [B, K], logprobs [B, K], the model's device counts).
+            Attention runs over the
             first `bucket` cache rows only (the loop picks the smallest
             bucket covering every active sequence), then the slice is
             written back. A rolling cache is `window` rows, its one
@@ -350,7 +358,7 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
             sliced = (cache if bucket == cache_len else jax.tree.map(
                 lambda c: jax.lax.slice_in_dim(c, 0, bucket, axis=2),
                 cache))
-            sliced, toks, lps = decode_scan(
+            sliced, toks, lps, counts = decode_scan(
                 truncate, bucket, params, sliced, last_tok, index,
                 temperature, top_k, top_p, key, aid)
             if bucket != cache_len:
@@ -359,7 +367,7 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
                         c, s, (0,) * c.ndim), cache, sliced)
             else:
                 cache = sliced
-            return cache, toks.T, lps.T
+            return cache, toks.T, lps.T, counts
         return decode_chunk
 
     fns = {"prefill": prefill, "extend": extend, "extend_mid": extend_mid,
@@ -391,10 +399,10 @@ def build_engine_fns(model, cfg, *, max_len: int, chunk: int,
                              temperature, top_k, top_p, key, aid=None):
                 """Flat `decode_chunk` over a gathered block view:
                 tables [B, bucket // bs] (pad entries 0 = NULL block)."""
-                view, toks, lps = decode_scan(
+                view, toks, lps, counts = decode_scan(
                     truncate, bucket, params, view_of(pool, tables),
                     last_tok, index, temperature, top_k, top_p, key, aid)
-                return write_back(pool, view, tables), toks.T, lps.T
+                return write_back(pool, view, tables), toks.T, lps.T, counts
             return decode_chunk
 
         def insert_paged(pool, frag, table):
@@ -901,26 +909,26 @@ class GenerationEngine:
         # block, or a state of several kinds whose blocks come and go
         # while the request decodes (`grows`). One mechanism below; what
         # the engine cannot yet do with the latter it refuses here.
+        # Three parts of the engine are written against rows of per-head
+        # K and V (models/llama.py `RowState`) and say so themselves.
+        if (kv_quant or "none") != "none":
+            require_kv_planes(self._state)
+        if role != "unified" or int(kv_host_tier_blocks) > 0:
+            require_row_blocks(self._state)
+        if draft is not None:
+            require_rows(
+                self._state,
+                "a draft model: a rejected proposal rewinds rows of K and "
+                "V, and the draft's cache is made of them")
         if self._state.grows:
-            kinds = " and ".join(self._state.kinds)
-            for given, why in (
-                    (int(prefix_cache) > 0,
-                     "prefix_cache > 0: a stored prefix shares blocks by "
-                     "reference, and no table here maps a prefix of "
-                     f"{kinds} blocks"),
-                    (draft is not None,
-                     "a draft model: a rejected proposal rewinds rows, and "
-                     "a window pooled by a rejected row cannot be unpooled"),
-                    ((kv_quant or "none") != "none",
-                     "kv_quant: the scale planes follow rows, not "
-                     f"{kinds} blocks"),
-                    (role != "unified" or int(kv_host_tier_blocks) > 0,
-                     "disaggregated shipment and the host tier: the wire "
-                     "format carries one table of row blocks")):
-                if given:
-                    raise ValueError(
-                        f"{type(self.model).__name__} keeps {kinds} blocks; "
-                        f"the engine cannot serve that with {why}")
+            # (A row state never grows, so this refuses exactly the states
+            # whose tables cannot map a stored prefix.)
+            if int(prefix_cache) > 0:
+                require_rows(
+                    self._state,
+                    "prefix_cache > 0: a stored prefix shares blocks by "
+                    "reference, and no table here maps a prefix of such "
+                    "blocks")
             self._state.check(self.prefill_buckets)
             # A row reads a bounded state, whatever its length: one
             # decode shape.
@@ -1525,7 +1533,7 @@ class GenerationEngine:
                 self._cache = self._import_blocks(self._cache, gathered,
                                                   gt)
             for (b, _), fn in self._decode.items():
-                self._cache, _, _ = fn(
+                self._cache, *_ = fn(
                     self._params, self._cache,
                     self._block_tables([], b // self._kv_bs),
                     jnp.zeros((n,), jnp.int32),
@@ -1537,7 +1545,7 @@ class GenerationEngine:
         else:
             self._cache = self._insert(self._cache, frag, jnp.int32(0))
             for fn in self._decode.values():
-                self._cache, _, _ = fn(
+                self._cache, *_ = fn(
                     self._params, self._cache, jnp.zeros((n,), jnp.int32),
                     jnp.zeros((n,), jnp.int32),
                     jnp.zeros((n,), jnp.float32),
@@ -3553,13 +3561,13 @@ class GenerationEngine:
                     self._grow(active)
                 tables = (self._block_tables(active,
                                              bucket // self._kv_bs),)
-            self._cache, toks, lps = self._decode[(bucket, trunc)](
+            self._cache, toks, lps, counts = self._decode[(bucket, trunc)](
                 self._params, self._cache, *tables, last_dev,
                 jnp.asarray(idx), jnp.asarray(temps), jnp.asarray(ks),
                 jnp.asarray(ps), sub, aid=self._aid_batch(aids))
         # Start the D2H transfer now; the fetch a pipeline-depth later
         # should find the bytes already on host.
-        for arr in (toks, lps):
+        for arr in (toks, lps, *counts.values()):
             getattr(arr, "copy_to_host_async", lambda: None)()
         with self._stats_lock:
             self.stats["decode_dispatches"] += 1
@@ -3574,8 +3582,8 @@ class GenerationEngine:
             st = self._slots[i]
             st["disp"] += self.chunk
             parts[i] = st
-        return {"kind": "van", "toks": toks, "lps": lps, "parts": parts,
-                "t0": t0, "p0": p0, "chunk": self.chunk}
+        return {"kind": "van", "toks": toks, "lps": lps, "counts": counts,
+                "parts": parts, "t0": t0, "p0": p0, "chunk": self.chunk}
 
     # tpk-hot: engine-fetch
     def _fetch_chunk(self, rec: dict, overlapped: bool) -> None:
@@ -3602,6 +3610,12 @@ class GenerationEngine:
             toks = np.asarray(rec["toks"])  # host sync point: [B, chunk]
             # tpk-lint: allow(host-sync) reason=second half of the designed per-chunk fetch boundary (logprobs ride the same prestaged copy)
             lps = np.asarray(rec["lps"])
+            # What the model counted on the device at this dispatch's first
+            # step (a few scalars of the same program, prestaged with the
+            # tokens: no sync of their own; none for most models).
+            # tpk-lint: allow(host-sync) reason=scalars of the dispatch already fetched above, prestaged by copy_to_host_async
+            self._count({name: int(np.asarray(n))
+                         for name, n in rec["counts"].items()})
             now = time.monotonic()
             pf1 = time.perf_counter()
         with obs.span("engine.emit", round=self._round):

@@ -71,6 +71,16 @@ MAGIC = b"TPKV1\n"
 _LEN = struct.Struct(">Q")
 
 
+def require_row_blocks(state) -> None:
+    """The wire format and the host tier carry one table of blocks of rows
+    of per-head K and V: a model that keeps another state is refused when
+    its engine is made, not mis-shaped on the wire."""
+    from kubeflow_tpu.serve.paging import require_rows
+
+    require_rows(state, "disaggregated shipment and the host tier: the wire "
+                 "format carries one table of blocks of K and V rows")
+
+
 class ShipmentError(ValueError):
     """Malformed / incompatible shipment bytes (bad magic, truncated
     frame, unknown version, dtype/shape mismatch with this engine)."""

@@ -79,6 +79,18 @@ def serving_state(cfg, block_size: int, max_len: int):
     return RowState(cfg, block_size, max_len)
 
 
+def require_rows(state, what: str) -> None:
+    """Refuse, when an engine is made, to serve `state` with a part of the
+    engine that is written against rows of per-head K and V
+    (models/llama.py `RowState`): `what` names the part and says why."""
+    from kubeflow_tpu.models.llama import RowState
+
+    if not isinstance(state, RowState):
+        raise ValueError(
+            f"{type(state).__name__} keeps {' and '.join(state.kinds)} "
+            f"blocks; the engine cannot serve that with {what}")
+
+
 class BlockAllocator:
     """Fixed-pool block allocator with refcounted sharing.
 

@@ -177,6 +177,15 @@ def quantized_bytes(params: Any) -> dict:
 KV_QUANT_MODES = ("none", "int8", "fp8")
 
 
+def require_kv_planes(state) -> None:
+    """A quantized pool keeps a scale a row a head beside planes of K and V:
+    a model that keeps another state is refused when its engine is made."""
+    from kubeflow_tpu.serve.paging import require_rows
+
+    require_rows(state, "kv_quant: the scale planes follow rows of per-head "
+                 "K and V")
+
+
 def kv_qdtype(mode: str):
     """Storage dtype of a quantized KV pool."""
     return {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}[mode]

@@ -119,10 +119,15 @@ def _jax_registry_runtime(model_dir: str, spec: dict) -> Model:
         with ocp.StandardCheckpointer() as ckptr:
             params = ckptr.restore(params_dir)
     else:  # no trained weights: init from the recorded seed (tests, smoke)
-        import flax.linen as nn
-        rng = jax.random.key(spec.get("seed", 0))
-        example = np.zeros((1, *example_shape), dtype=dtype)
-        params = nn.meta.unbox(module.init(rng, example)["params"])
+        from kubeflow_tpu.serve.weights import Seeded
+
+        params = Seeded(module, jax.random.key(spec.get("seed", 0)),
+                        np.zeros((1, *example_shape), dtype=dtype))
+        if not spec.get("generative") or spec.get("quantize"):
+            # A fixed forward and the int8 pass read the tree itself; the
+            # generation engine makes it leaf group by leaf group, each in
+            # the dtype it stores (serve/weights.py).
+            params = params.whole()
 
     module, params = _maybe_quantize(module, params, spec)
 

@@ -23,10 +23,12 @@ from __future__ import annotations
 import argparse
 import asyncio
 import functools
+import gc
 import json
 import math
 import os
 import signal
+import sys
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -262,6 +264,7 @@ class ModelRepository:
                     if self._models.get(name) is old:
                         return  # rolled back — old is live again
                     old.unload()
+                gc.unfreeze()  # what it leaves is the collector's again
 
             t = threading.Timer(self.UNLOAD_GRACE_S, _deferred_unload)
             t.daemon = True  # never delays interpreter exit
@@ -382,6 +385,7 @@ class ModelRepository:
             raise tornado.web.HTTPError(
                 404, reason=f"model {name!r} not found")
         self.get(name).unload()
+        gc.unfreeze()  # what it leaves is the collector's again
 
     def close(self) -> None:
         for b in self._batchers.values():
@@ -1363,6 +1367,35 @@ class ModelServer:
         self._serve(port, threading.Event())
 
 
+def _close_profiler() -> None:
+    """Write out a profiler session that whoever hosts `main()` opened
+    (the spans' second sink, utils/obs.py) before the process goes. A
+    trace is serialised by `stop_trace`, tens of seconds for a busy
+    engine's: one that a side thread is still writing when SIGTERM
+    arrives would die with that thread. `stop_trace` takes the profiler's
+    lock, so this waits for a stop under way elsewhere, stops a session
+    nobody stopped, and raises where there is none."""
+    profiler = sys.modules.get("jax.profiler")  # not imported: no session
+    try:
+        if profiler is not None:
+            profiler.stop_trace()
+    except RuntimeError:
+        pass
+
+
+def settle_heap() -> None:
+    """Put what loading left on the heap (parameter trees, traced programs,
+    modules: hundreds of thousands of objects that live as long as the
+    process) out of the cyclic collector's reach. A full collection walks
+    every tracked object with every thread stopped: over the load's heap
+    that is longer than a decode pipeline has work queued, so the device
+    idles a few rounds each time (PERF.md section 6, PR 36); over the
+    requests' objects alone it is not. A model unloaded later is thawed
+    first (`ModelRepository`), so nothing it leaves is kept."""
+    gc.collect()
+    gc.freeze()
+
+
 def main(argv: list[str] | None = None) -> int:
     p = argparse.ArgumentParser(prog="tpk-model-server")
     p.add_argument("--model-dir", action="append", default=[],
@@ -1438,9 +1471,14 @@ def main(argv: list[str] | None = None) -> int:
             server.repo.register(model, model_dir=d, mesh=mesh_spec,
                                  max_batch_size=args.max_batch_size,
                                  max_latency_ms=args.max_latency_ms)
+            # The peak so far is the load's: weights made and held, every
+            # program compiled and warmed (device_end has the whole run's).
             print(json.dumps({"event": "model_loaded", "name": model.name,
-                              "load_time_s": model.load_time_s}),
+                              "load_time_s": model.load_time_s,
+                              "peak_bytes_in_use":
+                                  devices.peak_bytes_in_use()}),
                   flush=True)
+        settle_heap()
         if args.grpc_port is not None:
             bound = server.start_grpc(args.grpc_port)
             print(json.dumps({"event": "grpc_serving", "port": bound}),
@@ -1452,6 +1490,7 @@ def main(argv: list[str] | None = None) -> int:
         for name in server.repo.names():
             server.repo.get(name).unload()
         server.stop()
+        _close_profiler()
         print(json.dumps({"event": "device_end", **clock.snapshot(),
                           "peak_bytes_in_use":
                               devices.peak_bytes_in_use()}), flush=True)

@@ -17,18 +17,79 @@ into an fp32 residual stream, a router's fp32 matmul, a pooling vector:
 each has a read that is not that convert, and stays as it came. An
 `Int8Leaf` (serve/quant.py) is not a float leaf and passes through
 untouched.
+
+A bundle without trained weights is served from its seed, and the tree
+`module.init` makes from it is fp32: whole, it can be larger than the device
+though what the engine stores of it is not. `Seeded` is that tree not made
+yet: `init` traced once into a jaxpr. The rule above needs its shapes only;
+`hold` then makes it a leaf at a time (the stacked leaves of a scanned trunk
+together: one `scan` makes them), each by running the equations that leaf
+depends on (the others, the example's forward pass among them, are dead to
+it) one by one as an eager `init` would, so a leaf has the bits the whole
+`init` gives it, a check script that rebuilds the weights either way sees the
+served ones, and the most fp32 on the device at any moment is one equation's
+leaves: one leaf of an unrolled model.
 """
 
 from __future__ import annotations
 
 from typing import Callable
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.extend import core as jex
+from jax.interpreters import partial_eval as pe
 
 from kubeflow_tpu.serve.quant import _is_quant_leaf as _is_int8
+
+
+class Seeded:
+    """The parameters `module.init(rng, example)` gives, not made yet."""
+
+    def __init__(self, module, rng, example):
+        def init(rng):
+            return nn.meta.unbox(module.init(rng, example)["params"])
+
+        self._whole, self._rng = init, rng
+        closed, self.abstract = jax.make_jaxpr(init, return_shape=True)(rng)
+        self._jaxpr, self._consts = closed.jaxpr, closed.consts
+
+    def whole(self):
+        """The tree itself, every leaf at once, as `init` makes it."""
+        return self._whole(self._rng)
+
+    def together(self) -> list[list[int]]:
+        """The leaves' indices (flatten order), those that one equation of
+        `init` makes in one list: a scanned trunk's stacked leaves come out
+        of one `scan` and are made by running it once, as `init` does; an
+        unrolled model's leaves are each alone."""
+        made_by = {v: n for n, eqn in enumerate(self._jaxpr.eqns)
+                   for v in eqn.outvars}
+        groups: dict = {}
+        for i, var in enumerate(self._jaxpr.outvars):
+            groups.setdefault(made_by.get(var, ("in", i)), []).append(i)
+        return list(groups.values())
+
+    def make(self, indices: list[int]) -> list[jax.Array]:
+        """The leaves at `indices`, as `init` makes them: the equations they
+        depend on, each dispatched by itself."""
+        wanted = set(indices)
+        jaxpr, _ = pe.dce_jaxpr(
+            self._jaxpr, [j in wanted for j in range(len(self._jaxpr.outvars))],
+            instantiate=True)
+        out = jax.core.eval_jaxpr(jaxpr, self._consts, self._rng)
+        return [out[sorted(wanted).index(i)] for i in indices]
+
+    def group(self, name: str):
+        """The subtree under the top-level `name`, made the same way: what
+        a reference that looks at one layer's fp32 weights at a time asks
+        for."""
+        first = sum(len(jax.tree.leaves(self.abstract[k]))
+                    for k in sorted(self.abstract) if k < name)
+        sub = jax.tree.structure(self.abstract[name])
+        return sub.unflatten(self.make(
+            list(range(first, first + sub.num_leaves))))
 
 
 def _bodies(eqn) -> list | None:
@@ -123,6 +184,8 @@ def stored_narrow(model, params, state, dtype, *, max_len: int,
     """One bool a leaf of `params` (an `Int8Leaf` one leaf), in flatten
     order: True where the engine stores the leaf in `dtype`, the model's
     compute dtype (None: a configuration that names none)."""
+    if isinstance(params, Seeded):
+        params = params.abstract
     leaves = jax.tree.leaves(params, is_leaf=_is_int8)
 
     def wider(leaf) -> bool:
@@ -133,7 +196,7 @@ def stored_narrow(model, params, state, dtype, *, max_len: int,
     if dtype is None or not any(wider(leaf) for leaf in leaves):
         return [False] * len(leaves)   # nothing to round: nothing to trace
     abstract = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), params)
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
     reads = [only_converted(jax.make_jaxpr(fwd)(abstract).jaxpr, dtype)
              for fwd in _forwards(model, state, max_len, piece)]
     narrow, at = [], 0
@@ -151,18 +214,28 @@ def hold(params, narrow: list[bool], dtype, put: Callable,
     `put(leaf, place)` (onto the device, or its shard of the mesh: half
     the bytes move). One leaf at a time, and with `donate` the caller
     gives its tree up: a device leaf is deleted as soon as its rounded
-    twin exists, so loading never holds the whole tree twice."""
+    twin exists, so loading never holds the whole tree twice. A `Seeded`
+    tree is made here, the leaves of one equation at a time (one leaf, for
+    an unrolled model), and given up as it is made."""
+    seeded = params if isinstance(params, Seeded) else None
+    if seeded is not None:
+        params, donate = seeded.abstract, True
     leaves, treedef = jax.tree.flatten(params, is_leaf=_is_int8)
+    places = places or [None] * len(leaves)
     cast = jax.jit(lambda x: x.astype(dtype))
-    out = []
-    for leaf, to, place in zip(leaves, narrow,
-                               places or [None] * len(leaves)):
-        if to:
-            twin = jax.block_until_ready(cast(leaf))
-            if donate and isinstance(leaf, jax.Array):
-                leaf.delete()
-            leaf = twin
-        out.append(put(leaf, place))
+    out = [None] * len(leaves)
+    for group in (seeded.together() if seeded is not None
+                  else [[i] for i in range(len(leaves))]):
+        made = seeded.make(group) if seeded is not None else [
+            leaves[i] for i in group]
+        for i, leaf in zip(group, made):
+            if narrow[i]:
+                twin = jax.block_until_ready(cast(leaf))
+                if donate and isinstance(leaf, jax.Array):
+                    leaf.delete()
+                leaf = twin
+            out[i] = put(leaf, places[i])
+        del made
     return treedef.unflatten(out)
 
 

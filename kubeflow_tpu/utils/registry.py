@@ -156,6 +156,24 @@ def _ensure_builtin() -> None:
         import dataclasses
         return _evabyte(dataclasses.replace(evabyte.evabyte_6_5b(), **kw))
 
+    from kubeflow_tpu.models import joyai
+
+    def _joyai(cfg):
+        return joyai.JoyAI(cfg), {
+            "task": "lm", "example_shape": (1, 16), "example_dtype": "int32",
+            "num_params": cfg.active_params, "held_params": cfg.held_params,
+            "vocab_size": cfg.vocab_size, "config": cfg}
+
+    @register_model("joyai_tiny")
+    def _joyai_tiny(**kw):
+        import dataclasses
+        return _joyai(dataclasses.replace(joyai.joyai_tiny(), **kw))
+
+    @register_model("joyai_llm_flash")
+    def _joyai_llm_flash(**kw):
+        import dataclasses
+        return _joyai(dataclasses.replace(joyai.joyai_llm_flash(), **kw))
+
     @register_model("bert_tiny")
     def _bert_tiny(**kw):
         import dataclasses

@@ -399,8 +399,9 @@ def test_expert_layer_matches_loop_reference(biased, two_blocks):
         with jax.default_matmul_precision("highest"):
             return ref.moe_ffn(x, p, expert_ref_cfg(block))
 
-    (y, share, load), (want, want_share, counts) = prog(params, x), plain(
-        params, x)
+    (y, share, load, touched), (want, want_share, counts) = prog(
+        params, x), plain(params, x)
+    assert int(touched) == int(jnp.sum(counts > 0))
     counts = counts.astype(jnp.float32)
     assert rel(y, want) < 1e-5
     assert float(share) == pytest.approx(float(want_share))
@@ -433,10 +434,10 @@ def test_the_shares_add_up_to_the_uncut_layer(num_experts, held):
     params = nn.meta.unbox(whole.init(jax.random.key(6), x)["params"])
     params["e_score_correction_bias"] = 0.1 * jax.random.normal(
         jax.random.key(7), (num_experts,))
-    uncut, share_all, _ = whole.apply({"params": params}, x)
+    uncut, share_all, *_ = whole.apply({"params": params}, x)
     assert float(share_all) == pytest.approx(1.0)
     routed = {k: v for k, v in params.items() if k != "shared_expert"}
-    uncut_routed, _, _ = expert_block(
+    uncut_routed, *_ = expert_block(
         num_experts, (0, num_experts), top, shared=0).apply(
             {"params": routed}, x)
     shared_part = uncut - uncut_routed
@@ -444,7 +445,7 @@ def test_the_shares_add_up_to_the_uncut_layer(num_experts, held):
     for start in range(0, num_experts, held):
         mine = dict(routed, **{k: routed[k][start:start + held]
                                for k in ("w_gate", "w_up", "w_down")})
-        y, share, _ = expert_block(
+        y, share, *_ = expert_block(
             num_experts, (start, held), top, shared=0).apply(
                 {"params": mine}, x)
         total, shares = total + y, shares + float(share)
@@ -785,3 +786,49 @@ def test_eva_cores_compile_for_the_chip_at_real_widths(one_chip, form):
         # state ([16, 2816, 32, 128] of K and of V) is copied outside it.
         assert text.count("tpu_custom_call") == 1
         assert not re.search(r"\[16,(2816|176),", text)
+
+
+@pytest.mark.parametrize("form", ["decode_core", "later_piece",
+                                  "decode_experts"])
+def test_latent_serving_compiles_for_the_chip_at_real_widths(one_chip, form):
+    """What the served latent-attention expert model (models/joyai.py) asks
+    of Mosaic at its published widths: the absorbed decode core's 16 rows
+    reading up to 512 blocks of 16 latent rows (576 values, 640 as stored)
+    each through their tables out of the pool; a later prompt piece's
+    queries against the rows before it (no causal mask, key 192 / value 128,
+    the kernel's row log-sum-exp returned); and the dropless expert layer at
+    a decode step's size (128 pairs over 256 experts of 2048 x 768). Here,
+    not in tests/test_joyai.py: the described chip belongs to one test
+    file."""
+    from kubeflow_tpu.models.moe import held_experts_ffn
+    from kubeflow_tpu.ops import mla
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    if form == "decode_core":
+        text = jax.jit(functools.partial(
+            mla.absorbed_step, rank=512, scale=192 ** -0.5, group=16,
+            interpret=False)).lower(
+            shape(16, 32, 640), shape(5 * 8193, 16, 640),
+            shape(16, 512, dtype=jnp.int32),
+            shape(16, dtype=jnp.int32)).compile().as_text()
+        # One kernel reads the rows through the tables; nothing of the
+        # state ([16, 8192, 640]) is copied outside it.
+        assert text.count("tpu_custom_call") == 1
+        assert not re.search(r"\[16,(8192|512),(16,)?640\]", text)
+    elif form == "later_piece":
+        text = jax.jit(lambda q, k, v: fa.flash_attention_lse(
+            q, k, v, False, 512, 512, False)).lower(
+            shape(1, 2048, 32, 192), shape(1, 2048, 32, 192),
+            shape(1, 2048, 32, 128)).compile().as_text()
+        assert text.count("tpu_custom_call") == 1
+    else:
+        text = jax.jit(functools.partial(
+            held_experts_ffn, start=0, num_experts=256, dtype=jnp.bfloat16,
+            interpret=False)).lower(
+            shape(16, 2048), shape(16, 8, dtype=jnp.int32),
+            shape(16, 8, dtype=jnp.float32), shape(256, 2048, 768),
+            shape(256, 2048, 768), shape(256, 768, 2048)
+        ).compile().as_text()
+        assert text.count("tpu_custom_call") == 3  # gate, up, down

@@ -364,6 +364,49 @@ def test_deferred_unload_spares_rolled_back_model():
         ModelRepository.UNLOAD_GRACE_S = old_grace
 
 
+@pytest.mark.parametrize("how", ["unload by name", "a version swap"])
+def test_settled_heap_is_thawed_when_a_model_goes(how):
+    """`main()` puts the heap that loading left out of the cyclic
+    collector's reach (a full collection over it stops the engine's thread
+    for longer than its pipeline covers); a model that goes later must not
+    leave cycles the collector can no longer see."""
+    import gc
+    import time
+    import weakref
+
+    from kubeflow_tpu.serve import server as srv
+
+    class Cyclic(Model):
+        def predict(self, inputs):
+            return inputs
+
+    old_grace = srv.ModelRepository.UNLOAD_GRACE_S
+    srv.ModelRepository.UNLOAD_GRACE_S = 0.05
+    try:
+        repo = srv.ModelRepository()
+        model = Cyclic("m")
+        model.me = model  # a cycle: only the collector frees it
+        gone = weakref.ref(model)
+        repo.register(model)
+        srv.settle_heap()
+        assert gc.get_freeze_count() > 0
+        if how == "unload by name":
+            repo.unload("m")
+            repo._models.pop("m")  # (the repository keeps an unloaded
+            repo._batchers.pop("m").close()  # model's entry for a reload)
+        else:
+            repo.register(Cyclic("m"))
+            time.sleep(0.5)
+        assert gc.get_freeze_count() == 0
+        del model
+        gc.collect()
+        assert gone() is None
+        repo.close()
+    finally:
+        gc.unfreeze()
+        srv.ModelRepository.UNLOAD_GRACE_S = old_grace
+
+
 def test_happy_path_unchanged_with_no_faults_armed(server):
     """Zero-overhead check (ISSUE 1): with no fault harness installed and
     no deadline header, the resilience layer must be invisible — same

@@ -105,6 +105,41 @@ def test_disabled_tracer_emits_neither_ring_span_nor_annotation(traced):
     assert traced["off"].span("x") is obs.NOP_SPAN
 
 
+@pytest.mark.parametrize("stopper", ["nobody", "a side thread"])
+def test_server_shutdown_writes_an_open_profiler_session(tmp_path, stopper):
+    """benchmarks/serve_child.py's side thread stops its profiler window
+    while the server goes on; SIGTERM can arrive while it still writes.
+    The server's shutdown waits for that stop (the profiler's lock), or
+    makes it where nobody did, and is a no-op with no session."""
+    import threading
+
+    from kubeflow_tpu.serve import server
+
+    server._close_profiler()  # no session: nothing to do, no raise
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    jnp.ones((8, 8)).sum().block_until_ready()
+    side = None
+    if stopper == "a side thread":
+        def stop():  # whichever of the two comes second finds none open
+            try:
+                jax.profiler.stop_trace()
+            except RuntimeError:
+                pass
+
+        side = threading.Thread(target=stop, daemon=True)
+        side.start()
+    server._close_profiler()
+    # Written by the time shutdown goes on, whoever stopped it.
+    assert glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)
+    if side is not None:
+        side.join(timeout=30)
+        assert not side.is_alive()
+    server._close_profiler()
+
+
 def test_obs_imports_and_spans_without_jax():
     code = (
         "import sys\n"
@@ -148,6 +183,9 @@ def engine_run():
     # not the op-by-op programs of the first admission and dispatch.
     for n, m in ((4, 9), (3, 6), (2, 1)):
         engine.submit([5, 9, 2, 44][:n], max_tokens=m)
+    # The loop parks in engine.wait under the tracer it was opened with; a
+    # swap between that pass's sweep and its wait would catch the wait alone.
+    time.sleep(0.2)
     prev = obs.set_tracer(obs.Tracer(capacity=100_000, enabled=True))
     try:
         before = engine.stats_snapshot()
