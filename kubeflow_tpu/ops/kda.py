@@ -34,7 +34,9 @@ The triangular system is solved by blocks of the same 16: each diagonal
 block's inverse is the finite Neumann product (I - L)(I + L^2)(I + L^4)
 (I + L^8) (L is strictly lower, L^16 = 0), then block forward substitution
 with that inverse spread over it: u_a = (inv rhs)_a - sum_{b<a} (inv A)_ab
-u_b.
+u_b. The solve (`_solve`) brings its own VJP: dR = (I + A)^-T dU, by block
+back substitution through the same inv A and one product with inv^T, and
+dA = -dR U^T read back on A's pattern.
 
 **What runs where.** `kda_fwd` walks the grid (batch, head group, chunk),
 the chunks in turn ("arbitrary") with the group's states [dv, dk] fp32 in
@@ -45,11 +47,14 @@ and registers, and writes o and the state the chunk started from. `kda_bwd`
 walks the same grid from the last chunk with dS in the scratch: it reads the
 chunk's inputs, its start state and dO, takes `jax.vjp` of the same chunk
 function inside the kernel body (so the chunk is recomputed there, never
-stored) and writes the operands' cotangents. Only those arrays and the start
-states (dk * dv * 4 bytes a chunk a head, live while the layer's backward
-runs) cross HBM. A head group is 128 / C heads (two at C = 64), stacked:
-time runs along all 128 lanes in the pairwise part, and the matmuls that do
-not involve a head's state are shared, block-diagonal.
+stored) and writes the operands' cotangents. That `jax.vjp` stops at the
+solve and takes its VJP above, so the Neumann product and the substitution
+are never linearised; the cotangents of beta and the pairwise diagonals
+come from autodiff of the elementwise work around it. Only those arrays and
+the start states (dk * dv * 4 bytes a chunk a head, live while the layer's
+backward runs) cross HBM. A head group is 128 / C heads (two at C = 64),
+stacked: time runs along all 128 lanes in the pairwise part, and the
+matmuls that do not involve a head's state are shared, block-diagonal.
 
 The chunk function is one of two. `kda_chunked` runs `_chunk`, the rule
 alone, on q, k, v, g as given (fp32). `kda_mixer` runs `_mixer_chunk`: a
@@ -243,6 +248,94 @@ def _from_diagonals(diag):
     return out.T  # built with i along the lanes, as `diag` has it
 
 
+def _to_diagonals(x, sub: int):
+    """The diagonals of x [n, n] that `_from_diagonals` fills, read back (its
+    transpose): out[d, i] = x[i, i - d], 0 where i < d."""
+    n = x.shape[0]
+    xt = x.T                                          # i along the lanes
+    diff = _iota((n, n), 1) - _iota((n, n), 0)
+    return jnp.concatenate(
+        [jnp.sum(jnp.where(diff == d, xt, 0.0), axis=0, keepdims=True)
+         for d in range(sub)], axis=0)
+
+
+def _sub_rows(x, a: int, c: int, sub: int):
+    """The rows of sub-chunk a of every head of x [pack * C, .]."""
+    return jnp.concatenate(
+        [x[h * c + a * sub:h * c + (a + 1) * sub]
+         for h in range(x.shape[0] // c)], axis=0)
+
+
+def _unstack(blocks, sub: int):
+    """nb blocks of (head, row) [pack * sub, .] -> [n, .] (head, step)."""
+    return jnp.concatenate(
+        [x[h * sub:(h + 1) * sub]
+         for h in range(blocks[0].shape[0] // sub) for x in blocks], axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _solve(power, a_off, rhs, c: int, sub: int, interpret: bool):
+    """u with (I + A) u = rhs for a chunk's n = pack * C rows, the heads
+    stacked, A = L + a_off strictly lower. L lies in the diagonal sub x sub
+    blocks and is given by diagonals, power [sub, n] (see `_diag_product`;
+    0 in row 0 and where n - d falls before the block); a_off [n, n] is the
+    rest, a head's rows reading its earlier sub-chunks; rhs [n, dv].
+
+    Its VJP is written out, so that autodiff never linearises the Neumann
+    product or the substitution: with M = I + A, dR = M^-T dU and dA = -dR
+    u^T on A's pattern, where M^-T = inv^T (I + inv a_off)^-T is a block
+    back substitution and one product with inv^T."""
+    return _solve_fwd(power, a_off, rhs, c, sub, interpret)[0]
+
+
+def _solve_fwd(power, a_off, rhs, c, sub, interpret):
+    roll = functools.partial(_roll, interpret=interpret)
+    nb = c // sub
+    # (I + L)^-1 of the diagonal blocks, L strictly lower and L^sub = 0:
+    # the finite Neumann product (I - L)(I + L^2)(I + L^4)...
+    eye = (_iota(power.shape, 0) == 0).astype(_F32)
+    inv = eye - power
+    for _ in range(max(sub.bit_length() - 2, 0)):
+        power = _diag_product(power, power, roll)
+        inv = _diag_product(inv, eye + power, roll)
+    inv = _from_diagonals(inv)                        # [n, n]
+    # Block forward substitution for (I + A) u = rhs with inv spread over
+    # it: u_a = (inv rhs)_a - sum_{b < a} (inv a_off)_ab u_b.
+    inv_a = _dot(inv, a_off, _NN, interpret)
+    y = _dot(inv, rhs, _NN, interpret)
+    us = [_sub_rows(y, 0, c, sub)]
+    for a in range(1, nb):
+        done = _unstack(us + [jnp.zeros_like(us[0])] * (nb - a), sub)
+        us.append(_sub_rows(y, a, c, sub)
+                  - _dot(_sub_rows(inv_a, a, c, sub), done, _NN, interpret))
+    u = _unstack(us, sub)                             # [n, dv]
+    return u, (inv, inv_a, u)
+
+
+def _solve_bwd(c, sub, interpret, res, du):
+    inv, inv_a, u = res
+    n, nb = inv.shape[0], c // sub
+    # w = (I + inv a_off)^-T dU from the last sub-chunk back:
+    # w_a = dU_a - sum_{b > a} (inv a_off)_ba^T w_b.
+    inv_at = inv_a.T
+    ws = [_sub_rows(du, nb - 1, c, sub)]
+    for a in range(nb - 2, -1, -1):
+        done = _unstack([jnp.zeros_like(ws[0])] * (a + 1) + ws, sub)
+        ws.insert(0, _sub_rows(du, a, c, sub)
+                  - _dot(_sub_rows(inv_at, a, c, sub), done, _NN, interpret))
+    d_rhs = _dot(inv, _unstack(ws, sub), _TN, interpret)   # inv^T w
+    d_m = -_dot(d_rhs, u, _NT, interpret)             # [n, n]: -dR u^T
+    r_i, c_i = _iota((n, n), 0), _iota((n, n), 1)
+    before = (r_i // c == c_i // c) & (c_i // sub < r_i // sub)
+    drow = _iota((sub, n), 0)
+    in_block = (drow >= 1) & (_iota((sub, n), 1) % sub >= drow)
+    return (jnp.where(in_block, _to_diagonals(d_m, sub), 0.0),
+            jnp.where(before, d_m, 0.0), d_rhs)
+
+
+_solve.defvjp(_solve_fwd, _solve_bwd)
+
+
 def _chunk(q, k, v, g, beta, st, *, pack: int, sub: int, interpret: bool):
     """One chunk of `pack` heads. q, k, g [C, pack * dk]; v [C, pack * dv];
     beta [1, pack * C]; st [pack * dv, dk], each head's state transposed.
@@ -265,9 +358,7 @@ def _chunk(q, k, v, g, beta, st, *, pack: int, sub: int, interpret: bool):
             [x[:, h * d:(h + 1) * d] for h in range(pack)], axis=0)
 
     def rows_of(x, a):  # the rows of sub-chunk a, of every head
-        return jnp.concatenate(
-            [x[h * c + a * sub:h * c + (a + 1) * sub] for h in range(pack)],
-            axis=0)
+        return _sub_rows(x, a, c, sub)
 
     qs, ks, vs, gs = stack(q, dk), stack(k, dk), stack(v, dv), stack(g, dk)
     r_i, c_i = _iota((n, n), 0), _iota((n, n), 1)
@@ -291,17 +382,6 @@ def _chunk(q, k, v, g, beta, st, *, pack: int, sub: int, interpret: bool):
     diag_k = jnp.where(valid, jnp.concatenate(dk_rows, axis=0), 0.0)
     diag_q = jnp.where(valid, jnp.concatenate(dq_rows, axis=0), 0.0)
 
-    # (I + L)^-1 of the diagonal blocks, L strictly lower and L^sub = 0:
-    # the finite Neumann product (I - L)(I + L^2)(I + L^4)...
-    drow = _iota((sub, n), 0)
-    eye = (drow == 0).astype(_F32)
-    power = jnp.where(drow >= 1, beta * diag_k, 0.0)
-    inv = eye - power
-    for _ in range(max(sub.bit_length() - 2, 0)):
-        power = _diag_product(power, power, roll)
-        inv = _diag_product(inv, eye + power, roll)
-    inv = _from_diagonals(inv)                        # [n, n]
-
     # Pairs in different sub-chunks, split at the row's sub-chunk start s:
     # exp(G_i - G_s) exp(G_s - G_j), both factors at most one.
     zeros = jnp.zeros((pack * sub, n), _F32)
@@ -319,28 +399,17 @@ def _chunk(q, k, v, g, beta, st, *, pack: int, sub: int, interpret: bool):
         off_k.append(jnp.where(before, off[:pack * sub], 0.0))
         off_q.append(jnp.where(before, off[pack * sub:], 0.0))
 
-    def unstack(blocks):  # nb blocks of (head, row) -> [n, .] (head, step)
-        return jnp.concatenate(
-            [x[h * sub:(h + 1) * sub] for h in range(pack) for x in blocks],
-            axis=0)
-
-    b_m = unstack(off_q) + _from_diagonals(diag_q)    # [n, n]
-    # Block forward substitution for (I + A) u = rhs with inv spread over
-    # it: u_a = (inv rhs)_a - sum_{b < a} (inv A_off)_ab u_b.
-    inv_a = dot(inv, unstack(off_k) * bcol)
+    b_m = _unstack(off_q, sub) + _from_diagonals(diag_q)  # [n, n]
 
     gam = jnp.exp(G)                                  # decay from the start
     kg, qg = ks * gam, qs * gam
     on_state = [dot(jnp.concatenate(                  # [2 C, dv] a head
         [kg[h * c:(h + 1) * c], qg[h * c:(h + 1) * c]], axis=0),
         st[h * dv:(h + 1) * dv], _NT) for h in range(pack)]
-    y = dot(inv, bcol * (vs - jnp.concatenate(
-        [x[:c] for x in on_state], axis=0)))
-    us = [rows_of(y, 0)]
-    for a in range(1, nb):
-        done = unstack(us + [jnp.zeros((pack * sub, dv), _F32)] * (nb - a))
-        us.append(rows_of(y, a) - dot(rows_of(inv_a, a), done))
-    u = unstack(us)                                   # [n, dv]
+    # (I + A) u = rhs: L within the sub-chunks by diagonals, the rest [n, n].
+    rhs = bcol * (vs - jnp.concatenate([x[:c] for x in on_state], axis=0))
+    u = _solve(jnp.where(_iota((sub, n), 0) >= 1, beta * diag_k, 0.0),
+               _unstack(off_k, sub) * bcol, rhs, c, sub, interpret)  # [n, dv]
     o = jnp.concatenate([x[c:] for x in on_state], axis=0) + dot(b_m, u)
 
     states = []

@@ -175,6 +175,129 @@ def test_kda_refuses_a_chunk_its_blocks_do_not_tile(chunk, sub):
         kda_chunked(*kda_inputs(64, 0.1), chunk=chunk, sub=sub)
 
 
+def plain_solve(power, a_off, rhs, c, sub, interpret):
+    """`ops/kda.py:_solve`'s forward as `_chunk` had it inline, with no rule of
+    its own: what autodiff linearises whole."""
+    from kubeflow_tpu.ops import kda
+
+    roll = functools.partial(kda._roll, interpret=interpret)
+    dot = functools.partial(kda._dot, dims=kda._NN, interpret=interpret)
+    n, nb = power.shape[1], c // sub
+    pack = n // c
+
+    def rows_of(x, a):
+        return jnp.concatenate(
+            [x[h * c + a * sub:h * c + (a + 1) * sub] for h in range(pack)],
+            axis=0)
+
+    def unstack(blocks):
+        return jnp.concatenate(
+            [x[h * sub:(h + 1) * sub] for h in range(pack) for x in blocks],
+            axis=0)
+
+    eye = (jnp.arange(sub)[:, None] == 0).astype(jnp.float32)
+    inv = eye - power
+    for _ in range(max(sub.bit_length() - 2, 0)):
+        power = kda._diag_product(power, power, roll)
+        inv = kda._diag_product(inv, eye + power, roll)
+    inv = kda._from_diagonals(inv)
+    inv_a, y = dot(inv, a_off), dot(inv, rhs)
+    us = [rows_of(y, 0)]
+    for a in range(1, nb):
+        done = unstack(us + [jnp.zeros((pack * sub, rhs.shape[1]))] * (nb - a))
+        us.append(rows_of(y, a) - dot(rows_of(inv_a, a), done))
+    return unstack(us)
+
+
+def solve_inputs(pack, c, dv, sub=16, seed=0):
+    """power, a_off, rhs and a cotangent for u, each 0 off its pattern, and
+    the patterns' masks (as `_chunk` builds them)."""
+    n = pack * c
+    r = np.random.default_rng(seed)
+    d, i = np.arange(sub)[:, None], np.arange(n)[None]
+    in_block = (d >= 1) & (i % sub >= d)
+    rows, cols = np.arange(n)[:, None], np.arange(n)[None]
+    before = (rows // c == cols // c) & (cols // sub < rows // sub)
+    power = np.where(in_block, r.uniform(-0.5, 0.5, (sub, n)), 0.0)
+    a_off = np.where(before, r.uniform(-0.5, 0.5, (n, n)), 0.0)
+    rhs, du = r.normal(size=(n, dv)), r.normal(size=(n, dv))
+    return ([jnp.asarray(x, jnp.float32) for x in (power, a_off, rhs, du)],
+            (in_block, before))
+
+
+@pytest.mark.parametrize("pack,c,dv", [
+    (2, 64, 128),  # the cell's head group: dk = dv = 128, two heads, nb 4
+    (2, 16, 128),  # one sub-chunk: no substitution at all
+    (3, 64, 8),    # toy widths
+    (1, 16, 8),
+])
+def test_kda_solve_vjp_matches_autodiff_of_the_plain_form(pack, c, dv):
+    """The solve's own VJP (a transposed block substitution, inv^T, -dR u^T
+    read back on A's pattern) against autodiff of the same forward written
+    out, interpreted in fp32; the primal is that forward to the bit."""
+    from kubeflow_tpu.ops import kda
+
+    (power, a_off, rhs, du), (in_block, before) = solve_inputs(pack, c, dv)
+
+    def plain(p, a, r):  # masked, so that the cotangents are 0 off A's pattern
+        return plain_solve(jnp.where(in_block, p, 0.0),
+                           jnp.where(before, a, 0.0), r, c, 16, True)
+
+    def value_and_cotangents(fn):  # op by op: faster than compiling here
+        out, pullback = jax.vjp(fn, power, a_off, rhs)
+        return out, pullback(du)
+
+    got, got_cts = value_and_cotangents(
+        lambda p, a, r: kda._solve(p, a, r, c, 16, True))
+    want, want_cts = value_and_cotangents(plain)
+    assert bool(jnp.array_equal(got, want))
+    for name, a, e in zip(("power", "a_off", "rhs"), got_cts, want_cts):
+        assert rel(a, e) < 1e-5, name
+
+
+def test_kda_backward_never_linearises_the_neumann_product(monkeypatch):
+    """`_diag_product` counted while `jax.vjp` of a chunk and its pullback are
+    traced: the forward's six products (sub 16: three squarings, three
+    factors), none of them differentiated. The same probe on the plain
+    forward sees all six linearised."""
+    from kubeflow_tpu.ops import kda
+
+    calls = {"primal": 0, "linearised": 0}
+    inner = kda._diag_product
+
+    @functools.partial(jax.custom_jvp, nondiff_argnums=(2,))
+    def counted(x, y, roll):
+        calls["primal"] += 1
+        return inner(x, y, roll)
+
+    @counted.defjvp
+    def _(roll, primals, tangents):
+        calls["linearised"] += 1
+        return jax.jvp(lambda x, y: inner(x, y, roll), primals, tangents)
+
+    monkeypatch.setattr(kda, "_diag_product", counted)
+    pack, c, dk, dv = 2, 32, 16, 8
+    r = np.random.default_rng(0)
+    args = [jnp.asarray(x, jnp.float32) for x in (
+        r.normal(size=(c, pack * dk)), r.normal(size=(c, pack * dk)),
+        r.normal(size=(c, pack * dv)), -np.abs(r.normal(size=(c, pack * dk))),
+        r.uniform(size=(1, pack * c)), r.normal(size=(pack * dv, dk)))]
+
+    def traced(fn, *args):  # the forward and its pullback, traced once
+        def both(*a):
+            out, pullback = jax.vjp(fn, *a)
+            return pullback(out)
+        calls.update(primal=0, linearised=0)
+        jax.make_jaxpr(both)(*args)
+        return dict(calls)
+
+    chunk = functools.partial(kda._chunk, pack=pack, sub=16, interpret=True)
+    assert traced(chunk, *args) == {"primal": 6, "linearised": 0}
+    (power, a_off, rhs, _), _ = solve_inputs(pack, c, dv)
+    assert traced(lambda p: plain_solve(p, a_off, rhs, c, 16, True),
+                  power) == {"primal": 0, "linearised": 6}
+
+
 # -- KDA: the fused mixer call against the reference's pieces -----------------
 
 L2_EPS, RMS_EPS = 1e-6, 1e-5
