@@ -18,7 +18,8 @@ alike (benchmarks/reference/kimi_linear.py): that partial result goes on to
 the next layer. Nothing here stands in for the absent chips.
 
 The trunk is unrolled (the layers' parameter trees differ, so no `nn.scan`)
-with each layer under `jax.checkpoint`. Activations and matmul operands are
+with each layer under `jax.checkpoint` (`_RematLayer`: a KDA layer keeps what
+its forward kernel wrote). Activations and matmul operands are
 `cfg.dtype` (bf16) with fp32 accumulation; norms, the softmax, the router,
 the gates and the whole KDA core are fp32 (the KDA mixer's inside the two
 kernels of ops/kda.py, see `KDAMixer`).
@@ -330,6 +331,15 @@ class KimiLayer(nn.Module):
         return x, share, load
 
 
+#: A layer under `jax.checkpoint`: its backward recomputes the layer but for
+#: what a KDA layer's `kda_fwd` wrote, o and the chunks' start states (named
+#: in ops/kda.py), which are kept from the forward; so that kernel runs once a
+#: layer, not twice. An MLA layer names nothing and keeps nothing. 0.67 GB a
+#: KDA layer at the cell's 2 x 8,192 tokens.
+_RematLayer = nn.remat(KimiLayer, policy=jax.checkpoint_policies
+                       .save_only_these_names("kda_o", "kda_starts"))
+
+
 class KimiLinear(nn.Module):
     """Causal LM. __call__ returns logits [B, S, V], or the post-norm hidden
     states with `return_hidden` (the chunked loss). Sows the step's routing
@@ -350,10 +360,7 @@ class KimiLinear(nn.Module):
             (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
         x = embed.astype(cfg.dtype)[tokens]
         x = nn.with_logical_constraint(x, ("batch", "act_seq", "act_embed"))
-        layer_cls = KimiLayer
-        if cfg.remat:
-            layer_cls = nn.remat(
-                KimiLayer, policy=jax.checkpoint_policies.nothing_saveable)
+        layer_cls = _RematLayer if cfg.remat else KimiLayer
         shares, loads = [], []
         for i in range(cfg.num_layers):
             x, share, load = layer_cls(cfg, i, name=f"layer_{i}")(x)

@@ -51,9 +51,12 @@ stored) and writes the operands' cotangents. That `jax.vjp` stops at the
 solve and takes its VJP above, so the Neumann product and the substitution
 are never linearised; the cotangents of beta and the pairwise diagonals
 come from autodiff of the elementwise work around it. Only those arrays and
-the start states (dk * dv * 4 bytes a chunk a head, live while the layer's
-backward runs) cross HBM. A head group is 128 / C heads (two at C = 64),
-stacked: time runs along all 128 lanes in the pairwise part, and the
+the start states (dk * dv * 4 bytes a chunk a head) cross HBM. The start
+states and o leave the forward named `kda_starts` and `kda_o`
+(`checkpoint_name`): a layer under `jax.checkpoint` that saves those names
+keeps them from its forward to its backward, and `kda_fwd` runs once a layer
+instead of again in the recomputation. A head group is 128 / C heads (two at
+C = 64), stacked: time runs along all 128 lanes in the pairwise part, and the
 matmuls that do not involve a head's state are shared, block-diagonal.
 
 The chunk function is one of two. `kda_chunked` runs `_chunk`, the rule
@@ -122,6 +125,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -703,7 +707,10 @@ def _kda(rows, consts, plan):
 
 
 def _kda_fwd(rows, consts, plan):
+    # The names are inert under any remat policy that does not save them.
     o, starts = _forward(rows, consts, plan)
+    o = checkpoint_name(o, "kda_o")
+    starts = checkpoint_name(starts, "kda_starts")
     return o, (rows, consts, starts)
 
 

@@ -3,7 +3,8 @@
 the chunked KDA core against the recurrence, latent attention through the
 flash kernels against a masked softmax, the held-experts layer against a loop
 over experts, the shares of an expert-parallel layer adding up to the uncut
-layer, the whole model's loss and gradients; and the flash backward this model
+layer, the whole model's loss and gradients, its remat keeping what the KDA
+forward kernel wrote; and the flash backward this model
 forced (value width != key width, q-side rows streamed through the grid).
 """
 
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.experimental import pallas as pl
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from kubeflow_tpu.models import kimi_linear as kl
 from kubeflow_tpu.models import moe
@@ -612,6 +614,73 @@ def test_model_loss_and_gradients_match_reference(seed):
             assert not float(jnp.linalg.norm(g)), path
         else:
             assert rel(g, w) < 2e-4, path
+
+
+def remat_model_and_loss(dtype):
+    """The tiny model with each layer under `jax.checkpoint` (as the cell
+    runs it), its parameters, and the loss of a fixed batch."""
+    cfg = dataclasses.replace(kl.kimi_linear_tiny(), dtype=dtype, remat=True)
+    model = kl.KimiLinear(cfg)
+    toks = jnp.asarray(np.random.default_rng(3).integers(
+        0, cfg.vocab_size, (2, 41)), jnp.int32)
+    inputs, targets = toks[:, :-1], toks[:, 1:]
+    params = nn.meta.unbox(model.init(jax.random.key(3), inputs)["params"])
+
+    from kubeflow_tpu.train.step import cross_entropy_loss
+
+    def loss(p):
+        return cross_entropy_loss(model.apply({"params": p}, inputs), targets)
+
+    return cfg, params, loss
+
+
+def kernel_calls(jaxpr, counts=None):
+    """Pallas calls in a jaxpr and every jaxpr nested in it, by the kernel's
+    name (its function's where the call gives none)."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = (eqn.params["name"] or eqn.params["jaxpr"].debug_info
+                    .func_src_info.split()[0])
+            counts[name] = counts.get(name, 0) + 1
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                if isinstance(sub, ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, Jaxpr):
+                    kernel_calls(sub, counts)
+    return counts
+
+
+def test_remat_runs_kda_fwd_once_a_layer():
+    """The gradient of the checkpointed trunk keeps what `kda_fwd` wrote (o
+    and the start states, saved by name): one forward kernel a KDA layer, not
+    a second in the recomputation. The MLA layer still recomputes its
+    flash forward."""
+    cfg, params, loss = remat_model_and_loss(jnp.bfloat16)
+    counts = kernel_calls(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    kda_layers = len(cfg.kda_layers)
+    assert kda_layers == 4 and len(cfg.full_attn_layers) == 1
+    assert counts["kda_fwd"] == kda_layers
+    assert counts["kda_bwd"] == kda_layers
+    assert counts["_flash_fwd_kernel"] == 2
+
+
+def test_remat_keeping_kda_outputs_leaves_gradients_unchanged(monkeypatch):
+    """Saved o and start states are what the recomputation would rebuild:
+    loss and every gradient equal those of the trunk that saves nothing."""
+    _, params, loss = remat_model_and_loss(jnp.float32)
+    # One program each: run op by op, the two gradients would leave
+    # thousands of small executables in the worker's cache.
+    got = jax.jit(jax.value_and_grad(loss))(params)
+    monkeypatch.setattr(kl, "_RematLayer", nn.remat(
+        kl.KimiLayer, policy=jax.checkpoint_policies.nothing_saveable))
+    want = jax.jit(jax.value_and_grad(loss))(params)
+    assert float(got[0]) == float(want[0])
+    flat = jax.tree_util.tree_leaves_with_path(got[1])
+    assert len(flat) > 100
+    for (path, g), w in zip(flat, jax.tree.leaves(want[1])):
+        assert rel(g, w) <= 1e-6, path
 
 
 def test_registry_reports_held_and_published_parameters_apart():
